@@ -24,6 +24,7 @@ from pathlib import Path
 
 from repro.core import (AdvisorOptions, DesignAdvisor, base_configuration,
                         make_scaled_workload, make_tpch_like)
+from repro.core.backend import enable_compile_cache
 from repro.core import candidates as cand
 from repro.core.cost_engine import CostEngine
 from repro.core.enumeration import greedy_enumerate, greedy_enumerate_scalar
@@ -167,6 +168,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="small fast run for CI (relaxed speedup gate)")
     args = ap.parse_args()
+    enable_compile_cache()
     root = Path(__file__).resolve().parent.parent
     if args.smoke:
         args.statements = 40

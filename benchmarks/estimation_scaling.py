@@ -46,6 +46,7 @@ from pathlib import Path
 from repro.core import (AdvisorOptions, DesignAdvisor, IndexDef,
                         SampleManager, make_scaled_workload, make_tpch_like,
                         sample_cf)
+from repro.core.backend import enable_compile_cache
 from repro.core.estimation_engine import EstimationEngine
 from repro.core.estimation_graph import F_GRID, EstimationPlanner, State
 from repro.core.planner_engine import assert_plan_identical
@@ -236,14 +237,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="small fast run for CI (relaxed speedup gate)")
     args = ap.parse_args()
-    if args.backend == "jax":
-        # codec math is int64: the jax kernels need x64, which must be set
-        # before jax runs anything in this process
-        try:
-            import jax
-            jax.config.update("jax_enable_x64", True)
-        except Exception:
-            pass
+    enable_compile_cache()
     root = Path(__file__).resolve().parent.parent
     if args.smoke:
         args.statements = 40

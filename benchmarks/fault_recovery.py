@@ -60,6 +60,7 @@ import numpy as np
 from repro.core import (AdvisorOptions, DesignAdvisor, DurableStore,
                         FaultInjector, WorkloadDelta, base_configuration,
                         make_scaled_workload, make_tpch_like)
+from repro.core.backend import enable_compile_cache
 from repro.serve.advisor_service import (AdvisorFleetService, FleetConfig,
                                          TenantQuarantined, TicketTimeout)
 
@@ -506,6 +507,7 @@ def main() -> int:
                     help="small fast run for CI (parity still asserted "
                     "for every resolved recommendation)")
     args = ap.parse_args()
+    enable_compile_cache()
     root = Path(__file__).resolve().parent.parent
     if args.smoke:
         args.tenants = 6

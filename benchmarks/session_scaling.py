@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import (AdvisorOptions, AdvisorSession, DesignAdvisor,
                         WorkloadDelta, base_configuration,
                         make_scaled_workload, make_tpch_like)
+from repro.core.backend import enable_compile_cache
 
 
 def make_delta(rng: np.random.Generator, wl_cur, drift_pool, k: int,
@@ -178,6 +179,7 @@ def main() -> int:
                     help="small fast run for CI (parity still asserted "
                     "every round; relaxed speedup gate)")
     args = ap.parse_args()
+    enable_compile_cache()
     root = Path(__file__).resolve().parent.parent
     if args.smoke:
         args.statements = 40

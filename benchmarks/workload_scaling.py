@@ -37,6 +37,7 @@ from pathlib import Path
 from repro.core import (AdvisorOptions, DesignAdvisor, base_configuration,
                         chunked_config_costs, make_scaled_workload,
                         make_tpch_like)
+from repro.core.backend import enable_compile_cache
 from repro.core.workload_compression import ClusterIndex
 
 
@@ -219,6 +220,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="small fast run for CI")
     args = ap.parse_args()
+    enable_compile_cache()
     root = Path(__file__).resolve().parent.parent
     if args.smoke:
         args.sizes = [200, 10_000]

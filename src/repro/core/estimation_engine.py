@@ -303,11 +303,15 @@ class EstimationEngine:
         self.batch_calls = 0        # per-(table, f) group batches run
         self.targets_estimated = 0  # total targets sized through the engine
         self.backend_fallbacks = int(fell_back)  # jax requested, numpy ran
+        # codec stacks the jax kernels sent to NumPy for leaving their
+        # int32 exactness envelope
+        self.envelope_reroutes = 0
 
     def stats(self) -> Dict[str, int]:
         return {"batch_calls": self.batch_calls,
                 "targets_estimated": self.targets_estimated,
-                "backend_fallbacks": self.backend_fallbacks}
+                "backend_fallbacks": self.backend_fallbacks,
+                "envelope_reroutes": self.envelope_reroutes}
 
     def estimate_batch(self, targets: Sequence, f: float,
                        bias_correct: bool = True) -> Dict:
@@ -319,6 +323,7 @@ class EstimationEngine:
         for t in targets:
             by_table.setdefault(t.table, []).append(t)
         out: Dict = {}
+        reroutes = _codec_reroutes(self.backend)
         for tname, ts in by_table.items():
             sample = self.manager.get_sample(tname, f)
             ests = batched_sample_cf(
@@ -327,4 +332,13 @@ class EstimationEngine:
             out.update(zip(ts, ests))
             self.batch_calls += 1
             self.targets_estimated += len(ts)
+        self.envelope_reroutes += _codec_reroutes(self.backend) - reroutes
         return out
+
+
+def _codec_reroutes(backend: str) -> int:
+    """Process-wide codec envelope reroutes of the jax kernels."""
+    if backend != "jax":
+        return 0
+    from ..kernels import codec_bytes
+    return codec_bytes.counters()["envelope_reroutes"]

@@ -771,11 +771,16 @@ class AdvisorSession:
         if isinstance(self._sampled_est, EstimateCache):
             out.update(samplecf_cache_evictions=self._sampled_est.evictions,
                        samplecf_cache_maxsize=self._sampled_est.maxsize)
+        peng = self.planner._engine
+        engines = [e for e in (self.engine, self.est_engine, peng)
+                   if e is not None]
+        out["backend_fallbacks"] = sum(e.backend_fallbacks for e in engines)
+        if self.est_engine is not None:
+            out["envelope_reroutes"] = self.est_engine.envelope_reroutes
         if self.engine is not None:
             out.update(engine_rows_added=self.engine.rows_added,
                        engine_rows_removed=self.engine.rows_removed,
                        engine_cols_refreshed=self.engine.cols_refreshed)
-        peng = self.planner._engine
         if peng is not None:
             out.update(graph_builds=peng.graph_builds,
                        rec_builds=peng.rec_builds,

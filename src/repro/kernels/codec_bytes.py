@@ -3,37 +3,48 @@
 These kernels accelerate `repro.core.compression.batched_bytes` — the
 (targets x rows) column stacks that SampleCF and the estimation engine feed
 through the five codec size formulas (NS / GDICT / LDICT / PREFIX / RLE).
-Each kernel is a segment reduce: per target row, reduce the row (NS, GDICT)
-or the (npages, rows_per_page) page grid (LDICT, PREFIX, RLE) down to one
-payload-byte count.
 
-int32-safe rescaling (the old jax path was gated on x64 being enabled;
-these kernels remove that gate):
+Layout.  Every method reduces *segments*: a whole target row for NS and
+GDICT, one page of a target row for LDICT, PREFIX and RLE.  The wrapper
+lays the segments out one per kernel row and edge-pads each row to a
+multiple of 128 lanes with the segment's last value (after the sort, for
+GDICT and LDICT), so a padding lane adds no distinct value, no run and no
+min/max movement; NS masks its padding with the segment's row count.  The
+grid is (row tiles, column tiles): a block holds at most `_BLOCK_ELEMS`
+values per plane at any sample size, and per-row accumulators carry the
+partial count, the running min/max and the previous tile's last value
+across column tiles, so a change that falls on a tile boundary is counted
+exactly once.  Paged methods then sum their per-page bytes per target.
+
+int32-safe arithmetic (no x64, no unsigned reductions):
 
 * Values are split into two uint32 planes ``hi = v >> 32``, ``lo = v & M32``
-  of the uint64 view of the input.  The split is a bijection, so every
-  primitive the codecs need factors exactly through the planes:
+  of the uint64 view of the input, and each plane travels in its
+  order-preserving signed view ``s(u) = (u ^ 0x80000000)`` bitcast to int32.
+  The split is a bijection and the view keeps equality and order, so every
+  primitive the codecs need factors exactly through the two views:
   - equality / adjacent-difference: ``a == b  <=>  a_hi == b_hi and
     a_lo == b_lo`` (GDICT/LDICT ndv counts, RLE run counts);
-  - unsigned order: lexicographic (hi, lo) order equals uint64 order, so
+  - order: lexicographic (hi, lo) order equals uint64 order, so
     ``jax.lax.sort((hi, lo), num_keys=2)`` sorts exactly like the NumPy
     reference's int64 sort for non-negative inputs, and the PREFIX page
     min/max decompose as ``mn_hi = min(hi)``,
-    ``mn_lo = min(lo where hi == mn_hi)`` (dually for max, xor per plane);
+    ``mn_lo = min(lo where hi == mn_hi)`` (dually for max);
+  - xor: ``s(a) ^ s(b) == a ^ b`` — the bias cancels;
   - significant_bytes: ``sig(v) = 4 + sig32(hi)`` if ``hi != 0`` else
-    ``sig32(lo)`` with ``sig32(u) = 1 + [u>=2^8] + [u>=2^16] + [u>=2^24]``.
+    ``sig32(lo)`` with ``sig32(u) = 1 + [u>=2^8] + [u>=2^16] + [u>=2^24]``,
+    each threshold compared in the signed view.
 * All byte-count arithmetic is then small-integer: with widths <= 8 every
   per-row/per-page term is <= ``rows * (width + 3) + PAGE_META``, so the
   final int32 accumulators stay below 2^31 whenever ``n <= 2^25`` rows.
   Inputs outside the proven envelope (negative values — the signed PREFIX
-  min/max would diverge — more rows, or wider columns) fall back to the
-  NumPy reference kernels, so `batched_codec_bytes` is exact for every
-  input.
+  min/max would diverge — more rows, or wider columns) are routed to the
+  NumPy reference kernels and counted in ``counters()["envelope_reroutes"]``,
+  so `batched_codec_bytes` is exact for every input.
 
 Parity contract: bit-identical to `compression.BATCH_KERNELS[method]` —
-asserted by tests/test_pallas_parity.py.  Kernels run under
-``interpret=True`` on CPU (same idiom as kernels/ops.py) and compile for
-TPU unchanged.
+asserted by tests/test_pallas_parity.py; tests/test_tpu_compile.py compiles
+the kernels for a TPU v5e at SampleCF's real sample shapes.
 """
 from __future__ import annotations
 
@@ -44,12 +55,24 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# mirror repro.core.compression.PAGE_META / _ptr_bytes thresholds; imported
-# lazily in the fallback path to avoid a kernels -> core import at load time
+from ..core.backend import pallas_interpret
+
+# mirror repro.core.compression.PAGE_META / _ptr_bytes thresholds; the
+# NumPy references are imported lazily in the reroute path
 _PAGE_META = 16
 _LANES = 128
+_SUBLANES = 8
 _M32 = np.uint64(0xFFFFFFFF)
+_BIAS = np.uint32(0x80000000)
+_IMIN = np.int32(-(2 ** 31))
+_IMAX = np.int32(2 ** 31 - 1)
+
+# block geometry: at most _BLOCK_ELEMS values per plane block (512 KiB of
+# int32) and _MAX_TILE_C lanes per column tile, whatever the sample size
+_BLOCK_ELEMS = 1 << 17
+_MAX_TILE_C = 2048
 
 # envelope of the int32 exactness proof (see module docstring)
 _MAX_ROWS = 1 << 25
@@ -58,22 +81,35 @@ _MAX_WIDTH = 8
 ORD_IND_METHODS = ("NS", "GDICT")
 ORD_DEP_METHODS = ("LDICT", "PREFIX", "RLE")
 
-
-def _use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
+_counters = {"kernel_calls": 0, "envelope_reroutes": 0}
 
 
-def _sig32(u):
-    """Significant bytes (1..4) of a uint32 plane."""
+def counters() -> dict:
+    """Process-wide call counters: Pallas launches and stacks rerouted to
+    NumPy for leaving the int32 envelope."""
+    return dict(_counters)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _signed(k: int):
+    """Signed view of the uint32 constant k."""
+    return jnp.int32(k - (1 << 31))
+
+
+def _sig32(s):
+    """Significant bytes (1..4) of a uint32 plane given in its signed view."""
     return (jnp.int32(1)
-            + (u >= jnp.uint32(1 << 8)).astype(jnp.int32)
-            + (u >= jnp.uint32(1 << 16)).astype(jnp.int32)
-            + (u >= jnp.uint32(1 << 24)).astype(jnp.int32))
+            + (s >= _signed(1 << 8)).astype(jnp.int32)
+            + (s >= _signed(1 << 16)).astype(jnp.int32)
+            + (s >= _signed(1 << 24)).astype(jnp.int32))
 
 
-def _sig64(hi, lo):
-    """significant_bytes of the uint64 value represented by (hi, lo)."""
-    return jnp.where(hi > jnp.uint32(0), 4 + _sig32(hi), _sig32(lo))
+def _sig64(sh, sl):
+    """significant_bytes of the uint64 value with signed-view planes."""
+    return jnp.where(sh != _IMIN, 4 + _sig32(sh), _sig32(sl))
 
 
 def _ptr(ndv):
@@ -81,137 +117,180 @@ def _ptr(ndv):
     return jnp.where(ndv <= 256, 1, jnp.where(ndv <= 65536, 2, 3))
 
 
-def _page_rows(shape, npages: int, rpp: int, last_rows: int):
-    """(TM, npages) int32 rows actually stored in each page."""
-    pg = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return jnp.where(pg == npages - 1, jnp.int32(last_rows), jnp.int32(rpp))
+def _lane_pick(x, mask):
+    """(TR, 1) value of x at the one lane where mask holds."""
+    return jnp.max(jnp.where(mask, x, _IMIN), axis=1, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies.  hi/lo are (TILE_M, n_pad) uint32 planes, w is (TILE_M, 1)
-# int32, out is (TILE_M, 1) int32.
+# The kernel.  hi/lo are (TR, TC) int32 signed views, w and cnt (TR, 1)
+# int32 (width, rows stored in the segment); out is (TR, 1) int32.  `acc`
+# are (TR, 1) int32 VMEM accumulators that live across the column axis.
 # ---------------------------------------------------------------------------
 
-def _ns_kernel(hi_ref, lo_ref, w_ref, out_ref, *, n: int):
-    hi, lo, w = hi_ref[...], lo_ref[...], w_ref[...]
-    sig = jnp.minimum(_sig64(hi, lo), w)
-    half = jnp.minimum(2 * sig + 1, 2 * w)
-    col = jax.lax.broadcasted_iota(jnp.int32, half.shape, 1)
-    half = jnp.where(col < n, half, 0)  # zero-padded lanes contribute nothing
-    out_ref[...] = (jnp.sum(half, axis=1, keepdims=True) + 1) // 2
+def _segment_kernel(hi_ref, lo_ref, w_ref, cnt_ref, out_ref, *acc,
+                    method: str, tile_c: int):
+    j = pl.program_id(1)
+    hi, lo = hi_ref[...], lo_ref[...]
+    w, cnt = w_ref[...], cnt_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 1)
+
+    if method == "NS":
+        (total,) = acc
+        half = jnp.minimum(2 * jnp.minimum(_sig64(hi, lo), w) + 1, 2 * w)
+        half = jnp.where(col + j * tile_c < cnt, half, 0)
+
+        @pl.when(j == 0)
+        def _():
+            total[...] = jnp.zeros_like(total)
+
+        total[...] += jnp.sum(half, axis=1, keepdims=True)
+
+    elif method == "PREFIX":
+        mnh, mnl, mxh, mxl = acc
+
+        @pl.when(j == 0)
+        def _():
+            mnh[...] = jnp.full_like(mnh, _IMAX)
+            mnl[...] = jnp.full_like(mnl, _IMAX)
+            mxh[...] = jnp.full_like(mxh, _IMIN)
+            mxl[...] = jnp.full_like(mxl, _IMIN)
+
+        # this tile's lexicographic (hi, lo) min and max, folded into the
+        # running ones
+        tnh = jnp.min(hi, axis=1, keepdims=True)
+        txh = jnp.max(hi, axis=1, keepdims=True)
+        tnl = jnp.min(jnp.where(hi == tnh, lo, _IMAX), axis=1, keepdims=True)
+        txl = jnp.max(jnp.where(hi == txh, lo, _IMIN), axis=1, keepdims=True)
+        rnh, rnl, rxh, rxl = mnh[...], mnl[...], mxh[...], mxl[...]
+        take_n = (tnh < rnh) | ((tnh == rnh) & (tnl < rnl))
+        take_x = (txh > rxh) | ((txh == rxh) & (txl > rxl))
+        mnh[...] = jnp.where(take_n, tnh, rnh)
+        mnl[...] = jnp.where(take_n, tnl, rnl)
+        mxh[...] = jnp.where(take_x, txh, rxh)
+        mxl[...] = jnp.where(take_x, txl, rxl)
+
+    else:  # GDICT / LDICT / RLE: 1 + number of adjacent changes
+        changes, carry_h, carry_l = acc
+        first = col == 0
+        last = col == tile_c - 1
+        fh, fl = _lane_pick(hi, first), _lane_pick(lo, first)
+        lh, ll = _lane_pick(hi, last), _lane_pick(lo, last)
+
+        @pl.when(j == 0)
+        def _():
+            changes[...] = jnp.ones_like(changes)
+            carry_h[...] = fh
+            carry_l[...] = fl
+
+        # a lane rotation pairs every lane with a neighbour, cyclically, in
+        # either direction; dropping the wrap-around pair leaves exactly
+        # the tile's inner adjacent pairs
+        neq = ((hi != pltpu.roll(hi, 1, 1))
+               | (lo != pltpu.roll(lo, 1, 1))).astype(jnp.int32)
+        wrap = ((fh != lh) | (fl != ll)).astype(jnp.int32)
+        edge = ((fh != carry_h[...]) | (fl != carry_l[...])).astype(jnp.int32)
+        changes[...] += jnp.sum(neq, axis=1, keepdims=True) - wrap + edge
+        carry_h[...] = lh
+        carry_l[...] = ll
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        meta = jnp.int32(_PAGE_META)
+        cap = cnt * w + meta
+        if method == "NS":
+            out_ref[...] = (acc[0][...] + 1) >> 1
+        elif method == "GDICT":
+            ndv = acc[0][...]
+            out_ref[...] = ndv * w + cnt * _ptr(ndv)
+        elif method == "LDICT":
+            ndv = acc[0][...]
+            out_ref[...] = jnp.minimum(ndv * w + cnt * _ptr(ndv) + meta, cap)
+        elif method == "RLE":
+            out_ref[...] = jnp.minimum(acc[0][...] * (w + 2) + meta, cap)
+        else:  # PREFIX
+            xh = acc[0][...] ^ acc[2][...]
+            xl = acc[1][...] ^ acc[3][...]
+            diff = jnp.where((xh | xl) == 0, 0,
+                             _sig64(xh ^ _IMIN, xl ^ _IMIN))
+            common = jnp.maximum(w - diff, 0)
+            out_ref[...] = jnp.minimum(
+                common + cnt * (1 + w - common) + meta, cap)
 
 
-def _gdict_kernel(hi_ref, lo_ref, w_ref, out_ref, *, n: int):
-    # rows arrive sorted and edge-padded with their own max, so padding lanes
-    # never add a distinct value and no mask is needed
-    hi, lo, w = hi_ref[...], lo_ref[...], w_ref[...]
-    neq = (hi[:, 1:] != hi[:, :-1]) | (lo[:, 1:] != lo[:, :-1])
-    ndv = 1 + jnp.sum(neq.astype(jnp.int32), axis=1, keepdims=True)
-    out_ref[...] = ndv * w + n * _ptr(ndv)
+_N_ACC = {"NS": 1, "GDICT": 3, "LDICT": 3, "RLE": 3, "PREFIX": 4}
 
 
-def _ldict_kernel(hi_ref, lo_ref, w_ref, out_ref, *,
-                  npages: int, rpp: int, last_rows: int):
-    tm = hi_ref.shape[0]
-    # rows arrive page-sorted; adjacent inequality within a page counts ndv
-    hi = hi_ref[...].reshape(tm, npages, rpp)
-    lo = lo_ref[...].reshape(tm, npages, rpp)
-    w = w_ref[...]
-    neq = (hi[:, :, 1:] != hi[:, :, :-1]) | (lo[:, :, 1:] != lo[:, :, :-1])
-    ndv = 1 + jnp.sum(neq.astype(jnp.int32), axis=2)        # (TM, npages)
-    rows = _page_rows(ndv.shape, npages, rpp, last_rows)
-    per_page = ndv * w + rows * _ptr(ndv) + _PAGE_META
-    cap = rows * w + _PAGE_META
-    out_ref[...] = jnp.sum(jnp.minimum(per_page, cap), axis=1, keepdims=True)
+def segment_tiles(rows: int, seg: int):
+    """(tile_r, tile_c, rows_pad, cols_pad) for `rows` segments of `seg`
+    values: column tiles of <= _MAX_TILE_C lanes, row tiles of
+    <= _BLOCK_ELEMS values per plane block."""
+    nct = -(-seg // _MAX_TILE_C)
+    tile_c = _round_up(-(-seg // nct), _LANES)
+    tile_r = max(_SUBLANES, min(_BLOCK_ELEMS // tile_c // _SUBLANES
+                                * _SUBLANES, _round_up(rows, _SUBLANES)))
+    return tile_r, tile_c, _round_up(rows, tile_r), nct * tile_c
 
 
-def _prefix_kernel(hi_ref, lo_ref, w_ref, out_ref, *,
-                   npages: int, rpp: int, last_rows: int):
-    tm = hi_ref.shape[0]
-    hi = hi_ref[...].reshape(tm, npages, rpp)
-    lo = lo_ref[...].reshape(tm, npages, rpp)
-    w = w_ref[...]
-    # 64-bit unsigned page min/max through the planes (lexicographic)
-    mnh = jnp.min(hi, axis=2)
-    mxh = jnp.max(hi, axis=2)
-    mnl = jnp.min(jnp.where(hi == mnh[:, :, None], lo,
-                            jnp.uint32(0xFFFFFFFF)), axis=2)
-    mxl = jnp.max(jnp.where(hi == mxh[:, :, None], lo, jnp.uint32(0)), axis=2)
-    xh, xl = mnh ^ mxh, mnl ^ mxl
-    diff = jnp.where((xh | xl) == jnp.uint32(0), 0, _sig64(xh, xl))
-    common = jnp.maximum(w - diff, 0)
-    rows = _page_rows(diff.shape, npages, rpp, last_rows)
-    per_page = common + rows * (1 + w - common) + _PAGE_META
-    cap = rows * w + _PAGE_META
-    out_ref[...] = jnp.sum(jnp.minimum(per_page, cap), axis=1, keepdims=True)
-
-
-def _rle_kernel(hi_ref, lo_ref, w_ref, out_ref, *,
-                npages: int, rpp: int, last_rows: int):
-    tm = hi_ref.shape[0]
-    # unsorted pages: adjacent inequality counts runs; the edge padding
-    # repeats the row's last value so padded lanes never start a run
-    hi = hi_ref[...].reshape(tm, npages, rpp)
-    lo = lo_ref[...].reshape(tm, npages, rpp)
-    w = w_ref[...]
-    neq = (hi[:, :, 1:] != hi[:, :, :-1]) | (lo[:, :, 1:] != lo[:, :, :-1])
-    runs = 1 + jnp.sum(neq.astype(jnp.int32), axis=2)
-    rows = _page_rows(runs.shape, npages, rpp, last_rows)
-    per_page = runs * (w + 2) + _PAGE_META
-    cap = rows * w + _PAGE_META
-    out_ref[...] = jnp.sum(jnp.minimum(per_page, cap), axis=1, keepdims=True)
-
-
-_KERNELS = {
-    "NS": _ns_kernel,
-    "GDICT": _gdict_kernel,
-    "LDICT": _ldict_kernel,
-    "PREFIX": _prefix_kernel,
-    "RLE": _rle_kernel,
-}
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "method", "n", "rpp", "tile_m", "interpret"))
-def _codec_call(hi, lo, w, *, method: str, n: int, rpp: int,
-                tile_m: int, interpret: bool):
-    m_pad, n_pad = hi.shape
-    if method == "GDICT":
-        hi, lo = jax.lax.sort((hi, lo), dimension=1, num_keys=2)
-        body = functools.partial(_gdict_kernel, n=n)
-    elif method == "NS":
-        body = functools.partial(_ns_kernel, n=n)
-    else:
-        npages = n_pad // rpp
-        last_rows = n - (npages - 1) * rpp
-        if method == "LDICT":
-            h3 = hi.reshape(m_pad, npages, rpp)
-            l3 = lo.reshape(m_pad, npages, rpp)
-            h3, l3 = jax.lax.sort((h3, l3), dimension=2, num_keys=2)
-            hi, lo = h3.reshape(m_pad, n_pad), l3.reshape(m_pad, n_pad)
-        body = functools.partial(_KERNELS[method], npages=npages, rpp=rpp,
-                                 last_rows=last_rows)
-    grid = (m_pad // tile_m,)
+def segment_call(hi, lo, w, cnt, *, method: str, tile_r: int, tile_c: int,
+                 interpret: bool):
+    """Per-segment payload bytes of (rows_pad, cols_pad) signed-view planes
+    laid out as the module docstring says; returns (rows_pad, 1) int32."""
+    r_pad, c_pad = hi.shape
+    row = pl.BlockSpec((tile_r, 1), lambda i, j: (i, 0))
+    plane = pl.BlockSpec((tile_r, tile_c), lambda i, j: (i, j))
     return pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_m, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((tile_m, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((tile_m, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((tile_m, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m_pad, 1), jnp.int32)],
+        functools.partial(_segment_kernel, method=method, tile_c=tile_c),
+        grid=(r_pad // tile_r, c_pad // tile_c),
+        in_specs=[plane, plane, row, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((r_pad, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((tile_r, 1), jnp.int32)
+                        for _ in range(_N_ACC[method])],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(hi, lo, w)[0]
+    )(hi, lo, w, cnt)
 
 
-def _pad_rows(a: np.ndarray, m_pad: int, fill) -> np.ndarray:
-    m = a.shape[0]
-    if m_pad == m:
-        return a
-    pad = np.full((m_pad - m,) + a.shape[1:], fill, dtype=a.dtype)
-    return np.concatenate([a, pad], axis=0)
+def _edge_pad(x, width: int):
+    if x.shape[1] == width:
+        return x
+    return jnp.pad(x, ((0, 0), (0, width - x.shape[1])), mode="edge")
+
+
+@functools.partial(jax.jit, static_argnames=("method", "rpp", "interpret"))
+def _codec_call(hi, lo, w, *, method: str, rpp: int, interpret: bool):
+    """(m,) int32 payload bytes of (m, n) signed-view planes; rpp is the
+    page length (<= n) for paged methods and ignored otherwise."""
+    m, n = hi.shape
+    if method in ORD_IND_METHODS:
+        seg, nseg = n, 1
+        cnt = np.full(1, n, dtype=np.int32)
+    else:  # one page per row, edge-padded to whole pages (== _pages_batch)
+        seg, nseg = rpp, -(-n // rpp)
+        hi = _edge_pad(hi, nseg * seg).reshape(m * nseg, seg)
+        lo = _edge_pad(lo, nseg * seg).reshape(m * nseg, seg)
+        cnt = np.full(nseg, seg, dtype=np.int32)
+        cnt[-1] = n - (nseg - 1) * seg
+    if method in ("GDICT", "LDICT"):
+        hi, lo = jax.lax.sort((hi, lo), dimension=1, num_keys=2)
+    rows = m * nseg
+    tile_r, tile_c, r_pad, c_pad = segment_tiles(rows, seg)
+    pad_rows = ((0, r_pad - rows), (0, 0))
+    hi = jnp.pad(_edge_pad(hi, c_pad), pad_rows)
+    lo = jnp.pad(_edge_pad(lo, c_pad), pad_rows)
+    w_seg = jnp.pad(jnp.repeat(w, nseg), (0, r_pad - rows),
+                    constant_values=1)[:, None]
+    cnt_seg = np.pad(np.tile(cnt, m), (0, r_pad - rows),
+                     constant_values=1)[:, None]
+    out = segment_call(hi, lo, w_seg, jnp.asarray(cnt_seg), method=method,
+                       tile_r=tile_r, tile_c=tile_c, interpret=interpret)
+    return out[:rows, 0].reshape(m, nseg).sum(axis=1)
+
+
+def _signed_view(u: np.ndarray) -> np.ndarray:
+    return (u ^ _BIAS).view(np.int32)
 
 
 def in_envelope(cols: np.ndarray, widths: np.ndarray) -> bool:
@@ -227,7 +306,8 @@ def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
 
     cols is an (ntargets, nrows) int64 stack, widths (ntargets,), rpp the
     shared rows-per-page.  Inputs outside the int32 exactness envelope are
-    routed to the NumPy reference so the result is exact unconditionally.
+    routed to the NumPy reference (and counted) so the result is exact
+    unconditionally.
     """
     cols = np.asarray(cols, dtype=np.int64)
     widths = np.asarray(widths, dtype=np.int64)
@@ -235,38 +315,21 @@ def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
     if m == 0 or n == 0:
         return np.zeros(m, dtype=np.int64)
     if not in_envelope(cols, widths):
+        _counters["envelope_reroutes"] += 1
         from ..core import compression as _comp
         return _comp.BATCH_KERNELS[method](cols, widths, rpp)
 
-    # pad the rows axis for the kernel's needs, then split uint32 planes
-    if method == "NS":
-        n_pad = -(-n // _LANES) * _LANES
-        if n_pad != n:
-            cols = np.concatenate(
-                [cols, np.zeros((m, n_pad - n), dtype=np.int64)], axis=1)
-    elif method == "GDICT":
-        n_pad = -(-n // _LANES) * _LANES
-        if n_pad != n:
-            cols = np.concatenate(
-                [cols, np.repeat(cols[:, -1:], n_pad - n, axis=1)], axis=1)
-    else:  # paged: edge-pad to a whole number of pages (== _pages_batch)
-        npages = -(-n // rpp)
-        n_pad = npages * rpp
-        if n_pad != n:
-            cols = np.concatenate(
-                [cols, np.repeat(cols[:, -1:], n_pad - n, axis=1)], axis=1)
-
-    u = cols.astype(np.uint64)
-    hi = (u >> np.uint64(32)).astype(np.uint32)
-    lo = (u & _M32).astype(np.uint32)
-
-    m_pad = -(-m // 8) * 8
-    tile_m = next(t for t in (64, 32, 16, 8) if m_pad % t == 0)
-    hi = _pad_rows(hi, m_pad, 0)
-    lo = _pad_rows(lo, m_pad, 0)
-    w = _pad_rows(widths.astype(np.int32)[:, None], m_pad, 1)
-
+    # pad the target axis to whole sublanes (bounds the compiled-shape
+    # count; pad rows are dropped) and split the signed-view planes
+    m_pad = _round_up(m, _SUBLANES)
+    u = np.zeros((m_pad, n), dtype=np.uint64)
+    u[:m] = cols
+    w = np.ones(m_pad, dtype=np.int32)
+    w[:m] = widths
+    hi = _signed_view((u >> np.uint64(32)).astype(np.uint32))
+    lo = _signed_view((u & _M32).astype(np.uint32))
+    page = min(int(rpp), n) if method in ORD_DEP_METHODS else 0
     out = _codec_call(jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(w),
-                      method=method, n=n, rpp=int(rpp), tile_m=tile_m,
-                      interpret=_use_interpret())
-    return np.asarray(out, dtype=np.int64)[:m, 0]
+                      method=method, rpp=page, interpret=pallas_interpret())
+    _counters["kernel_calls"] += 1
+    return np.asarray(out, dtype=np.int64)[:m]

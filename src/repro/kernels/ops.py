@@ -1,6 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
-* Auto-select interpret mode on CPU (the kernels TARGET TPU; interpret=True
+* Interpret mode only where jax's default backend is the CPU
+  (`core.backend.pallas_interpret`; the kernels TARGET TPU, interpret=True
   executes the kernel body in Python for correctness validation).
 * Handle arbitrary-rank inputs by flattening leading dims and padding the
   last dim to tile multiples, so the optimizer / KV cache / checkpoint
@@ -14,15 +15,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core.backend import pallas_interpret
 from . import ref
 from .dequant_matmul import dequant_matmul as _dequant_matmul_pallas
 from .quantize_blockwise import (dequantize_blockwise_2d,
                                  quantize_blockwise_2d)
 from .ref import DEFAULT_BLOCK
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _to_2d(x: jnp.ndarray, block: int):
@@ -63,7 +61,7 @@ def quantize_blockwise(x: jnp.ndarray, block: int = DEFAULT_BLOCK,
         if flat.shape[1] % cand == 0 and cand % block == 0:
             tile_n = cand
             break
-    q, s = quantize_blockwise_2d(flat, block, interpret=_use_interpret(),
+    q, s = quantize_blockwise_2d(flat, block, interpret=pallas_interpret(),
                                  tile_m=tile_m, tile_n=tile_n)
     q = q[:, :n].reshape(x.shape)
     s = s[:, :nb].reshape(x.shape[:-1] + (nb,))
@@ -95,7 +93,7 @@ def dequantize_blockwise(q: jnp.ndarray, scales: jnp.ndarray,
             tile_n = cand
             break
     out = dequantize_blockwise_2d(flat, sflat, block, dtype,
-                                  interpret=_use_interpret(),
+                                  interpret=pallas_interpret(),
                                   tile_m=tile_m, tile_n=tile_n)
     return out[:, :n].reshape(q.shape)
 
@@ -120,5 +118,5 @@ def dequant_matmul(a: jnp.ndarray, qw: jnp.ndarray, scales: jnp.ndarray,
             tile_n = cand
             break
     return _dequant_matmul_pallas(a, qw, scales, block,
-                                  interpret=_use_interpret(),
+                                  interpret=pallas_interpret(),
                                   tile_m=tile_m, tile_n=tile_n)

@@ -43,13 +43,45 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core.backend import pallas_interpret
+
 _LANES = 128
 _SQRT2_F32 = np.float32(math.sqrt(2.0))
 _BIG = np.int32(2 ** 31 - 1)  # "no winner" sentinel for the argmin outputs
 
+_counters = {"prob_calls": 0, "fused_calls": 0}
 
-def _use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
+
+def counters() -> dict:
+    """Process-wide kernel launch counts."""
+    return dict(_counters)
+
+
+# Rational float32 erf (the Eigen/XLA f32 approximation): Mosaic has no
+# erf lowering, but lowers this clamp + two Horner polynomials + divide.
+# |erf_f32(x) - erf(x)| < 5e-7 over a dense grid; beyond |x| = 4 erf is
+# +-1 in float32.
+_ERF_ALPHA = tuple(np.float32(c) for c in (
+    -2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+    -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+    -1.60960333262415e-02))
+_ERF_BETA = tuple(np.float32(c) for c in (
+    -1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+    -7.37332916720468e-03, -1.42647390514189e-02))
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def erf_f32(x):
+    """float32 erf from ops every backend lowers (see _ERF_ALPHA)."""
+    x = jnp.clip(x, jnp.float32(-4.0), jnp.float32(4.0))
+    x2 = x * x
+    return x * _horner(x2, _ERF_ALPHA) / _horner(x2, _ERF_BETA)
 
 
 def _prob_expr(cm, cs, e: float):
@@ -60,9 +92,9 @@ def _prob_expr(cm, cs, e: float):
     small = cs <= jnp.float32(1e-12)
     s = jnp.where(small, jnp.float32(1.0), cs)
     phi_hi = jnp.float32(0.5) * (jnp.float32(1.0)
-                                 + jax.lax.erf((hi - cm) / s / _SQRT2_F32))
+                                 + erf_f32((hi - cm) / s / _SQRT2_F32))
     phi_lo = jnp.float32(0.5) * (jnp.float32(1.0)
-                                 + jax.lax.erf((lo - cm) / s / _SQRT2_F32))
+                                 + erf_f32((lo - cm) / s / _SQRT2_F32))
     ind = ((cm >= lo) & (cm <= hi)).astype(jnp.float32)
     return jnp.where(small, ind, phi_hi - phi_lo)
 
@@ -127,7 +159,8 @@ def prob_within(means: np.ndarray, stds: np.ndarray, e: float) -> np.ndarray:
     mp[0, :n] = means.ravel()
     sp[0, :n] = stds.ravel()
     out = _prob_call(jnp.asarray(mp), jnp.asarray(sp), e=float(e),
-                     interpret=_use_interpret())
+                     interpret=pallas_interpret())
+    _counters["prob_calls"] += 1
     return np.asarray(out, dtype=np.float64)[0, :n].reshape(means.shape)
 
 
@@ -235,7 +268,8 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
         jnp.asarray(mp), jnp.asarray(sp), jnp.asarray(dmp), jnp.asarray(vtp),
         jnp.asarray(mqp), jnp.asarray(m67p), jnp.asarray(p9p),
         jnp.asarray(exp_), k=k, e=float(e), q=float(q),
-        interpret=_use_interpret())
+        interpret=pallas_interpret())
+    _counters["fused_calls"] += 1
     return (np.asarray(cm, dtype=np.float64)[:nc, :nf],
             np.asarray(cs, dtype=np.float64)[:nc, :nf],
             np.asarray(p, dtype=np.float64)[:nc, :nf],

@@ -110,7 +110,9 @@ class TestCodecBitEquality:
         neg = RNG.integers(-1000, 1000, size=(2, 30))
         neg[0, 0] = -5
         assert not ck.in_envelope(neg, np.array([4, 4]))
+        before = ck.counters()["envelope_reroutes"]
         assert_codec_exact(method, neg, np.array([4, 4]), 8)
+        assert ck.counters()["envelope_reroutes"] == before + 1
 
     @pytest.mark.parametrize("method", METHODS)
     def test_empty_stack(self, method):
@@ -118,6 +120,89 @@ class TestCodecBitEquality:
             method, np.zeros((0, 5), dtype=np.int64),
             np.zeros(0, dtype=np.int64), 4)
         assert got.shape == (0,)
+
+    @pytest.mark.parametrize("method", METHODS)
+    @pytest.mark.parametrize("rpp", [273, 682, 1638])
+    def test_unaligned_rows_per_page(self, method, rpp):
+        # rows-per-page values of real index widths that are not lane
+        # multiples, with a partial last page
+        cols = RNG.integers(0, 1 << 12, size=(3, 3 * rpp + 101))
+        cols[1] = np.sort(cols[1])          # page-local runs and dictionaries
+        assert_codec_exact(method, cols, np.array([2, 4, 8]), rpp)
+
+    @pytest.mark.parametrize("method", METHODS)
+    @pytest.mark.parametrize("n,rpp", [
+        (5000, 4),      # NS/GDICT: column tiles; paged: row tiles
+        (2500, 1),      # paged: 7500 one-row pages over several row tiles
+        (7000, 3000),   # paged: pages wider than one column tile
+    ])
+    def test_multi_tile(self, method, n, rpp):
+        m = 3
+        seg = n if method in ck.ORD_IND_METHODS else min(rpp, n)
+        rows = m * (-(-n // seg))
+        tile_r, tile_c, r_pad, c_pad = ck.segment_tiles(rows, seg)
+        assert r_pad // tile_r > 1 or c_pad // tile_c > 1
+        cols = np.stack([
+            RNG.integers(0, 1 << 20, size=n),
+            np.repeat(np.arange(-(-n // 37)), 37)[:n],    # runs across tiles
+            np.sort(RNG.integers(0, 50, size=n)),
+        ])
+        assert_codec_exact(method, cols, np.array([3, 4, 8]), rpp)
+
+    @pytest.mark.parametrize("method", METHODS)
+    def test_change_at_tile_boundary(self, method):
+        """Column tile boundaries that fall inside a run of equal values
+        (GDICT's sorted rows, RLE's runs) or exactly on a change: each
+        counted once."""
+        n = 5000
+        _, tile_c, _, c_pad = ck.segment_tiles(8, n)
+        assert c_pad // tile_c > 1
+        run = np.arange(n, dtype=np.int64)
+        run[tile_c - 40:tile_c + 40] = tile_c        # run across the boundary
+        step = np.arange(n, dtype=np.int64) // tile_c  # change on it
+        edge = np.zeros(n, dtype=np.int64)
+        edge[tile_c:] = 1                              # one change, on it
+        cols = np.stack([run, step, edge, run[::-1].copy()])
+        assert_codec_exact(method, cols, np.array([4, 2, 1, 8]), n)
+        assert_codec_exact(method, cols, np.array([4, 2, 1, 8]), 2 * tile_c)
+
+    @pytest.mark.parametrize("method", METHODS)
+    def test_plane_extremes(self, method):
+        """Signed-view min/max and significant bytes at the extremes of
+        both uint32 planes (lo's sign bit, all-ones lo, the largest hi)."""
+        top = (1 << 63) - 1
+        vals = np.array([
+            0, 1, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF, 1 << 32,
+            (1 << 32) + 0xFFFFFFFF, (0x7FFFFFFF << 32), top,
+            (0x7FFFFFFF << 32) | 0x80000000, 0x80000001, 0x7FFFFFFE,
+        ], dtype=np.int64)
+        pairs = np.stack([np.roll(vals, k) for k in range(len(vals))])
+        cols = np.concatenate([pairs, pairs[:, ::-1]], axis=0)
+        widths = np.full(cols.shape[0], 8)
+        for rpp in (2, 3, 12):
+            assert_codec_exact(method, cols, widths, rpp)
+        # pages of two lo-plane values straddling the sign bit
+        lo_mix = np.tile(np.array([0x7FFFFFFF, 0x80000000], dtype=np.int64),
+                         (2, 8))
+        lo_mix[1] += 5 << 32
+        assert_codec_exact(method, lo_mix, np.array([4, 8]), 2)
+
+    @pytest.mark.parametrize("method", METHODS)
+    def test_min_max_fold_across_tiles(self, method):
+        """A page wider than one column tile whose min (or max) sits in a
+        later tile with the same hi plane: the running lexicographic
+        min/max must take it on lo alone."""
+        n = 3000
+        _, tile_c, _, c_pad = ck.segment_tiles(8, n)
+        assert c_pad // tile_c > 1
+        low = np.full(n, 0x10000, dtype=np.int64)
+        low[tile_c:] = 0x1FFFF
+        low[-1] = 0xFFFF                 # min in the later tile
+        high = np.full(n, 0x1FFFF, dtype=np.int64)
+        high[tile_c:] = 0x10000
+        high[-1] = 0x2FFFF               # max in the later tile
+        cols = np.stack([low, high, low + (5 << 32), high + (5 << 32)])
+        assert_codec_exact(method, cols, np.array([4, 4, 8, 8]), n)
 
     def test_dispatcher_routes_jax_backend(self):
         cols = RNG.integers(0, 1 << 10, size=(6, 90))
@@ -172,6 +257,18 @@ def staged_reference(m, s, dm, vt, mq, mask, e):
     ii = mask.nonzero()
     p[ii] = err.prob_within_batch(cm[ii], cs[ii], e)
     return cm, cs, p
+
+
+class TestErfF32:
+    def test_against_math_erf(self):
+        import math
+        x = np.linspace(-6.0, 6.0, 4001)
+        got = np.asarray(ps.erf_f32(np.float32(x)), dtype=np.float64)
+        want = np.array([math.erf(v) for v in np.float32(x).tolist()])
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        # odd, and saturated beyond the clamp
+        np.testing.assert_array_equal(got, -got[::-1])
+        assert got[0] == -1.0 and got[-1] == 1.0
 
 
 class TestProbWithin:
