@@ -26,6 +26,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import candidates as cand
+from . import tracing
 from .compression import DEFAULT_ADVISOR_METHODS
 from .cost_engine import CostEngine
 from .enumeration import (EnumerationResult, greedy_enumerate,
@@ -115,6 +116,7 @@ def pool_with_merged(pool: Dict[Tuple, IndexDef],
     return pool
 
 
+@tracing.traced("advisor.enumerate")
 def enumerate_pool(optimizer, sizes, options: AdvisorOptions,
                    pool: Dict[Tuple, IndexDef], base: Configuration,
                    budget_bytes: float,
@@ -185,6 +187,7 @@ class DesignAdvisor:
             for q in self.workload.queries()
         }
 
+    @tracing.traced("advisor.candidates")
     def _candidate_universe(self) -> Tuple[Dict[str, List[IndexDef]],
                                            List[IndexDef], List[IndexDef]]:
         """One pass over candidate generation + compression expansion.
@@ -239,6 +242,7 @@ class DesignAdvisor:
             tkey_to_defs.setdefault(k, []).append(idx)
         return tkey_to_defs
 
+    @tracing.traced("advisor.estimate")
     def estimate_sizes(self, all_cands: Sequence[IndexDef]
                        ) -> Tuple[float, Optional[Plan], int, int]:
         """Register estimated sizes for every compressed candidate."""
@@ -321,11 +325,12 @@ class DesignAdvisor:
         per_query_exp, merged_all, all_cands = self._candidate_universe()
         est_cost, plan, n_s, n_d = self.estimate_sizes(all_cands)
 
-        engine = self.build_engine()
-        base_cost = (engine.config_cost(base) if engine is not None
-                     else self.optimizer.workload_cost(base))
-        pool, n_cand = self.select_pool(per_query_exp, merged_all, base,
-                                        engine)
+        with tracing.span("advisor.cost"):
+            engine = self.build_engine()
+            base_cost = (engine.config_cost(base) if engine is not None
+                         else self.optimizer.workload_cost(base))
+            pool, n_cand = self.select_pool(per_query_exp, merged_all, base,
+                                            engine)
         res = self.enumerate_pool(pool, base, budget_bytes, engine)
         n_full = len(self.workload.statements)
         return Recommendation(
@@ -337,6 +342,7 @@ class DesignAdvisor:
             steps=res.steps, n_statements_full=n_full,
             n_representatives=n_full)
 
+    @tracing.traced("advisor.recommend", request=True)
     def recommend(self, budget_bytes: float) -> Recommendation:
         """Full recommendation; with `opt.compression_budget` set (and
         below the statement count) the pipeline runs on the compressed

@@ -49,7 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import compression, distinct, errors
+from . import compression, distinct, errors, tracing
 from .backend import resolve as _resolve
 from .relation import IndexDef, Table, rows_per_page, uncompressed_pages
 from .samplecf import SampleManager, SizeEstimate
@@ -63,6 +63,7 @@ def _resolve_backend(backend: str) -> str:
     return _resolve(backend, site="estimation_engine")[0]
 
 
+@tracing.traced("samplecf.permute")
 def _prefix_permutations(sample: Table,
                          prefixes: Sequence[Tuple[str, ...]]
                          ) -> Dict[Tuple[str, ...], np.ndarray]:
@@ -132,6 +133,7 @@ def _prefix_permutations(sample: Table,
     return out
 
 
+@tracing.traced("estimate.samplecf")
 def batched_sample_cf(table: Table, sample: Table,
                       specs: Sequence[TargetSpec], f: float,
                       bias_correct: bool = True,

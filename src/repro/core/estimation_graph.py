@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import deduction as ded
 from . import errors as err
+from . import tracing
 from .compression import METHODS
 from .estimation_engine import EstimationEngine
 from .relation import IndexDef, Table, uncompressed_pages
@@ -376,6 +377,7 @@ class EstimationPlanner:
         return Plan(f=f, nodes=nodes, targets=tuple(targets),
                     total_cost=total_cost, feasible=feasible)
 
+    @tracing.traced("estimate.plan")
     def plan(self, targets: Sequence[NodeKey], e: float, q: float,
              f_grid: Sequence[float] = F_GRID) -> Plan:
         """Outer loop over sampling fractions (§5.2 last paragraph).
@@ -404,6 +406,7 @@ class EstimationPlanner:
         finally:
             self.use_engine = saved
 
+    @tracing.traced("estimate.plan")
     def plan_all_sampled(self, targets: Sequence[NodeKey], e: float,
                          q: float, f_grid: Sequence[float] = F_GRID) -> Plan:
         """The paper's "All" baseline: SampleCF on every target, no
@@ -584,6 +587,7 @@ class EstimationPlanner:
                     local[k] = cache[(k, plan.f)] = est
         return self._resolve_plan(plan, local.__getitem__)
 
+    @tracing.traced("estimate.resolve")
     def _resolve_plan(self, plan: Plan, sampled_est
                       ) -> Dict[NodeKey, SizeEstimate]:
         out: Dict[NodeKey, SizeEstimate] = {}

@@ -18,7 +18,7 @@ from typing import Dict, Iterator, MutableMapping, Optional, Tuple
 
 import numpy as np
 
-from . import compression
+from . import compression, tracing
 from .relation import (IndexDef, Table, build_index_data, rows_per_page,
                        uncompressed_pages)
 
@@ -173,12 +173,13 @@ class SampleManager:
     def get_sample(self, table_name: str, f: float) -> Table:
         key = (table_name, round(f, 6))
         if key not in self._samples:
-            t = self.tables[table_name]
-            n = max(2, int(round(t.nrows * f)))
-            n = min(n, t.nrows)
-            rng = self._rng_for(table_name, f)
-            rows = rng.choice(t.nrows, size=n, replace=False)
-            self._samples[key] = t.take(np.sort(rows))
+            with tracing.span("estimate.sample"):
+                t = self.tables[table_name]
+                n = max(2, int(round(t.nrows * f)))
+                n = min(n, t.nrows)
+                rng = self._rng_for(table_name, f)
+                rows = rng.choice(t.nrows, size=n, replace=False)
+                self._samples[key] = t.take(np.sort(rows))
             self.sampling_calls += 1
         return self._samples[key]
 

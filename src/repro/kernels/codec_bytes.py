@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import tracing
 from ..core.backend import pallas_interpret
 
 # mirror repro.core.compression.PAGE_META / _ptr_bytes thresholds; the
@@ -81,12 +82,15 @@ _MAX_WIDTH = 8
 ORD_IND_METHODS = ("NS", "GDICT")
 ORD_DEP_METHODS = ("LDICT", "PREFIX", "RLE")
 
-_counters = {"kernel_calls": 0, "envelope_reroutes": 0}
+_counters = {"kernel_calls": 0, "envelope_reroutes": 0, "h2d_bytes": 0,
+             "d2h_bytes": 0}
 
 
 def counters() -> dict:
-    """Process-wide call counters: Pallas launches and stacks rerouted to
-    NumPy for leaving the int32 envelope."""
+    """Process-wide call counters: Pallas launches, stacks rerouted to
+    NumPy for leaving the int32 envelope, and the bytes of the arrays the
+    launches sent to the device (`h2d_bytes`) and read back
+    (`d2h_bytes`)."""
     return dict(_counters)
 
 
@@ -250,6 +254,7 @@ def segment_call(hi, lo, w, cnt, *, method: str, tile_r: int, tile_c: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=f"codec_{method.lower()}",
     )(hi, lo, w, cnt)
 
 
@@ -300,6 +305,7 @@ def in_envelope(cols: np.ndarray, widths: np.ndarray) -> bool:
             and (m == 0 or n == 0 or int(cols.min()) >= 0))
 
 
+@tracing.traced("kernel.codec")
 def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
                         rpp: int) -> np.ndarray:
     """Pallas twin of compression.BATCH_KERNELS[method] — bit-identical.
@@ -332,4 +338,6 @@ def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
     out = _codec_call(jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(w),
                       method=method, rpp=page, interpret=pallas_interpret())
     _counters["kernel_calls"] += 1
+    _counters["h2d_bytes"] += hi.nbytes + lo.nbytes + w.nbytes
+    _counters["d2h_bytes"] += out.nbytes
     return np.asarray(out, dtype=np.int64)[:m]

@@ -43,17 +43,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core import tracing
 from ..core.backend import pallas_interpret
 
 _LANES = 128
 _SQRT2_F32 = np.float32(math.sqrt(2.0))
 _BIG = np.int32(2 ** 31 - 1)  # "no winner" sentinel for the argmin outputs
 
-_counters = {"prob_calls": 0, "fused_calls": 0}
+_counters = {"prob_calls": 0, "fused_calls": 0, "h2d_bytes": 0,
+             "d2h_bytes": 0}
 
 
 def counters() -> dict:
-    """Process-wide kernel launch counts."""
+    """Process-wide kernel launch counts, and the bytes of the arrays the
+    launches sent to the device (`h2d_bytes`) and read back
+    (`d2h_bytes`)."""
     return dict(_counters)
 
 
@@ -141,9 +145,11 @@ def _prob_call(m, s, *, e: float, interpret: bool):
         out_specs=[pl.BlockSpec(m.shape, lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(m.shape, jnp.float32)],
         interpret=interpret,
+        name="planner_prob",
     )(m, s)[0]
 
 
+@tracing.traced("kernel.planner")
 def prob_within(means: np.ndarray, stds: np.ndarray, e: float) -> np.ndarray:
     """Pallas twin of errors.prob_within_batch (float32).  Accepts any
     shape; pads to pow2 lane multiples to bound the compiled-shape count
@@ -161,6 +167,8 @@ def prob_within(means: np.ndarray, stds: np.ndarray, e: float) -> np.ndarray:
     out = _prob_call(jnp.asarray(mp), jnp.asarray(sp), e=float(e),
                      interpret=pallas_interpret())
     _counters["prob_calls"] += 1
+    _counters["h2d_bytes"] += mp.nbytes + sp.nbytes
+    _counters["d2h_bytes"] += out.nbytes
     return np.asarray(out, dtype=np.float64)[0, :n].reshape(means.shape)
 
 
@@ -221,6 +229,7 @@ def _fused_call(m, s, dm, vt, mq, m67, p9, ex, *, k: int, e: float,
                    jax.ShapeDtypeStruct((1, nf), jnp.int32),
                    jax.ShapeDtypeStruct((1, nf), jnp.int32)],
         interpret=interpret,
+        name="planner_fused_score",
     )(m, s, dm, vt, mq, m67, p9, ex)
 
 
@@ -232,6 +241,7 @@ def _pad_axis(a: np.ndarray, axis: int, size: int, fill) -> np.ndarray:
     return np.concatenate([a, np.full(shape, fill, dtype=a.dtype)], axis=axis)
 
 
+@tracing.traced("kernel.planner")
 def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
                 vt: np.ndarray, mq: np.ndarray, mask67: np.ndarray,
                 pre9, extra, e: float, q: float):
@@ -264,12 +274,13 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
     p9p = prep(z, 0, np.int32)
     exp_ = prep(x, 0.0, np.float32)
 
-    cm, cs, p, w6, w9 = _fused_call(
-        jnp.asarray(mp), jnp.asarray(sp), jnp.asarray(dmp), jnp.asarray(vtp),
-        jnp.asarray(mqp), jnp.asarray(m67p), jnp.asarray(p9p),
-        jnp.asarray(exp_), k=k, e=float(e), q=float(q),
-        interpret=pallas_interpret())
+    args = (mp, sp, dmp, vtp, mqp, m67p, p9p, exp_)
+    outs = _fused_call(*(jnp.asarray(a) for a in args), k=k, e=float(e),
+                       q=float(q), interpret=pallas_interpret())
+    cm, cs, p, w6, w9 = outs
     _counters["fused_calls"] += 1
+    _counters["h2d_bytes"] += sum(a.nbytes for a in args)
+    _counters["d2h_bytes"] += sum(o.nbytes for o in outs)
     return (np.asarray(cm, dtype=np.float64)[:nc, :nf],
             np.asarray(cs, dtype=np.float64)[:nc, :nf],
             np.asarray(p, dtype=np.float64)[:nc, :nf],

@@ -13,6 +13,7 @@ and the persistent compilation cache is off around these compiles (a
 compile for a described chip cannot be read back without one).
 """
 import functools
+import re
 
 import pytest
 
@@ -108,3 +109,25 @@ def test_fused_score_compiles(one_chip):
                                   q=0.9, interpret=False),
                 stack, stack, col, col, col, ((nc, nf), i32),
                 ((nc, nf), i32), ((nc, nf), f32))
+
+
+@pytest.mark.parametrize("kernel,fn,shapes", [
+    ("codec_ldict",
+     functools.partial(ck._codec_call, method="LDICT", rpp=682,
+                       interpret=False),
+     [((8, 6000), jnp.int32)] * 2 + [((8,), jnp.int32)]),
+    ("codec_ns",
+     functools.partial(ck._codec_call, method="NS", rpp=0, interpret=False),
+     [((8, 6000), jnp.int32)] * 2 + [((8,), jnp.int32)]),
+    ("planner_prob",
+     functools.partial(ps._prob_call, e=0.5, interpret=False),
+     [((1, 128), jnp.float32)] * 2),
+    ("planner_fused_score",
+     functools.partial(ps._fused_call, k=2, e=0.5, q=0.9, interpret=False),
+     [((8, 256), jnp.float32)] * 2 + [((8, 1), jnp.float32)] * 3
+     + [((8, 128), jnp.int32)] * 2 + [((8, 128), jnp.float32)])])
+def test_kernels_carry_their_names(one_chip, kernel, fn, shapes):
+    """Each Pallas call is named, so that a trace's device operations say
+    which kernel ran."""
+    text = compile_for(one_chip, fn, *shapes).as_text()
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]* custom-call\(", text)
