@@ -19,10 +19,14 @@ exactly once.  Paged methods then sum their per-page bytes per target.
 int32-safe arithmetic (no x64, no unsigned reductions):
 
 * Values are split into two uint32 planes ``hi = v >> 32``, ``lo = v & M32``
-  of the uint64 view of the input, and each plane travels in its
-  order-preserving signed view ``s(u) = (u ^ 0x80000000)`` bitcast to int32.
-  The split is a bijection and the view keeps equality and order, so every
-  primitive the codecs need factors exactly through the two views:
+  of the uint64 view of the input, each in its order-preserving signed view
+  ``s(u) = (u ^ 0x80000000)`` bitcast to int32.  The split happens on the
+  device: the (m, n) int64 stack travels as its own bytes, an (m, 2n) int32
+  view with ``lo`` on the even lanes and ``hi`` on the odd ones (the host
+  is little-endian), and `_codec_call` takes the two lane-strided halves
+  and XORs each with the bias.  The split is a bijection and the view keeps
+  equality and order, so every primitive the codecs need factors exactly
+  through the two views:
   - equality / adjacent-difference: ``a == b  <=>  a_hi == b_hi and
     a_lo == b_lo`` (GDICT/LDICT ndv counts, RLE run counts);
   - order: lexicographic (hi, lo) order equals uint64 order, so
@@ -65,8 +69,6 @@ from ..core.backend import pallas_interpret
 _PAGE_META = 16
 _LANES = 128
 _SUBLANES = 8
-_M32 = np.uint64(0xFFFFFFFF)
-_BIAS = np.uint32(0x80000000)
 _IMIN = np.int32(-(2 ** 31))
 _IMAX = np.int32(2 ** 31 - 1)
 
@@ -265,10 +267,15 @@ def _edge_pad(x, width: int):
 
 
 @functools.partial(jax.jit, static_argnames=("method", "rpp", "interpret"))
-def _codec_call(hi, lo, w, *, method: str, rpp: int, interpret: bool):
-    """(m,) int32 payload bytes of (m, n) signed-view planes; rpp is the
-    page length (<= n) for paged methods and ignored otherwise."""
-    m, n = hi.shape
+def _codec_call(x, w, *, method: str, rpp: int, interpret: bool):
+    """(m,) int32 payload bytes of an (m, n) stack given as its (m, 2n)
+    int32 words (lo on even lanes, hi on odd); rpp is the page length
+    (<= n) for paged methods and ignored otherwise.  The target axis needs
+    no pad of its own: the segment rows are padded to whole row tiles
+    (weight 1, count 1) and the pad rows dropped."""
+    m, n = x.shape[0], x.shape[1] // 2
+    hi = x[:, 1::2] ^ _IMIN
+    lo = x[:, 0::2] ^ _IMIN
     if method in ORD_IND_METHODS:
         seg, nseg = n, 1
         cnt = np.full(1, n, dtype=np.int32)
@@ -292,10 +299,6 @@ def _codec_call(hi, lo, w, *, method: str, rpp: int, interpret: bool):
     out = segment_call(hi, lo, w_seg, jnp.asarray(cnt_seg), method=method,
                        tile_r=tile_r, tile_c=tile_c, interpret=interpret)
     return out[:rows, 0].reshape(m, nseg).sum(axis=1)
-
-
-def _signed_view(u: np.ndarray) -> np.ndarray:
-    return (u ^ _BIAS).view(np.int32)
 
 
 def in_envelope(cols: np.ndarray, widths: np.ndarray) -> bool:
@@ -325,19 +328,14 @@ def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
         from ..core import compression as _comp
         return _comp.BATCH_KERNELS[method](cols, widths, rpp)
 
-    # pad the target axis to whole sublanes (bounds the compiled-shape
-    # count; pad rows are dropped) and split the signed-view planes
-    m_pad = _round_up(m, _SUBLANES)
-    u = np.zeros((m_pad, n), dtype=np.uint64)
-    u[:m] = cols
-    w = np.ones(m_pad, dtype=np.int32)
-    w[:m] = widths
-    hi = _signed_view((u >> np.uint64(32)).astype(np.uint32))
-    lo = _signed_view((u & _M32).astype(np.uint32))
+    # the stack's own bytes, split into planes on the device; never the
+    # int64 array itself, which jax would truncate to int32 without x64
+    x = np.ascontiguousarray(cols).view(np.int32)
+    w = widths.astype(np.int32)
     page = min(int(rpp), n) if method in ORD_DEP_METHODS else 0
-    out = _codec_call(jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(w),
+    out = _codec_call(jnp.asarray(x, dtype=jnp.int32), jnp.asarray(w),
                       method=method, rpp=page, interpret=pallas_interpret())
     _counters["kernel_calls"] += 1
-    _counters["h2d_bytes"] += hi.nbytes + lo.nbytes + w.nbytes
+    _counters["h2d_bytes"] += x.nbytes + w.nbytes
     _counters["d2h_bytes"] += out.nbytes
-    return np.asarray(out, dtype=np.int64)[:m]
+    return np.asarray(out, dtype=np.int64)

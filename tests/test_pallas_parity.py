@@ -69,6 +69,12 @@ class TestCodecBitEquality:
         ((8, 129), 128),    # one row past a lane boundary
         ((17, 200), 1000),  # rpp > nrows: one page
         ((4, 333), 1),      # rpp=1: every row its own page
+        ((7, 1), 1),        # target counts off and on whole sublanes,
+        ((8, 1), 4),        # one row each: the device takes the exact
+        ((9, 1), 1),        # target count, with no pad of its own
+        ((17, 1), 2),
+        ((7, 50), 8),
+        ((9, 31), 4),
     ])
     def test_random_small_values(self, method, shape, rpp):
         cols = RNG.integers(0, 1 << 16, size=shape)
@@ -169,13 +175,21 @@ class TestCodecBitEquality:
     @pytest.mark.parametrize("method", METHODS)
     def test_plane_extremes(self, method):
         """Signed-view min/max and significant bytes at the extremes of
-        both uint32 planes (lo's sign bit, all-ones lo, the largest hi)."""
+        both uint32 planes (lo's sign bit, all-ones lo, the largest hi),
+        and the device's split of the int64 words into those planes:
+        every pairing of the hi words 0, 1, 2^31-1 with the lo words 0,
+        2^31-1, 2^31, 2^32-1 is among the values."""
         top = (1 << 63) - 1
         vals = np.array([
             0, 1, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF, 1 << 32,
             (1 << 32) + 0xFFFFFFFF, (0x7FFFFFFF << 32), top,
             (0x7FFFFFFF << 32) | 0x80000000, 0x80000001, 0x7FFFFFFE,
+            (1 << 32) | 0x7FFFFFFF, (1 << 32) | 0x80000000,
+            (0x7FFFFFFF << 32) | 0x7FFFFFFF,
         ], dtype=np.int64)
+        assert {(h << 32) | lo for h in (0, 1, 0x7FFFFFFF)
+                for lo in (0, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF)
+                } <= set(vals.tolist())
         pairs = np.stack([np.roll(vals, k) for k in range(len(vals))])
         cols = np.concatenate([pairs, pairs[:, ::-1]], axis=0)
         widths = np.full(cols.shape[0], 8)
@@ -186,6 +200,55 @@ class TestCodecBitEquality:
                          (2, 8))
         lo_mix[1] += 5 << 32
         assert_codec_exact(method, lo_mix, np.array([4, 8]), 2)
+
+    @pytest.mark.parametrize("method", ["GDICT", "PREFIX"])
+    def test_hi_words_reach_the_device(self, method):
+        """Two stacks equal in their lo words and different in their hi
+        words have different sizes, so a hand-off that kept only the low
+        32 bits of each value (jax truncates int64 to int32 without x64)
+        could not match the reference."""
+        n = 96
+        lo = np.arange(n, dtype=np.int64) % 4
+        same_hi = np.stack([lo, lo + (3 << 32)])
+        mixed_hi = np.stack([lo + ((np.arange(n) % 7) << 32),
+                             lo + ((np.arange(n) // 4 % 3) << 40)])
+        widths = np.array([8, 8])
+        want_same = ref_bytes(method, same_hi, widths, 32)
+        want_mixed = ref_bytes(method, mixed_hi, widths, 32)
+        assert (want_same != want_mixed).all()
+        assert_codec_exact(method, same_hi, widths, 32)
+        assert_codec_exact(method, mixed_hi, widths, 32)
+
+    @pytest.mark.parametrize("method", METHODS)
+    @pytest.mark.parametrize("layout", ["row_slice", "transposed"])
+    def test_non_contiguous_stack(self, method, layout):
+        """Stacks that are views, not contiguous arrays: every other row of
+        a larger stack, and the transpose of a (rows, targets) array."""
+        base = RNG.integers(0, 1 << 40, size=(18, 70))
+        base[::3] >>= 20                  # some rows with a zero hi word
+        if layout == "row_slice":
+            cols = base[1::2]
+        else:
+            cols = np.ascontiguousarray(base[:9].T).T
+        assert not cols.flags["C_CONTIGUOUS"]
+        widths = RNG.integers(1, 9, size=cols.shape[0])
+        assert_codec_exact(method, cols, widths, 16)
+
+    @pytest.mark.parametrize("m", [1, 7, 8, 9, 17])
+    def test_transfer_counters(self, m):
+        """One launch sends the stack's own bytes (m * n * 8) and the
+        widths (m * 4), and reads back one int32 a target."""
+        n = 45
+        cols = RNG.integers(0, 1 << 33, size=(m, n))
+        widths = RNG.integers(1, 9, size=m)
+        for method in METHODS:
+            before = ck.counters()
+            assert_codec_exact(method, cols, widths, 10)
+            after = ck.counters()
+            assert after["kernel_calls"] == before["kernel_calls"] + 1
+            assert (after["h2d_bytes"] - before["h2d_bytes"]
+                    == m * n * 8 + m * 4)
+            assert after["d2h_bytes"] - before["d2h_bytes"] == m * 4
 
     @pytest.mark.parametrize("method", METHODS)
     def test_min_max_fold_across_tiles(self, method):

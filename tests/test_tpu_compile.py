@@ -4,7 +4,7 @@ The TPU compiler compiles for a chip that is described, not attached, so
 these tests need no accelerator.  They catch what interpret mode cannot: a
 primitive Mosaic has no lowering for, an unaligned shape cast, a block
 that overflows scoped VMEM.  Shapes are the TPC-H SF1 main path's:
-SampleCF samples of 60,000 rows, up to 126 targets per codec call, rows
+SampleCF samples of 60,000 rows, up to 282 targets per codec call, rows
 per page that are not lane multiples, and the planner's largest
 (candidates, children) record.
 
@@ -24,7 +24,7 @@ from repro.kernels import codec_bytes as ck  # noqa: E402
 from repro.kernels import planner_score as ps  # noqa: E402
 
 SAMPLE_ROWS = 60_000   # 1% SampleCF sample of SF1 lineitem (6.0M rows)
-TARGETS = 126          # largest target stack of one SF1 codec call
+TARGETS = 282          # largest target stack of one SF1 codec call
 MAX_CANDIDATES = 14    # largest planner record at SF1: candidates ...
 MAX_CHILDREN = 11      # ... and children per candidate
 N_FRACTIONS = 5        # len(estimation_graph.F_GRID)
@@ -60,16 +60,18 @@ def compile_for(sharding, fn, *shapes):
 
 
 @pytest.mark.parametrize("method,rpp", [
-    ("NS", 0), ("LDICT", 682), ("LDICT", 1638), ("PREFIX", 682),
-    ("PREFIX", 1638), ("RLE", 682), ("RLE", 273)])
+    ("NS", 0), ("LDICT", 73), ("LDICT", 682), ("LDICT", 1638),
+    ("PREFIX", 682), ("PREFIX", 1638), ("RLE", 682), ("RLE", 273)])
 def test_codec_call_compiles(one_chip, method, rpp):
-    """The whole codec call at an SF1 sample: layout, page sort, kernel."""
-    m = -(-TARGETS // 8) * 8     # batched_codec_bytes pads targets to 8
-    plane = ((m, SAMPLE_ROWS), jnp.int32)
+    """The whole codec call at an SF1 sample: plane split, layout, page
+    sort, kernel.  The stack arrives as its int32 words, (m, 2n), with a
+    target count that is not a whole number of sublanes."""
+    assert TARGETS % 8
     compile_for(one_chip,
                 functools.partial(ck._codec_call, method=method, rpp=rpp,
                                   interpret=False),
-                plane, plane, ((m,), jnp.int32))
+                ((TARGETS, 2 * SAMPLE_ROWS), jnp.int32),
+                ((TARGETS,), jnp.int32))
 
 
 @pytest.mark.parametrize("method,rows,seg", [
@@ -115,10 +117,10 @@ def test_fused_score_compiles(one_chip):
     ("codec_ldict",
      functools.partial(ck._codec_call, method="LDICT", rpp=682,
                        interpret=False),
-     [((8, 6000), jnp.int32)] * 2 + [((8,), jnp.int32)]),
+     [((8, 12000), jnp.int32), ((8,), jnp.int32)]),
     ("codec_ns",
      functools.partial(ck._codec_call, method="NS", rpp=0, interpret=False),
-     [((8, 6000), jnp.int32)] * 2 + [((8,), jnp.int32)]),
+     [((5, 12000), jnp.int32), ((5,), jnp.int32)]),
     ("planner_prob",
      functools.partial(ps._prob_call, e=0.5, interpret=False),
      [((1, 128), jnp.float32)] * 2),
