@@ -73,8 +73,7 @@ def scan_cost(size_bytes: ArrayLike, nrows: ArrayLike, ncols_used: ArrayLike,
     if beta_coef is None:
         beta_coef = beta_coef_of(compression)
     io = T_IO_SEQ * pages_of(size_bytes)
-    cpu = CPU_ROW * nrows + beta_coef * nrows * ncols_used   # A.2
-    return io + cpu
+    return io + CPU_ROW * nrows + beta_coef * nrows * ncols_used   # A.2
 
 
 def seek_cost(size_bytes: ArrayLike, nrows_index: ArrayLike,
@@ -86,8 +85,7 @@ def seek_cost(size_bytes: ArrayLike, nrows_index: ArrayLike,
         beta_coef = beta_coef_of(compression)
     rows = nrows_index * selectivity
     io = SEEK_OVERHEAD + T_IO_SEQ * pages_of(size_bytes * selectivity)
-    cpu = CPU_ROW * rows + beta_coef * rows * ncols_used
-    return io + cpu
+    return io + CPU_ROW * rows + beta_coef * rows * ncols_used
 
 
 def rid_lookup_cost(nrows: ArrayLike, base_size_bytes: ArrayLike,
@@ -99,9 +97,8 @@ def rid_lookup_cost(nrows: ArrayLike, base_size_bytes: ArrayLike,
         beta_coef = beta_coef_of(base_compression)
     npages = pages_of(base_size_bytes)
     touched = np.minimum(nrows, npages)  # cap: can't touch more pages than exist
-    io = T_IO_RAND * touched
-    cpu = CPU_ROW * nrows + beta_coef * nrows * ncols_used
-    return io + cpu
+    return (T_IO_RAND * touched + CPU_ROW * nrows
+            + beta_coef * nrows * ncols_used)
 
 
 def update_cost(index_size_bytes: ArrayLike, index_nrows: ArrayLike,
@@ -116,5 +113,4 @@ def update_cost(index_size_bytes: ArrayLike, index_nrows: ArrayLike,
         np.minimum(rows_written / np.maximum(index_nrows, 1e-300), 1.0))
     io = T_IO_SEQ * pages_of(index_size_bytes * frac_written)
     cpu = (CPU_ROW + INDEX_MAINT_CPU) * rows_written
-    cpu = cpu + alpha_coef * rows_written     # A.1
-    return io + cpu
+    return io + cpu + alpha_coef * rows_written     # A.1

@@ -126,6 +126,7 @@ def greedy_enumerate(optimizer: WhatIfOptimizer, sizes: SizeProvider,
                      if p.table == t and p.clustered], dtype=np.int64)
         for t in pool_tables}
     present = np.zeros(n, dtype=bool)
+    n_stmts = len(optimizer.workload.statements)
 
     def recompute_present(cfg: Configuration) -> None:
         present[:] = False
@@ -190,11 +191,46 @@ def greedy_enumerate(optimizer: WhatIfOptimizer, sizes: SizeProvider,
             score = np.where(valid, benefit, -np.inf)
         feasible = valid & (used + delta_used <= budget_bytes)
 
-        best_any_k = int(np.argmax(score))
+        def scalar_choice(mask: np.ndarray) -> int:
+            """The candidate the scalar greedy takes among `mask`.  The
+            batched benefits sum per table, the scalar ones over the whole
+            workload in statement order, so the two round apart by a few
+            ulps of the total; where candidates lie that close to the top,
+            rounding decides, and the scalar optimizer is asked to score
+            them its own way (first largest in pool order)."""
+            s = np.where(mask, score, -np.inf)
+            # a sum of n terms rounds within n ulps of its magnitude
+            tol = 4 * n_stmts * np.finfo(float).eps * max(1.0, abs(cost))
+            if variant == "density":
+                tol = tol / np.maximum(delta_used, 1.0)
+            near = np.flatnonzero(mask & (s + tol >= s.max()))
+            if near.size == 1:
+                return int(near[0])
+            # a clustered layout's costs depend on its size and codec
+            # alone (scans and RID lookups), so layouts that differ only
+            # in column order total alike and the first stands for all
+            reps: Dict[Tuple, int] = {}
+            for k in near:
+                p = pool[k]
+                reps.setdefault((p.table, p.compression, sizes.size(p))
+                                if p.clustered else (int(k),), int(k))
+            now = optimizer.workload_cost(config)
+            best: Optional[Tuple[float, int]] = None
+            for k in reps.values():
+                cfg2 = _apply(config, pool[k])
+                b = now - optimizer.workload_cost(cfg2)
+                if b <= 1e-9:
+                    continue
+                if variant == "density":
+                    b = b / max(storage_used(cfg2, base, sizes) - used, 1.0)
+                if best is None or b > best[0]:
+                    best = (b, int(k))
+            return best[1] if best is not None else int(near[0])
+
+        best_any_k = scalar_choice(valid)
         best_feas_k: Optional[int] = None
         if feasible.any():
-            feas_score = np.where(feasible, score, -np.inf)
-            best_feas_k = int(np.argmax(feas_score))
+            best_feas_k = scalar_choice(feasible)
 
         chosen: Optional[Tuple[IndexDef, Configuration]] = None
         recovered_choice = False

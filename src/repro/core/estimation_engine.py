@@ -315,6 +315,24 @@ class EstimationEngine:
                 "backend_fallbacks": self.backend_fallbacks,
                 "envelope_reroutes": self.envelope_reroutes}
 
+    def warm_up(self, f: float, methods: Sequence[str]) -> int:
+        """Run once each codec program a batch at fraction `f` can use on
+        this backend, so that no batch lowers one: for every table, its
+        sample's rows and the page lengths of every key width the table
+        allows (`codec_bytes.programs`).  Returns the launches; 0 on the
+        NumPy backend."""
+        if self.backend != "jax":
+            return 0
+        from ..kernels import codec_bytes
+        launches = []
+        for name, table in self.tables.items():
+            widths = [c.width for c in table.columns]
+            launches += codec_bytes.programs(
+                self.manager.sample_rows(name, f), methods,
+                range(rows_per_page(sum(widths)),
+                      rows_per_page(min(widths)) + 1))
+        return codec_bytes.warm_up(sorted(set(launches)))
+
     def estimate_batch(self, targets: Sequence, f: float,
                        bias_correct: bool = True) -> Dict:
         """SizeEstimate for every target, keyed by the target objects."""

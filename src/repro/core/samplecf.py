@@ -14,7 +14,7 @@ import dataclasses
 import hashlib
 import zlib
 from collections import OrderedDict
-from typing import Dict, Iterator, MutableMapping, Optional, Tuple
+from typing import Dict, Iterator, List, MutableMapping, Optional, Tuple
 
 import numpy as np
 
@@ -170,13 +170,21 @@ class SampleManager:
                int(round(round(f, 6) * 1e6)))
         return np.random.default_rng(key)
 
+    def sample_rows(self, table_name: str, f: float) -> int:
+        """Rows of the (table, f) sample, drawn or not."""
+        nrows = self.tables[table_name].nrows
+        return min(max(2, int(round(nrows * f))), nrows)
+
+    def fractions(self) -> List[float]:
+        """The fractions some sample has been drawn at."""
+        return sorted({f for _, f in self._samples})
+
     def get_sample(self, table_name: str, f: float) -> Table:
         key = (table_name, round(f, 6))
         if key not in self._samples:
             with tracing.span("estimate.sample"):
                 t = self.tables[table_name]
-                n = max(2, int(round(t.nrows * f)))
-                n = min(n, t.nrows)
+                n = self.sample_rows(table_name, f)
                 rng = self._rng_for(table_name, f)
                 rows = rng.choice(t.nrows, size=n, replace=False)
                 self._samples[key] = t.take(np.sort(rows))

@@ -698,9 +698,10 @@ class AdvisorSession:
         engine = self.engine
         if engine is not None:
             engine.sync_sizes()
-        elif changed:
+        if changed:
             # the scalar optimizer memoizes statement costs by (statement,
-            # config); re-registered sizes invalidate those entries
+            # config); re-registered sizes invalidate those entries (the
+            # batched greedy asks it where rounding decides a step)
             self.optimizer._cache.clear()
         base_cost = (engine.config_cost(base) if engine is not None
                      else self.optimizer.workload_cost(base))

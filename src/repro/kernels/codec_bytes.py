@@ -9,12 +9,16 @@ GDICT, one page of a target row for LDICT, PREFIX and RLE.  The wrapper
 lays the segments out one per kernel row and edge-pads each row to a
 multiple of 128 lanes with the segment's last value (after the sort, for
 GDICT and LDICT), so a padding lane adds no distinct value, no run and no
-min/max movement; NS masks its padding with the segment's row count.  The
-grid is (row tiles, column tiles): a block holds at most `_BLOCK_ELEMS`
-values per plane at any sample size, and per-row accumulators carry the
-partial count, the running min/max and the previous tile's last value
-across column tiles, so a change that falls on a tile boundary is counted
-exactly once.  Paged methods then sum their per-page bytes per target.
+min/max movement; NS masks its padding with the segment's row count.
+Pages are gathered `page_bucket(page)` lanes wide with the page length a
+traced value, and a call's targets go in launches of `CHUNK` rows, so
+the programs a stack of n sample rows can lower are a small fixed set
+(`programs`, `warm_up`).  The grid is (row tiles, column tiles): a
+block holds at most `_BLOCK_ELEMS` values per plane at any sample size,
+and per-row accumulators carry the partial count, the running min/max
+and the previous tile's last value across column tiles, so a change that
+falls on a tile boundary is counted exactly once.  Paged methods then
+sum their per-page bytes per target.
 
 int32-safe arithmetic (no x64, no unsigned reductions):
 
@@ -53,6 +57,8 @@ the kernels for a TPU v5e at SampleCF's real sample shapes.
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -266,25 +272,66 @@ def _edge_pad(x, width: int):
     return jnp.pad(x, ((0, 0), (0, width - x.shape[1])), mode="edge")
 
 
-@functools.partial(jax.jit, static_argnames=("method", "rpp", "interpret"))
-def _codec_call(x, w, *, method: str, rpp: int, interpret: bool):
+# Launch shapes.  A codec program is keyed on its static shapes, so the
+# shapes a call can take are kept to a small fixed set: the target axis
+# goes in launches of CHUNK rows, and a page-local method lays its pages
+# out `page_bucket(page)` lanes wide, the page length itself a traced
+# value.  Every launch row is a real target: the last launch of a call
+# ends at its last target, overlapping the launch before it, and a call of
+# fewer than CHUNK targets repeats its last one.
+CHUNK = 8
+_WARM_THREADS = 8
+
+
+def launch_starts(m: int) -> List[int]:
+    """First target of each launch that covers m targets."""
+    starts = list(range(0, max(m - CHUNK, 0) + 1, CHUNK))
+    if m > CHUNK and starts[-1] + CHUNK < m:
+        starts.append(m - CHUNK)
+    return starts
+
+
+def page_bucket(page: int) -> int:
+    """Lanes per page row for pages of `page` rows: the power of two at or
+    above it.  A bucket b holds page lengths b/2 < page <= b."""
+    return 1 << (int(page) - 1).bit_length()
+
+
+def _page_rows(n: int, bucket: int) -> int:
+    """Page rows per target in bucket `bucket`: enough for the bucket's
+    shortest page length."""
+    return -(-n // (bucket // 2 + 1))
+
+
+@functools.partial(jax.jit, static_argnames=("method", "bucket", "interpret"))
+def _codec_call(x, w, page, *, method: str, bucket: int, interpret: bool):
     """(m,) int32 payload bytes of an (m, n) stack given as its (m, 2n)
-    int32 words (lo on even lanes, hi on odd); rpp is the page length
-    (<= n) for paged methods and ignored otherwise.  The target axis needs
-    no pad of its own: the segment rows are padded to whole row tiles
-    (weight 1, count 1) and the pad rows dropped."""
+    int32 words (lo on even lanes, hi on odd).  For page-local methods
+    `page` (a traced int32, <= n) is the page length and `bucket` its
+    `page_bucket`; ORD-IND methods ignore both.
+
+    Page p of a target is laid out as one row of `bucket` lanes: lane l
+    holds value min(p * page + min(l, page - 1), n - 1), so the lanes past
+    the page's rows repeat its last value, as the NumPy reference's edge
+    padding does (no new value, run or min/max), and the page rows past
+    the last page repeat the target's last value and are left out of the
+    sum.  The segment rows are padded to whole row tiles (weight 1, count
+    1) and the pad rows dropped."""
     m, n = x.shape[0], x.shape[1] // 2
-    hi = x[:, 1::2] ^ _IMIN
-    lo = x[:, 0::2] ^ _IMIN
     if method in ORD_IND_METHODS:
         seg, nseg = n, 1
-        cnt = np.full(1, n, dtype=np.int32)
-    else:  # one page per row, edge-padded to whole pages (== _pages_batch)
-        seg, nseg = rpp, -(-n // rpp)
-        hi = _edge_pad(hi, nseg * seg).reshape(m * nseg, seg)
-        lo = _edge_pad(lo, nseg * seg).reshape(m * nseg, seg)
-        cnt = np.full(nseg, seg, dtype=np.int32)
-        cnt[-1] = n - (nseg - 1) * seg
+        hi = x[:, 1::2] ^ _IMIN
+        lo = x[:, 0::2] ^ _IMIN
+        cnt = jnp.full((1,), n, dtype=jnp.int32)
+    else:
+        seg, nseg = bucket, _page_rows(n, bucket)
+        start = jnp.arange(nseg, dtype=jnp.int32) * page
+        lane = jnp.minimum(jnp.arange(seg, dtype=jnp.int32), page - 1)
+        idx = jnp.minimum(start[:, None] + lane[None, :], n - 1).reshape(-1)
+        hi = (jnp.take(x, 2 * idx + 1, axis=1) ^ _IMIN).reshape(m * nseg,
+                                                                 seg)
+        lo = (jnp.take(x, 2 * idx, axis=1) ^ _IMIN).reshape(m * nseg, seg)
+        cnt = jnp.clip(n - start, 1, page)
     if method in ("GDICT", "LDICT"):
         hi, lo = jax.lax.sort((hi, lo), dimension=1, num_keys=2)
     rows = m * nseg
@@ -294,11 +341,14 @@ def _codec_call(x, w, *, method: str, rpp: int, interpret: bool):
     lo = jnp.pad(_edge_pad(lo, c_pad), pad_rows)
     w_seg = jnp.pad(jnp.repeat(w, nseg), (0, r_pad - rows),
                     constant_values=1)[:, None]
-    cnt_seg = np.pad(np.tile(cnt, m), (0, r_pad - rows),
-                     constant_values=1)[:, None]
-    out = segment_call(hi, lo, w_seg, jnp.asarray(cnt_seg), method=method,
+    cnt_seg = jnp.pad(jnp.tile(cnt, m), (0, r_pad - rows),
+                      constant_values=1)[:, None]
+    out = segment_call(hi, lo, w_seg, cnt_seg, method=method,
                        tile_r=tile_r, tile_c=tile_c, interpret=interpret)
-    return out[:rows, 0].reshape(m, nseg).sum(axis=1)
+    per_page = out[:rows, 0].reshape(m, nseg)
+    if method not in ORD_IND_METHODS:
+        per_page = jnp.where(start[None, :] < n, per_page, 0)
+    return per_page.sum(axis=1)
 
 
 def in_envelope(cols: np.ndarray, widths: np.ndarray) -> bool:
@@ -332,10 +382,58 @@ def batched_codec_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
     # int64 array itself, which jax would truncate to int32 without x64
     x = np.ascontiguousarray(cols).view(np.int32)
     w = widths.astype(np.int32)
-    page = min(int(rpp), n) if method in ORD_DEP_METHODS else 0
-    out = _codec_call(jnp.asarray(x, dtype=jnp.int32), jnp.asarray(w),
-                      method=method, rpp=page, interpret=pallas_interpret())
-    _counters["kernel_calls"] += 1
-    _counters["h2d_bytes"] += x.nbytes + w.nbytes
-    _counters["d2h_bytes"] += out.nbytes
-    return np.asarray(out, dtype=np.int64)
+    if method in ORD_DEP_METHODS:
+        page = min(int(rpp), n)
+        bucket = page_bucket(page)
+    else:
+        page, bucket = 0, 0
+    page = np.int32(page)
+    if m < CHUNK:                # repeat the last target up to a launch
+        rep = np.minimum(np.arange(CHUNK), m - 1)
+        x, w = x[rep], w[rep]
+    starts = launch_starts(m)
+    outs = []
+    for i in starts:             # every launch queued before a read-back
+        xs, ws = x[i:i + CHUNK], w[i:i + CHUNK]
+        outs.append(_launch(xs, ws, page, method, bucket))
+        _counters["kernel_calls"] += 1
+        _counters["h2d_bytes"] += xs.nbytes + ws.nbytes + page.nbytes
+    out = np.empty(max(m, CHUNK), dtype=np.int64)
+    for i, o in zip(starts, outs):
+        out[i:i + CHUNK] = np.asarray(o)
+    _counters["d2h_bytes"] += 4 * CHUNK * len(outs)
+    return out[:m]
+
+
+def _launch(x: np.ndarray, w: np.ndarray, page: np.int32, method: str,
+            bucket: int):
+    return _codec_call(jnp.asarray(x, dtype=jnp.int32), jnp.asarray(w), page,
+                       method=method, bucket=bucket,
+                       interpret=pallas_interpret())
+
+
+def programs(n: int, methods, pages) -> List[Tuple[str, int, int]]:
+    """(method, n, page) of one launch of each program that
+    `batched_codec_bytes` can run on stacks of n sample rows: one for each
+    order-independent method and, for the page-local methods, one for each
+    page bucket of the page lengths `pages` (each capped at n)."""
+    buckets = sorted({page_bucket(min(int(p), n)) for p in pages})
+    return [(method, n, page) for method in methods
+            for page in ([min(b, n) for b in buckets]
+                         if method in ORD_DEP_METHODS else [0])]
+
+
+def warm_up(launches: Sequence[Tuple[str, int, int]]) -> int:
+    """Run each launch `programs` lists once, on zeros, from _WARM_THREADS
+    threads so that their compiles and cache loads overlap.  Returns how
+    many ran."""
+    def run(launch):
+        method, n, page = launch
+        bucket = page_bucket(page) if method in ORD_DEP_METHODS else 0
+        _launch(np.zeros((CHUNK, 2 * n), np.int32), np.ones(CHUNK, np.int32),
+                np.int32(page), method, bucket).block_until_ready()
+
+    with ThreadPoolExecutor(max_workers=_WARM_THREADS) as pool:
+        for _ in pool.map(run, launches):
+            pass
+    return len(launches)

@@ -256,8 +256,14 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
     (int64; meaningless where the respective mask column is empty).
     """
     nc, k, nf = m.shape
-    nc_pad = -(-nc // 8) * 8
+    # powers of two on the candidate and child axes keep the programs a
+    # small fixed set; EXACT (mean=1, std=0) child pads are the fold's
+    # exact identity, and pad candidates are never eligible
+    nc_pad = max(8, 1 << (nc - 1).bit_length())
+    k_pad = 1 << (k - 1).bit_length()
     nf_pad = -(-nf // _LANES) * _LANES
+    m = _pad_axis(np.asarray(m, dtype=np.float32), 1, k_pad, 1.0)
+    s = _pad_axis(np.asarray(s, dtype=np.float32), 1, k_pad, 0.0)
     z = np.zeros((nc, nf)) if pre9 is None else pre9
     x = np.zeros((nc, nf)) if extra is None else extra
 
@@ -265,8 +271,8 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
         a = _pad_axis(np.asarray(a, dtype=dtype), 0, nc_pad, fill)
         return _pad_axis(a, a.ndim - 1, nf_pad, fill)
 
-    mp = prep(m, 1.0, np.float32).reshape(nc_pad, k * nf_pad)
-    sp = prep(s, 0.0, np.float32).reshape(nc_pad, k * nf_pad)
+    mp = prep(m, 1.0, np.float32).reshape(nc_pad, k_pad * nf_pad)
+    sp = prep(s, 0.0, np.float32).reshape(nc_pad, k_pad * nf_pad)
     dmp = _pad_axis(np.asarray(dm, dtype=np.float32), 0, nc_pad, 1.0)
     vtp = _pad_axis(np.asarray(vt, dtype=np.float32), 0, nc_pad, 1.0)
     mqp = _pad_axis(np.asarray(mq, dtype=np.float32), 0, nc_pad, 1.0)
@@ -275,7 +281,7 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
     exp_ = prep(x, 0.0, np.float32)
 
     args = (mp, sp, dmp, vtp, mqp, m67p, p9p, exp_)
-    outs = _fused_call(*(jnp.asarray(a) for a in args), k=k, e=float(e),
+    outs = _fused_call(*(jnp.asarray(a) for a in args), k=k_pad, e=float(e),
                        q=float(q), interpret=pallas_interpret())
     cm, cs, p, w6, w9 = outs
     _counters["fused_calls"] += 1
@@ -286,3 +292,28 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
             np.asarray(p, dtype=np.float64)[:nc, :nf],
             np.asarray(w6, dtype=np.int64)[0, :nf],
             np.asarray(w9, dtype=np.int64)[0, :nf])
+
+
+# the largest record `warm_up` runs: candidates and children per candidate
+# (TPC-H SF1 records reach 19 candidates and 30 children; a larger record
+# lowers its program when it first comes)
+MAX_CANDIDATES = 32
+MAX_CHILDREN = 64
+
+
+def warm_up(e: float, q: float) -> int:
+    """Run once each `fused_score` program of up to MAX_CANDIDATES
+    candidates and MAX_CHILDREN children at accuracy (e, q): every
+    power-of-two pair of those axes.  Returns the launches."""
+    launches = 0
+    nc = 8
+    while nc <= MAX_CANDIDATES:
+        k = 1
+        while k <= MAX_CHILDREN:
+            ones, zeros = np.ones((nc, 1)), np.zeros((nc, 1), dtype=bool)
+            fused_score(np.ones((nc, k, 1)), np.zeros((nc, k, 1)), ones,
+                        ones, ones, zeros, None, None, e, q)
+            launches += 1
+            k *= 2
+        nc *= 2
+    return launches

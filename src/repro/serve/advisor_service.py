@@ -31,8 +31,9 @@ advisors:
   at recommend time), unions the group's missing (NodeKey, f) targets,
   and sizes them in one `EstimationEngine.estimate_batch` call per
   (group, f) — many tenants' targets stacked into the engine's grouped
-  (ntargets, nrows) kernel batches (vmapped jax kernels on the jax
-  backend, chunked NumPy otherwise).  `estimate_batch` results are
+  (ntargets, nrows) kernel batches, on the group's estimation backend
+  (the tenants' `estimation_backend`: the Pallas codec kernels on
+  "jax", NumPy otherwise).  `estimate_batch` results are
   byte-identical to the scalar `sample_cf` per target, and therefore
   independent of WHICH tenants' targets share a batch — union-batching
   is bit-exact.
@@ -48,6 +49,14 @@ advisors:
   recommend.  Bit-identical to per-slot costing on both backends:
   against a secondary-free session base every per-candidate cost is
   purely elementwise, so stacking cannot change a single bit.
+
+Observability: with `core.tracing` on, a step is the span `fleet.step`
+(a request), holding `fleet.admit`, `fleet.prefetch`,
+`fleet.cost_prefetch` and one `fleet.execute` per slot; the sessions'
+own spans (planner, SampleCF, `kernel.codec`) nest inside those.  The
+counters in `stats` (`prefetch_targets`, `prefetch_hits`,
+`prefetch_batches`, `cost_prefetch_jobs`, `recommends`, ...) only grow,
+so a reader takes their change over a window.
 
 Durability (the fleet's failure surface, driven by a seeded
 `faults.FaultInjector` in tests and benchmarks/fault_recovery.py):
@@ -117,11 +126,12 @@ from concurrent.futures import Future
 from concurrent.futures import TimeoutError as FutureTimeout
 from typing import Dict, List, Optional, Tuple
 
+from ..core import tracing
 from ..core.advisor import AdvisorOptions
 from ..core.cost_engine import batched_candidate_costs
 from ..core.durability import DurableStore, RecoveredTenant
 from ..core.estimation_engine import EstimationEngine
-from ..core.estimation_graph import NodeKey, State
+from ..core.estimation_graph import F_GRID, NodeKey, State
 from ..core.faults import FaultError, FaultInjector
 from ..core.samplecf import (EstimateCache, SampleManager, SizeEstimate,
                              schema_fingerprint)
@@ -184,7 +194,6 @@ class FleetConfig:
     slots: int = 8                    # tenant requests executed per step
     max_queue: Optional[int] = None   # global bound; submit raises QueueFull
     prefetch: bool = True             # cross-tenant batched SampleCF prefetch
-    backend: str = "numpy"            # prefetch engine backend
     # --- durability ---------------------------------------------------
     cache_entries: Optional[int] = None   # bound each group's SampleCF cache
     deadline_steps: Optional[int] = None  # default per-request deadline
@@ -272,21 +281,21 @@ class _FleetRequest:
 
 
 class _ShareGroup:
-    """One (schema fingerprint, backend) equivalence class of tenants:
-    a shared order-independent SampleManager, a shared (NodeKey, f)
-    SampleCF cache (bounded LRU when the fleet config asks), and the
-    batched estimation engine the prefetch stacks the group's targets
-    into."""
+    """One (schema fingerprint, estimation backend) equivalence class of
+    tenants: a shared order-independent SampleManager, a shared
+    (NodeKey, f) SampleCF cache (bounded LRU when the fleet config asks),
+    and the batched estimation engine the prefetch stacks the group's
+    targets into, on the backend the key names."""
 
     def __init__(self, key: Tuple[str, str], tables: Dict, seed: int,
-                 backend: str, cache_entries: Optional[int] = None):
+                 cache_entries: Optional[int] = None):
         self.key = key
         self.samples = SampleManager(tables, seed=seed)
         self.cache: Dict[Tuple[NodeKey, float], SizeEstimate] = (
             EstimateCache(cache_entries) if cache_entries is not None
             else {})
         self.engine = EstimationEngine(tables, self.samples,
-                                       backend=backend)
+                                       backend=key[1])
         self.n_tenants = 0
 
 
@@ -348,6 +357,8 @@ class AdvisorFleetService:
         self.slots: List[Optional[_FleetRequest]] = [None] * self.fc.slots
         self.steps = 0
         self.retired = 0
+        self.recommends = 0           # recommends resolved with an answer
+        self._warmed: set = set()     # what warm_up has run
         self.prefetch_batches = 0     # (group, f) batched prefetch calls
         self.prefetch_targets = 0     # targets sized by the prefetch
         self.prefetch_hits = 0        # peeked targets already cached
@@ -406,8 +417,7 @@ class AdvisorFleetService:
         group = self.groups.get(key)
         if group is None:
             group = self.groups[key] = _ShareGroup(
-                key, schema.tables, opt.sample_seed,
-                self.fc.backend, self.fc.cache_entries)
+                key, schema.tables, opt.sample_seed, self.fc.cache_entries)
         return group
 
     def crash_tenant(self, tenant_id: str) -> None:
@@ -583,6 +593,7 @@ class AdvisorFleetService:
     # Service loop (mirrors ServeEngine: admit -> batch -> execute ->
     # retire)
     # ------------------------------------------------------------------
+    @tracing.traced("fleet.admit")
     def _admit(self) -> None:
         """Fill free slots from the queue in arrival order, at most one
         in-flight request per tenant so each tenant's requests execute
@@ -660,12 +671,14 @@ class AdvisorFleetService:
             rec = deg.recommend(req.budget_bytes)
             req.ticket.degraded = True
             t.recommends += 1
+            self.recommends += 1
             t.consecutive_failures = 0
             self.degraded_recommends += 1
             req.ticket._resolve(rec)
         except BaseException as e:
             self._final_failure(req, t, e)
 
+    @tracing.traced("fleet.prefetch")
     def _prefetch(self) -> None:
         """Union-batch the admitted recommends' missing SampleCF targets.
 
@@ -727,6 +740,7 @@ class AdvisorFleetService:
             self.prefetch_batches += 1
             self.prefetch_targets += len(keys)
 
+    @tracing.traced("fleet.cost_prefetch")
     def _cost_prefetch(self) -> None:
         """Stack the admitted recommends' stale per-query costing jobs
         into cross-tenant (tenant x statement x candidate) batches, one
@@ -815,6 +829,7 @@ class AdvisorFleetService:
             t.n_pending -= 1
             self.retired += 1
 
+    @tracing.traced("fleet.execute")
     def _execute(self, req: _FleetRequest) -> bool:
         """Run one slot's request.  Returns True when the request is
         retired (resolved either way), False when it was requeued for a
@@ -869,6 +884,7 @@ class AdvisorFleetService:
                 assert req.budget_bytes is not None
                 rec = t.session.recommend(req.budget_bytes)
                 t.recommends += 1
+                self.recommends += 1
                 t.consecutive_failures = 0
                 req.ticket._resolve(rec)
         except BaseException as e:      # isolate failures to this tenant
@@ -885,6 +901,7 @@ class AdvisorFleetService:
             self._final_failure(req, t, e)
         return True
 
+    @tracing.traced("fleet.step", request=True)
     def step(self) -> None:
         """One service iteration: readmit cooled-down tenants, expire
         overdue requests, admit queued requests into free slots, run the
@@ -917,6 +934,39 @@ class AdvisorFleetService:
                     self.retired += 1
         self.steps += 1
 
+    def warm_up(self) -> int:
+        """Run once each kernel program the tenants' estimation can use, so
+        that no later step lowers one: every share group's codec programs
+        at the planner's cheapest sampling fraction (`F_GRID[0]`, which it
+        takes wherever that meets the accuracy target) and at each
+        fraction the group has sampled at (`EstimationEngine.warm_up`),
+        and the planner's scoring programs at the tenants' accuracy
+        targets.  Programs an earlier call ran are not run again.  Returns
+        the launches (0 on NumPy paths)."""
+        from ..kernels import planner_score
+        methods: Dict[Tuple[str, str], set] = {}
+        accuracy = set()
+        for t in self.tenants.values():
+            if t.session is None or t.group is None:
+                continue
+            methods.setdefault(t.group.key, set()).update(
+                t.session.opt.methods)
+            if t.session.opt.planner_backend == "jax":
+                accuracy.add((t.session.opt.e, t.session.opt.q))
+        launches = 0
+        for key, ms in methods.items():
+            group = self.groups[key]
+            for f in sorted({F_GRID[0], *group.samples.fractions()}):
+                done = ("codec", key, f, tuple(sorted(ms)))
+                if done not in self._warmed:
+                    self._warmed.add(done)
+                    launches += group.engine.warm_up(f, sorted(ms))
+        for e, q in sorted(accuracy):
+            if ("planner", e, q) not in self._warmed:
+                self._warmed.add(("planner", e, q))
+                launches += planner_score.warm_up(e, q)
+        return launches
+
     def run_until_drained(self, max_steps: int = 1_000_000) -> None:
         """Step until the queue is empty, or raise `DrainStalled` after
         `max_steps` steps THIS CALL (never silently return with work
@@ -943,6 +993,7 @@ class AdvisorFleetService:
             "queued": len(self.queue),
             "steps": self.steps,
             "retired": self.retired,
+            "recommends": self.recommends,
             "prefetch_batches": self.prefetch_batches,
             "prefetch_targets": self.prefetch_targets,
             "prefetch_hits": self.prefetch_hits,

@@ -145,6 +145,23 @@ class TestEnumerationParity:
         assert rec_b.config == rec_s.config
         assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
 
+    @pytest.mark.parametrize("seed", [1, 3])
+    def test_recommend_matches_scalar_where_rounding_decides(self, schema,
+                                                             seed):
+        """Seeds whose greedy meets candidates (two clustered orderings)
+        with totals one ulp apart: the batched greedy asks the scalar
+        optimizer there, and so takes the scalar greedy's choice."""
+        wl = make_scaled_workload(schema, n_statements=60, seed=seed)
+        adv = DesignAdvisor(wl)
+        base_size = sum(adv.sizes.size(i)
+                        for i in base_configuration(schema).indexes)
+        rec_b = DesignAdvisor(wl, AdvisorOptions.dtac()).recommend(
+            0.25 * base_size)
+        rec_s = DesignAdvisor(wl, AdvisorOptions(use_engine=False)).recommend(
+            0.25 * base_size)
+        assert rec_b.config == rec_s.config
+        assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
+
     def test_insert_heavy_parity(self, schema, base_size):
         wl = make_tpch_workload(schema, insert_weight=50.0)
         rec_b = DesignAdvisor(wl, AdvisorOptions.dtac()).recommend(

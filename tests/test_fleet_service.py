@@ -500,3 +500,93 @@ class TestDurableStoreWiring:
         f3 = AdvisorFleetService.recover(tmp_path)
         assert len(f3.tenants["t0"].session.workload.statements) \
             == len(wl.statements)
+
+
+def drift_delta(rng, schema, tid: str, rnd: int, wl) -> WorkloadDelta:
+    """Two statements replaced by fresh ad-hoc queries, three survivors
+    reweighted (the fleet's drift round)."""
+    names = [s.name for s in wl.statements]
+    removed = tuple(rng.choice(names, size=2, replace=False))
+    added = tuple(dataclasses.replace(s, name=f"{tid}_r{rnd}_{j}")
+                  for j, s in enumerate(make_scaled_workload(
+                      schema, n_statements=2,
+                      seed=700 + 10 * rnd + int(tid[1:])).statements))
+    rw = tuple((n, float(rng.uniform(0.5, 2.0))) for n in rng.choice(
+        [n for n in names if n not in removed], size=3, replace=False))
+    return WorkloadDelta(added=added, removed=removed, reweighted=rw)
+
+
+class TestShareGroupBackend:
+    """The share group's batched prefetch runs on the tenants' own
+    `estimation_backend` (the group's key); the fleet has no backend
+    knob of its own."""
+
+    JAX = dataclasses.replace(AdvisorOptions.dtac(),
+                              estimation_backend="jax",
+                              planner_backend="jax")
+
+    def test_group_engine_takes_the_tenants_estimation_backend(self,
+                                                               schema):
+        assert "backend" not in {f.name for f in
+                                 dataclasses.fields(FleetConfig)}
+        fleet = AdvisorFleetService(FleetConfig(slots=2))
+        fleet.register_tenant("a", tenant_workload(schema, "a"), self.JAX)
+        fleet.register_tenant("b", tenant_workload(schema, "b", seed=3),
+                              AdvisorOptions.dtac())
+        ga, gb = fleet.tenants["a"].group, fleet.tenants["b"].group
+        assert ga is not gb
+        assert (ga.key[1], ga.engine.backend) == ("jax", "jax")
+        assert (gb.key[1], gb.engine.backend) == ("numpy", "numpy")
+
+    def test_jax_prefetch_drift_parity(self, schema):
+        """Drift rounds of several tenants on the jax backend: the
+        prefetch sizes the targets through the codec kernels, and every
+        resolved recommend is `==` a fresh advisor on the tenant's
+        workload."""
+        from repro.kernels import codec_bytes
+        fleet, wls = make_fleet(schema, 3, self.JAX)
+        rng = np.random.default_rng(11)
+        for rnd in range(2):
+            tks = {}
+            for tid in wls:
+                d = drift_delta(rng, schema, tid, rnd, wls[tid])
+                fleet.submit_delta(tid, d)
+                wls[tid] = wls[tid].apply_delta(d)
+                tks[tid] = fleet.submit_recommend(tid, BUDGET)
+            launches = codec_bytes.counters()["kernel_calls"]
+            targets = fleet.prefetch_targets
+            fleet.run_until_drained()
+            assert fleet.prefetch_targets > targets
+            assert codec_bytes.counters()["kernel_calls"] > launches
+            for tid, tk in tks.items():
+                fresh = DesignAdvisor(wls[tid], self.JAX).recommend(BUDGET)
+                assert identical(tk.result(), fresh), (rnd, tid)
+        assert fleet.stats["recommends"] == 6
+
+    def test_warm_up_leaves_nothing_to_lower(self, schema):
+        """`warm_up` before any request, the tenants' first recommends,
+        and `warm_up` again (for any fraction those sampled at besides the
+        planner's cheapest): drift rounds then lower no codec or planner
+        program, and a call runs nothing an earlier one ran."""
+        from repro.kernels import codec_bytes, planner_score
+        fleet, wls = make_fleet(schema, 2, self.JAX)
+        assert fleet.warm_up() > 0
+        for tid in wls:
+            fleet.submit_recommend(tid, BUDGET)
+        fleet.run_until_drained()
+        fleet.warm_up()
+        programs = (codec_bytes._codec_call._cache_size(),
+                    planner_score._fused_call._cache_size(),
+                    planner_score._prob_call._cache_size())
+        rng = np.random.default_rng(12)
+        for rnd in range(2):
+            for tid in wls:
+                d = drift_delta(rng, schema, tid, rnd, wls[tid])
+                fleet.submit_delta(tid, d)
+                wls[tid] = wls[tid].apply_delta(d)
+                fleet.submit_recommend(tid, BUDGET)
+            fleet.run_until_drained()
+        assert (codec_bytes._codec_call._cache_size(),
+                planner_score._fused_call._cache_size(),
+                planner_score._prob_call._cache_size()) == programs
+        assert fleet.warm_up() == 0

@@ -75,6 +75,9 @@ class TestCodecBitEquality:
         ((17, 1), 2),
         ((7, 50), 8),
         ((9, 31), 4),
+        ((15, 37), 10),     # an overlapping last launch on both sides
+        ((16, 37), 10),     # of a launch edge
+        ((73, 37), 10),
     ])
     def test_random_small_values(self, method, shape, rpp):
         cols = RNG.integers(0, 1 << 16, size=shape)
@@ -128,10 +131,13 @@ class TestCodecBitEquality:
         assert got.shape == (0,)
 
     @pytest.mark.parametrize("method", METHODS)
-    @pytest.mark.parametrize("rpp", [273, 682, 1638])
+    @pytest.mark.parametrize("rpp", [31, 32, 33, 64, 65, 127, 128, 129,
+                                     273, 682, 1638])
     def test_unaligned_rows_per_page(self, method, rpp):
         # rows-per-page values of real index widths that are not lane
-        # multiples, with a partial last page
+        # multiples, and page lengths on both sides of page-bucket edges
+        # (the pages past the last and the lanes past a page's rows change
+        # nothing), with a partial last page
         cols = RNG.integers(0, 1 << 12, size=(3, 3 * rpp + 101))
         cols[1] = np.sort(cols[1])          # page-local runs and dictionaries
         assert_codec_exact(method, cols, np.array([2, 4, 8]), rpp)
@@ -234,21 +240,26 @@ class TestCodecBitEquality:
         widths = RNG.integers(1, 9, size=cols.shape[0])
         assert_codec_exact(method, cols, widths, 16)
 
-    @pytest.mark.parametrize("m", [1, 7, 8, 9, 17])
+    @pytest.mark.parametrize("m", [1, 7, 8, 9, 17, 73])
     def test_transfer_counters(self, m):
-        """One launch sends the stack's own bytes (m * n * 8) and the
-        widths (m * 4), and reads back one int32 a target."""
+        """The launches (one per `launch_starts` entry) send CHUNK rows of
+        the stack's own bytes (n * 8 each), their widths (4 each) and one
+        int32 page length, and read back one int32 a row: no pad row, and
+        no row past the ones the call covers."""
         n = 45
         cols = RNG.integers(0, 1 << 33, size=(m, n))
         widths = RNG.integers(1, 9, size=m)
+        launches = len(ck.launch_starts(m))
+        assert launches == -(-m // ck.CHUNK)
         for method in METHODS:
             before = ck.counters()
             assert_codec_exact(method, cols, widths, 10)
             after = ck.counters()
-            assert after["kernel_calls"] == before["kernel_calls"] + 1
+            assert after["kernel_calls"] == before["kernel_calls"] + launches
             assert (after["h2d_bytes"] - before["h2d_bytes"]
-                    == m * n * 8 + m * 4)
-            assert after["d2h_bytes"] - before["d2h_bytes"] == m * 4
+                    == launches * (ck.CHUNK * (n * 8 + 4) + 4))
+            assert (after["d2h_bytes"] - before["d2h_bytes"]
+                    == launches * ck.CHUNK * 4)
 
     @pytest.mark.parametrize("method", METHODS)
     def test_min_max_fold_across_tiles(self, method):
@@ -286,6 +297,62 @@ class TestCodecBitEquality:
             widths = np.full(m, w, dtype=np.int64)
             for method in METHODS:
                 assert_codec_exact(method, cols, widths, rpp)
+
+
+class TestCodecShapes:
+    """The programs a codec call lowers are a small fixed set: the target
+    axis goes in launches of `CHUNK` rows, and a page-local method lays
+    its pages out `page_bucket(page)` lanes wide, the page length a
+    traced value; every shape stays bit-equal to the NumPy references."""
+
+    def test_launches_and_buckets_are_a_small_fixed_set(self):
+        for m in range(1, 301):
+            starts = ck.launch_starts(m)
+            assert len(starts) == -(-m // ck.CHUNK)
+            assert starts == sorted(set(starts)) and starts[0] == 0
+            covered = set()
+            for i in starts:            # every launch holds real rows only
+                assert 0 <= i and (i + ck.CHUNK <= m or m < ck.CHUNK)
+                covered.update(range(i, min(i + ck.CHUNK, m)))
+            assert covered == set(range(m))
+        buckets = {ck.page_bucket(p) for p in range(1, 2049)}
+        assert buckets == {2 ** k for k in range(12)}
+        for p in range(1, 2049):
+            assert ck.page_bucket(p) // 2 < p <= ck.page_bucket(p)
+
+    def test_one_program_per_bucket(self):
+        """Calls of any target count, across launch and bucket edges,
+        lower one program for each (method, page bucket) they use, and no
+        other."""
+        n = 59                          # a sample length no other test uses
+        cols = RNG.integers(0, 1 << 20, size=(73, n))
+        widths = RNG.integers(1, 9, size=73)
+        before = ck._codec_call._cache_size()
+        used = set()
+        for m in (1, 7, 8, 9, 16, 17, 72, 73):
+            for method, rpp in (("NS", 0), ("LDICT", 9), ("LDICT", 12),
+                                ("LDICT", 16), ("LDICT", 17)):
+                assert_codec_exact(method, cols[:m], widths[:m], rpp)
+                used.add((method, ck.page_bucket(rpp)
+                          if method == "LDICT" else 0))
+        assert len(used) == 3
+        assert ck._codec_call._cache_size() - before == len(used)
+
+    def test_warm_up_runs_every_program_once(self):
+        """After `warm_up`, calls of any target count and any page length
+        in the warmed range lower nothing new."""
+        n = 83
+        launches = ck.warm_up(ck.programs(n, ("NS", "LDICT"),
+                                          range(20, 70)))
+        # NS: one; LDICT: page buckets 32, 64, 128 (pages 20..69)
+        assert launches == 1 + 3
+        size = ck._codec_call._cache_size()
+        cols = RNG.integers(0, 1 << 20, size=(75, n))
+        widths = RNG.integers(1, 9, size=75)
+        for m, rpp in ((75, 20), (9, 33), (1, 64), (66, 65), (17, 69)):
+            for method in ("NS", "LDICT"):
+                assert_codec_exact(method, cols[:m], widths[:m], rpp)
+        assert ck._codec_call._cache_size() == size
 
 
 # ---------------------------------------------------------------------------
@@ -419,6 +486,34 @@ class TestFusedScore:
                            self.E, self.Q)
         for x, y in zip(a, b):
             np.testing.assert_array_equal(x, y)
+
+    def test_power_of_two_axes_one_program(self):
+        """Candidate and child counts go up to powers of two: records of
+        9..16 candidates and 3..4 children share one program, and the
+        pads change no output bit (K=3 against K=4 with an EXACT child)."""
+        before = ps._fused_call._cache_size()
+        for nc in (9, 12, 16):
+            m, s, dm, vt, mq = random_rvs(nc, 3, 5, seed=nc)
+            mask67 = np.ones((nc, 5), dtype=bool)
+            a = ps.fused_score(m, s, dm, vt, mq, mask67, None, None,
+                               self.E, 0.37)
+            b = ps.fused_score(np.concatenate([m, np.ones((nc, 1, 5))], 1),
+                               np.concatenate([s, np.zeros((nc, 1, 5))], 1),
+                               dm, vt, mq, mask67, None, None, self.E, 0.37)
+            for x, y in zip(a, b):
+                np.testing.assert_array_equal(x, y)
+        assert ps._fused_call._cache_size() - before == 1
+
+    def test_warm_up_runs_every_program_once(self, monkeypatch):
+        monkeypatch.setattr(ps, "MAX_CANDIDATES", 16)
+        monkeypatch.setattr(ps, "MAX_CHILDREN", 8)
+        assert ps.warm_up(self.E, 0.41) == 2 * 4
+        size = ps._fused_call._cache_size()
+        for nc, k in ((3, 1), (8, 2), (11, 3), (16, 8), (13, 7)):
+            m, s, dm, vt, mq = random_rvs(nc, k, 5, seed=k)
+            ps.fused_score(m, s, dm, vt, mq, np.ones((nc, 5), dtype=bool),
+                           None, None, self.E, 0.41)
+        assert ps._fused_call._cache_size() == size
 
     def test_winner_indices_match_host_argmax(self):
         nc, k, nf = 14, 2, 6

@@ -4,8 +4,9 @@ The TPU compiler compiles for a chip that is described, not attached, so
 these tests need no accelerator.  They catch what interpret mode cannot: a
 primitive Mosaic has no lowering for, an unaligned shape cast, a block
 that overflows scoped VMEM.  Shapes are the TPC-H SF1 main path's:
-SampleCF samples of 60,000 rows, up to 282 targets per codec call, rows
-per page that are not lane multiples, and the planner's largest
+SampleCF samples of 60,000 rows, launches of `CHUNK` of a codec call's
+targets (up to 282 of them), rows per page that are not lane multiples,
+and the planner's largest
 (candidates, children) record.
 
 The topology is described inside a module fixture, never at import time,
@@ -63,15 +64,17 @@ def compile_for(sharding, fn, *shapes):
     ("NS", 0), ("LDICT", 73), ("LDICT", 682), ("LDICT", 1638),
     ("PREFIX", 682), ("PREFIX", 1638), ("RLE", 682), ("RLE", 273)])
 def test_codec_call_compiles(one_chip, method, rpp):
-    """The whole codec call at an SF1 sample: plane split, layout, page
-    sort, kernel.  The stack arrives as its int32 words, (m, 2n), with a
-    target count that is not a whole number of sublanes."""
-    assert TARGETS % 8
+    """The whole codec launch at an SF1 sample: plane split, page
+    gather, page sort, kernel.  The stack arrives as its int32 words,
+    (c, 2n), in one launch of `CHUNK` targets, with the page length a
+    traced int32 and its bucket static."""
+    assert TARGETS > ck.CHUNK
+    bucket = ck.page_bucket(rpp) if method in ck.ORD_DEP_METHODS else 0
     compile_for(one_chip,
-                functools.partial(ck._codec_call, method=method, rpp=rpp,
-                                  interpret=False),
-                ((TARGETS, 2 * SAMPLE_ROWS), jnp.int32),
-                ((TARGETS,), jnp.int32))
+                functools.partial(ck._codec_call, method=method,
+                                  bucket=bucket, interpret=False),
+                ((ck.CHUNK, 2 * SAMPLE_ROWS), jnp.int32),
+                ((ck.CHUNK,), jnp.int32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("method,rows,seg", [
@@ -115,12 +118,15 @@ def test_fused_score_compiles(one_chip):
 
 @pytest.mark.parametrize("kernel,fn,shapes", [
     ("codec_ldict",
-     functools.partial(ck._codec_call, method="LDICT", rpp=682,
+     functools.partial(ck._codec_call, method="LDICT", bucket=1024,
                        interpret=False),
-     [((8, 12000), jnp.int32), ((8,), jnp.int32)]),
+     [((ck.CHUNK, 12000), jnp.int32), ((ck.CHUNK,), jnp.int32),
+      ((), jnp.int32)]),
     ("codec_ns",
-     functools.partial(ck._codec_call, method="NS", rpp=0, interpret=False),
-     [((5, 12000), jnp.int32), ((5,), jnp.int32)]),
+     functools.partial(ck._codec_call, method="NS", bucket=0,
+                       interpret=False),
+     [((ck.CHUNK, 12000), jnp.int32), ((ck.CHUNK,), jnp.int32),
+      ((), jnp.int32)]),
     ("planner_prob",
      functools.partial(ps._prob_call, e=0.5, interpret=False),
      [((1, 128), jnp.float32)] * 2),

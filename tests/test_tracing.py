@@ -198,3 +198,68 @@ def test_spans_match_their_profiler_events(traced_recommend):
     for (name, s0, e0), (_, s1, e1) in zip(recorded, events):
         assert abs(s1 - s0) <= 50e3, name
         assert abs(e1 - e0) <= 50e3, name
+
+
+# every span a fleet step opens around its sessions' own spans
+FLEET_PHASES = {"fleet.admit", "fleet.prefetch", "fleet.cost_prefetch",
+                "fleet.execute"}
+
+
+def _fleet_with_two_recommends():
+    import dataclasses
+
+    from repro.serve.advisor_service import AdvisorFleetService, FleetConfig
+    schema = make_tpch_like(scale=0.1, z=0, seed=0)
+    options = AdvisorOptions(estimation_backend="jax", planner_backend="jax")
+    fleet = AdvisorFleetService(FleetConfig(slots=2))
+    for tid, iw in (("t0", 0.1), ("t1", 20.0)):
+        wl = make_tpch_workload(schema, insert_weight=iw)
+        wl = dataclasses.replace(wl, statements=[
+            dataclasses.replace(s, name=f"{tid}_{s.name}")
+            for s in wl.statements])
+        fleet.register_tenant(tid, wl, options)
+        fleet.submit_recommend(tid, 1e6)
+    return fleet
+
+
+def test_a_fleet_step_nests_its_phases_and_the_sessions_spans(recording):
+    fleet = _fleet_with_two_recommends()
+    fleet.step()
+    spans = tracing.drain()
+    by_id = {s.span_id: s for s in spans}
+    (root,) = [s for s in spans if s.name == "fleet.step"]
+    assert root.parent_id is None and root.request_id == root.span_id
+    assert {s.request_id for s in spans} == {root.span_id}
+    phases = [s for s in spans if s.parent_id == root.span_id]
+    assert {s.name for s in phases} == FLEET_PHASES
+    assert sum(s.name == "fleet.execute" for s in phases) == 2
+    for name in ("fleet.admit", "fleet.prefetch", "fleet.cost_prefetch"):
+        assert sum(s.name == name for s in phases) == 1
+
+    def phase_of(s):
+        while s.parent_id != root.span_id:
+            s = by_id[s.parent_id]
+        return s.name
+
+    inner = [s for s in spans if s.name not in FLEET_PHASES | {"fleet.step"}]
+    assert {phase_of(s) for s in inner} <= {"fleet.prefetch",
+                                             "fleet.cost_prefetch",
+                                             "fleet.execute"}
+    # the prefetch plans the recommends and sizes their targets through
+    # the codec kernels; the slots then find every target in the cache
+    assert {phase_of(s) for s in inner if s.name == "kernel.codec"} \
+        == {"fleet.prefetch"}
+    assert "fleet.prefetch" in {phase_of(s) for s in inner
+                                if s.name == "estimate.plan"}
+    for s in spans:
+        if s.parent_id is not None:
+            parent = by_id[s.parent_id]
+            assert parent.start_ns <= s.start_ns <= s.end_ns <= parent.end_ns
+
+
+def test_a_fleet_step_records_nothing_with_tracing_off():
+    fleet = _fleet_with_two_recommends()
+    tracing.drain()
+    fleet.step()
+    assert fleet.stats["recommends"] == 2
+    assert tracing.drain() == []
