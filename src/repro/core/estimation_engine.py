@@ -27,8 +27,11 @@ Concretely, per (table, f) group the engine:
 
 1. collects the distinct (method, prefix, rows-per-page) jobs of all
    targets (ORD-IND jobs collapse to (method, column));
-2. materializes one `np.lexsort` permutation per *maximal* prefix and
-   reuses it for every shorter prefix it extends;
+2. materializes one permutation per *maximal* prefix and reuses it for
+   every shorter prefix it extends: the prefix's dense column ranks are
+   folded into one int64 key, re-densified where the next column would
+   pass 63 bits and no longer folded once the key is all-distinct, and
+   the keys are argsorted stably in one call (`_prefix_permutations`);
 3. stacks the permuted columns into (ntargets, nrows) matrices grouped by
    (method, rows-per-page) and sizes them with the `*_bytes_batch` kernels
    of `repro.core.compression` (NumPy, or — under the unified
@@ -63,6 +66,19 @@ def _resolve_backend(backend: str) -> str:
     return _resolve(backend, site="estimation_engine")[0]
 
 
+_permute_counters = {"prefixes": 0, "densify": 0, "cut_short": 0,
+                     "columns_skipped": 0, "scattered": 0}
+
+
+def permute_counters() -> Dict[str, int]:
+    """Process-wide counters of `_prefix_permutations`' rank fold: maximal
+    prefixes sorted (`prefixes`), re-densify passes (`densify`), prefixes
+    stopped at an all-distinct key (`cut_short`), the key columns those
+    never folded (`columns_skipped`), and permutations taken by a scatter
+    instead of a sort (`scattered`)."""
+    return dict(_permute_counters)
+
+
 @tracing.traced("samplecf.permute")
 def _prefix_permutations(sample: Table,
                          prefixes: Sequence[Tuple[str, ...]]
@@ -74,55 +90,91 @@ def _prefix_permutations(sample: Table,
     columns only permute rows *within* groups of equal (c_0..c_j) values,
     where c_j is constant.
 
-    The maximal prefixes themselves are sorted in ONE grouped call: each
-    column is replaced by its dense rank (order-isomorphic, so the
-    permutation is unchanged), ranks are bit-packed into a single int64
-    key per prefix, and a stable row-wise argsort sorts the whole
-    (nprefixes, nrows) key matrix at once.  A prefix whose packed ranks
-    exceed 63 bits falls back to np.lexsort — both are stable sorts of the
-    same key sequence, hence the identical permutation.
+    Each maximal prefix is folded into one int64 key, order-isomorphic to
+    its row tuples: the key starts at the first column's dense rank and
+    shifts in each further column's rank (`key << bits | rank`).  When the
+    next column would overflow 63 bits, the key is re-densified
+    (`np.unique(return_inverse=True)`, at most bit_length(nrows - 1) bits)
+    and the fold goes on.  Once the key is all-distinct — a dense rank, or
+    a fold that took in an all-distinct column, with as many distinct
+    values as rows — the later columns cannot reorder it and are never
+    folded.  Maximal prefixes are visited in sorted order and the keys of
+    the current one's heads are kept, so prefixes that share leading
+    columns fold them once.  A stable row-wise argsort of the keys, one
+    call for all of them, gives each permutation (ties broken by row
+    index, as a lexicographic sort does); a key that ended dense and
+    all-distinct is already each row's sorted position and is scattered
+    instead (`perm[key] = arange(n)`).
     """
+    n = sample.nrows
     uniq = set(prefixes)
     parents = {p[:-1] for p in uniq if len(p) > 1}
-    maximal = [p for p in uniq if p not in parents]
+    maximal = sorted(p for p in uniq if p not in parents)
 
-    ranks: Dict[str, np.ndarray] = {}
-    bits: Dict[str, int] = {}
+    # per column: (dense rank, its bits, all-distinct)
+    cols: Dict[str, Tuple[np.ndarray, int, bool]] = {}
 
-    def rank_of(c: str) -> np.ndarray:
-        r = ranks.get(c)
-        if r is None:
+    def rank_of(c: str) -> Tuple[np.ndarray, int, bool]:
+        got = cols.get(c)
+        if got is None:
             u, inv = np.unique(sample.values[c], return_inverse=True)
-            r = ranks[c] = inv.astype(np.int64, copy=False)
-            bits[c] = max(int(u.size - 1).bit_length(), 1)
-        return r
+            got = cols[c] = (inv.astype(np.int64, copy=False),
+                             max(int(u.size - 1).bit_length(), 1),
+                             u.size == n)
+        return got
 
-    out: Dict[Tuple[str, ...], np.ndarray] = {}
-    packable: List[Tuple[str, ...]] = []
+    # heads[j]: (key, bits, all-distinct, dense) of the current maximal
+    # prefix's first j + 1 columns
+    heads: List[Tuple[np.ndarray, int, bool, bool]] = []
+    prev: Tuple[str, ...] = ()
+    # the final keys, once each: prefixes cut short at a shared head
+    # share its key and so its permutation
+    finals: Dict[int, Tuple[np.ndarray, bool]] = {}
+    final_of: Dict[Tuple[str, ...], int] = {}
     for p in maximal:
-        for c in p:
-            rank_of(c)
-        if sum(bits[c] for c in p) <= 63:
-            packable.append(p)
-        else:
-            out[p] = np.lexsort([sample.values[c] for c in reversed(p)])
-    if packable:
-        # depth-wise batched packing: keys[i] = fold over p of (k << b) | r
-        cols_used = sorted(ranks)
-        cidx = {c: i for i, c in enumerate(cols_used)}
-        rmat = np.stack([ranks[c] for c in cols_used])
-        bvec = np.array([bits[c] for c in cols_used], dtype=np.int64)
-        keys = rmat[[cidx[p[0]] for p in packable]].copy()
-        maxlen = max(len(p) for p in packable)
-        for d in range(1, maxlen):
-            sel = np.array([i for i, p in enumerate(packable) if len(p) > d])
-            if not sel.size:
-                continue
-            ci = np.array([cidx[packable[i][d]] for i in sel])
-            keys[sel] = (keys[sel] << bvec[ci, None]) | rmat[ci]
-        perms = np.argsort(keys, axis=1, kind="stable")
-        for i, p in enumerate(packable):
-            out[p] = perms[i]
+        shared = 0
+        while shared < len(heads) and prev[shared] == p[shared]:
+            shared += 1
+        del heads[shared:]
+        prev = p
+        if not heads:
+            r, b, distinct = rank_of(p[0])
+            heads.append((r, b, distinct, True))
+        while len(heads) < len(p) and not heads[-1][2]:
+            key, b = heads[-1][:2]
+            r, rb, distinct = rank_of(p[len(heads)])
+            if b + rb > 63:
+                u, inv = np.unique(key, return_inverse=True)
+                _permute_counters["densify"] += 1
+                key, b = inv.astype(np.int64, copy=False), \
+                    int(u.size - 1).bit_length()
+                # the dense head serves every later prefix that shares it
+                heads[-1] = (key, b, u.size == n, True)
+                if u.size == n:
+                    continue
+            heads.append(((key << rb) | r, b + rb, distinct, False))
+        if len(heads) < len(p):
+            _permute_counters["cut_short"] += 1
+            _permute_counters["columns_skipped"] += len(p) - len(heads)
+        key, _, distinct, dense = heads[-1]
+        finals.setdefault(id(key), (key, distinct and dense))
+        final_of[p] = id(key)
+    _permute_counters["prefixes"] += len(maximal)
+
+    perm_of: Dict[int, np.ndarray] = {}
+    sort = [k for k, (_, scatter) in finals.items() if not scatter]
+    if sort:
+        perms = np.argsort(np.stack([finals[k][0] for k in sort]), axis=1,
+                           kind="stable")
+        perm_of.update(zip(sort, perms))
+    for k, (key, scatter) in finals.items():
+        if scatter:
+            perm = perm_of[k] = np.empty(n, dtype=np.int64)
+            perm[key] = np.arange(n)
+    out: Dict[Tuple[str, ...], np.ndarray] = {}
+    for p, k in final_of.items():
+        out[p] = perm_of[k]
+        _permute_counters["scattered"] += finals[k][1]
     # every needed non-maximal prefix is an ancestor of some maximal one
     for p in maximal:
         perm = out[p]
@@ -308,12 +360,15 @@ class EstimationEngine:
         # codec stacks the jax kernels sent to NumPy for leaving their
         # int32 exactness envelope
         self.envelope_reroutes = 0
+        # this engine's share of permute_counters(): the prefix-sort fold
+        self.permute = dict.fromkeys(_permute_counters, 0)
 
     def stats(self) -> Dict[str, int]:
         return {"batch_calls": self.batch_calls,
                 "targets_estimated": self.targets_estimated,
                 "backend_fallbacks": self.backend_fallbacks,
-                "envelope_reroutes": self.envelope_reroutes}
+                "envelope_reroutes": self.envelope_reroutes,
+                **{f"permute_{k}": v for k, v in self.permute.items()}}
 
     def warm_up(self, f: float, methods: Sequence[str]) -> int:
         """Run once each codec program a batch at fraction `f` can use on
@@ -344,6 +399,7 @@ class EstimationEngine:
             by_table.setdefault(t.table, []).append(t)
         out: Dict = {}
         reroutes = _codec_reroutes(self.backend)
+        permute = permute_counters()
         for tname, ts in by_table.items():
             sample = self.manager.get_sample(tname, f)
             ests = batched_sample_cf(
@@ -353,6 +409,8 @@ class EstimationEngine:
             self.batch_calls += 1
             self.targets_estimated += len(ts)
         self.envelope_reroutes += _codec_reroutes(self.backend) - reroutes
+        for k, v in permute_counters().items():
+            self.permute[k] += v - permute[k]
         return out
 
 

@@ -1,6 +1,7 @@
 """Batched estimation engine: exact parity with scalar SampleCF, batched
 kernel equality, SampleManager determinism, the planner's greedy-vs-optimal
-behavior on small graphs, and the "All" baseline grid scan.
+behavior on small graphs, the "All" baseline grid scan, and the prefix
+sorts' rank fold against np.lexsort.
 
 Everything here is deterministic (no hypothesis dependency) so the parity
 guarantees run in every environment; the hypothesis property twins live in
@@ -18,6 +19,8 @@ from repro.core import (METHODS, AdvisorOptions, DesignAdvisor,
                         make_tpch_like, sample_cf)
 from repro.core import compression as C
 from repro.core import errors as E
+from repro.core.estimation_engine import (_prefix_permutations,
+                                          permute_counters)
 from repro.core.estimation_graph import F_GRID, FORCE_ALL_Q, sampling_cost
 from repro.core.planner_engine import assert_plan_identical
 from repro.core.relation import ColumnDef, Table, rows_per_page
@@ -521,3 +524,162 @@ class TestSinglePageClosedForms:
                 ref = sample_cf(mgr, IndexDef("t", ("a", "b"), m), 1.0,
                                 sample_table=t)
                 assert est.est_bytes == ref.est_bytes, (m, n)
+
+
+# ---------------------------------------------------------------------------
+# Prefix sorts: the rank fold of _prefix_permutations against np.lexsort
+# ---------------------------------------------------------------------------
+
+def _heads(*keys):
+    """Every needed prefix of the given key column tuples."""
+    return sorted({k[:j + 1] for k in keys for j in range(len(k))})
+
+
+def _lineitem_like(n, seed=0):
+    """24 lineitem columns at TPC-H widths (text as 8-byte payload
+    columns, as bench/gen/tpch.py lays them out), with TPC-H's NDVs."""
+    rng = np.random.default_rng(seed)
+    top = (1 << 63) - 1
+    instruct = rng.integers(0, top, (4, 4))[rng.integers(0, 4, n)]
+    cols = {
+        "l_returnflag": (1, rng.integers(0, 3, n)),
+        "l_linestatus": (1, rng.integers(0, 2, n)),
+        "l_shipmode": (1, rng.integers(0, 7, n)),
+        "l_discount": (1, rng.integers(0, 11, n)),
+        "l_tax": (1, rng.integers(0, 9, n)),
+        "l_quantity": (1, 1 + rng.integers(0, 50, n)),
+        "l_linenumber": (4, 1 + rng.integers(0, 7, n)),
+        "l_shipdate": (4, 728_000 + rng.integers(0, 2_526, n)),
+        "l_commitdate": (4, 728_000 + rng.integers(0, 2_526, n)),
+        "l_receiptdate": (4, 728_000 + rng.integers(0, 2_526, n)),
+        "l_suppkey": (4, rng.integers(0, 10_000, n)),
+        "l_partkey": (4, rng.integers(0, 200_000, n)),
+        "l_orderkey": (4, rng.integers(0, 6_000_000, n)),
+        "l_extendedprice": (4, rng.integers(100, 10_500_000, n)),
+    }
+    for i in range(4):
+        cols[f"l_shipinstruct{i}"] = (8, instruct[:, i])
+    for i in range(6):
+        cols[f"l_comment{i}"] = (8, rng.integers(0, top, n))
+    return Table("lineitem", [ColumnDef(c, w) for c, (w, _) in cols.items()],
+                 {c: v for c, (_, v) in cols.items()})
+
+
+# low-NDV columns first, so the fold passes 63 bits before it turns
+# all-distinct, with the wide columns left to skip
+LINEITEM_COVER = (
+    "l_returnflag", "l_linestatus", "l_shipmode", "l_shipinstruct0",
+    "l_shipinstruct1", "l_shipinstruct2", "l_shipinstruct3", "l_discount",
+    "l_tax", "l_quantity", "l_linenumber", "l_shipdate", "l_commitdate",
+    "l_receiptdate", "l_suppkey", "l_partkey", "l_orderkey",
+    "l_extendedprice") + tuple(f"l_comment{i}" for i in range(6))
+
+
+def _perm_case(case):
+    """(table, needed prefixes, counters that must engage) of one case."""
+    rng = np.random.default_rng(7)
+    if case == "wide24":
+        # 24 columns, ~14 bits each at 3000 rows: far past 63 bits
+        t = _lineitem_like(3000)
+        keys = [LINEITEM_COVER, LINEITEM_COVER[11:] + LINEITEM_COVER[:11],
+                tuple(rng.permutation(LINEITEM_COVER))]
+        return t, _heads(*keys), ("densify", "cut_short", "columns_skipped")
+    if case == "distinct_lead":
+        n = 500
+        vals = {"id": rng.permutation(n), "a": rng.integers(0, 5, n),
+                "b": rng.integers(0, 3, n)}
+        t = Table("t", [ColumnDef(c, 4) for c in vals], vals)
+        return t, _heads(("id", "a", "b"), ("a", "id", "b")), \
+            ("cut_short", "scattered")
+    if case == "low_ndv_ties":
+        # 60 two- and three-valued columns, 90 bits: the first 45 repeat
+        # 20 row patterns, so the key is full of ties when it passes 63
+        # bits (at 42 columns) and only the last 15 break them
+        n = 400
+        rows = rng.integers(0, 20, n)
+        vals = {f"c{i}": rng.integers(0, 2 + i % 2, 20)[rows] if i < 45
+                else rng.integers(0, 2 + i % 2, n) for i in range(60)}
+        t = Table("t", [ColumnDef(c, 1) for c in vals], vals)
+        key = tuple(vals)
+        # the third shares the first's re-densified head
+        return t, _heads(key, key[::-1], key[:42] + key[50:]), ("densify",)
+    if case == "near_int64_max":
+        n = 300
+        top = (1 << 63) - 1
+        vals = {"a": top - rng.integers(0, 4, n), "b": top - rng.integers(
+            0, 1 << 40, n), "c": rng.choice([0, top, top - 1], n)}
+        t = Table("t", [ColumnDef(c, 8) for c in vals], vals)
+        return t, _heads(("a", "b", "c"), ("c", "a")), ()
+    if case == "shared_heads":
+        # eight maximal prefixes behind one shared head, cut short there
+        t = _lineitem_like(2000, seed=1)
+        head = ("l_shipdate", "l_orderkey")
+        rest = [c.name for c in t.columns if c.name not in head]
+        keys = [head + tuple(rng.permutation(rest)[:6]) for _ in range(8)]
+        return t, _heads(*keys), ("cut_short", "columns_skipped")
+    n = int(case[1:])
+    vals = {"a": rng.integers(0, 3, n), "b": rng.integers(0, 1 << 60, n)}
+    t = Table("t", [ColumnDef("a", 1), ColumnDef("b", 8)], vals)
+    return t, _heads(("a", "b"), ("b",)), ()
+
+
+@pytest.mark.parametrize("case", ["wide24", "distinct_lead", "low_ndv_ties",
+                                  "near_int64_max", "shared_heads", "n0",
+                                  "n1", "n2"])
+def test_prefix_permutations_match_lexsort(case):
+    """Each maximal prefix's permutation is exactly the stable
+    lexicographic order np.lexsort gives; a shorter prefix reuses the
+    permutation of a maximal prefix it heads, which lays its columns out
+    as np.lexsort of the shorter prefix does."""
+    t, needed, engaged = _perm_case(case)
+    before = permute_counters()
+    got = _prefix_permutations(t, needed)
+    delta = {k: v - before[k] for k, v in permute_counters().items()}
+    assert set(got) == set(needed)
+    for p in needed:
+        ref = np.lexsort([t.values[c] for c in reversed(p)])
+        longer = [q for q in needed if len(q) > len(p) and q[:len(p)] == p]
+        if not longer:
+            np.testing.assert_array_equal(got[p], ref)
+            continue
+        assert any(got[p] is got[q] for q in longer)
+        for c in p:
+            np.testing.assert_array_equal(t.values[c][got[p]],
+                                          t.values[c][ref])
+    maximal = [p for p in needed
+               if not any(q[:-1] == p for q in needed)]
+    assert delta["prefixes"] == len(maximal)
+    for k in engaged:
+        assert delta[k] > 0, (k, delta)
+
+
+def test_batched_24col_covering_key_equals_scalar():
+    """A 24-column covering key on a lineitem-like table, multi-page, is
+    byte-identical to the scalar sample_cf under every order-dependent
+    method, and the fold re-densifies and stops early on it."""
+    t = _lineitem_like(3000)
+    cols = LINEITEM_COVER
+    assert len(cols) == 24 and rows_per_page(sum(
+        t.col_by_name[c].width for c in cols)) < t.nrows
+    methods = ("LDICT", "PREFIX", "RLE")
+    mgr = SampleManager({"lineitem": t}, seed=0)
+    eng = EstimationEngine({"lineitem": t}, mgr)
+    keys = [NodeKey("lineitem", cols, m) for m in methods]
+    got = eng.estimate_batch(keys, 1.0)
+    before = permute_counters()
+    direct = batched_sample_cf(t, t, [(cols, m) for m in methods], 1.0)
+    delta = {k: v - before[k] for k, v in permute_counters().items()}
+    for key, est in zip(keys, direct):
+        ref = sample_cf(mgr, IndexDef("lineitem", cols, key.method), 1.0)
+        assert got[key].est_bytes == ref.est_bytes, key.method
+        assert got[key].cf == ref.cf
+        ref_t = sample_cf(mgr, IndexDef("lineitem", cols, key.method), 1.0,
+                          sample_table=t)
+        assert est.est_bytes == ref_t.est_bytes, key.method
+        assert est.cf == ref_t.cf
+    assert delta["densify"] > 0 and delta["cut_short"] > 0 \
+        and delta["columns_skipped"] > 0, delta
+    st = eng.stats()
+    assert st["permute_prefixes"] == 1
+    assert st["permute_densify"] > 0 and st["permute_cut_short"] > 0 \
+        and st["permute_columns_skipped"] > 0, st
