@@ -288,7 +288,7 @@ def run_recovery(tenants: int, rounds: int, slots: int, statements: int,
     schema = make_tpch_like(scale=scale, z=0, seed=seed)
     opt = dataclasses.replace(AdvisorOptions.dtac(), backend=backend)
     fc = FleetConfig(slots=slots, retry_backoff=(1, 2, 4, 8),
-                     quarantine_after=None, backend=backend)
+                     quarantine_after=None)
     configs = [
         {"name": "journal_only", "compact_after": None, "group_commit": 2},
         {"name": "compact_4", "compact_after": 4, "group_commit": 1},

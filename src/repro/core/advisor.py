@@ -29,8 +29,7 @@ from . import candidates as cand
 from . import tracing
 from .compression import DEFAULT_ADVISOR_METHODS
 from .cost_engine import CostEngine
-from .enumeration import (EnumerationResult, greedy_enumerate,
-                          greedy_enumerate_scalar)
+from .enumeration import EnumerationResult, greedy_enumerate
 from .estimation_engine import EstimationEngine
 from .estimation_graph import EstimationPlanner, NodeKey, Plan
 from .relation import IndexDef
@@ -54,25 +53,21 @@ class AdvisorOptions:
     q: float = 0.9                         # ... at this confidence
     use_deduction: bool = True
     sample_seed: int = 0
-    use_engine: bool = True                # batched cost engine (hot path)
-    engine_backend: str = "numpy"          # "numpy" | "jax"
-    use_batched_estimation: bool = True    # batched SampleCF engine (§4-§5)
-    estimation_backend: str = "numpy"      # "numpy" | "jax"
-    use_batched_planner: bool = True       # batched §5.2 planner engine
-    planner_backend: str = "numpy"         # "numpy" | "jax"
-    # THE unified accelerator knob: backend="jax" (or "numpy") overrides
-    # every per-module *_backend above, threading one backend through
-    # costing, codec-bytes kernels, estimation, planner scoring, and the
-    # fleet COST phase.  None keeps the per-module knobs (compat).
+    # Each engine's backend, "numpy" | "jax" (repro.core.backend).  The
+    # benchmark configurations set these three: estimation and planner on
+    # "jax", costing on "numpy", since the float32 jax cost engine does
+    # not always take the float64 greedy's decisions.
+    engine_backend: str = "numpy"          # CostEngine (costing, greedy)
+    estimation_backend: str = "numpy"      # EstimationEngine (SampleCF)
+    planner_backend: str = "numpy"         # PlannerEngine (§5.2 planner)
+    # One name for all three: backend="jax" (or "numpy") overrides every
+    # per-module *_backend above.  None keeps them as set.
     backend: Optional[str] = None
 
     def __post_init__(self):
         if self.backend is not None:
-            from .backend import BACKENDS
-            if self.backend not in BACKENDS:
-                raise ValueError(f"unknown backend {self.backend!r} "
-                                 f"(expected one of {BACKENDS})")
-            self.engine_backend = self.backend
+            from .backend import resolve
+            self.engine_backend = resolve(self.backend)
             self.estimation_backend = self.backend
             self.planner_backend = self.backend
     # advise on <= ~N weighted representatives (workload compression);
@@ -120,17 +115,13 @@ def pool_with_merged(pool: Dict[Tuple, IndexDef],
 def enumerate_pool(optimizer, sizes, options: AdvisorOptions,
                    pool: Dict[Tuple, IndexDef], base: Configuration,
                    budget_bytes: float,
-                   engine: Optional[CostEngine]) -> EnumerationResult:
-    """§6.2 greedy enumeration dispatch — one shared implementation for
-    the one-shot advisor and the online session (their bit-exact parity
+                   engine: CostEngine) -> EnumerationResult:
+    """§6.2 greedy enumeration — one shared implementation for the
+    one-shot advisor and the online session (their bit-exact parity
     contract depends on running the same code here)."""
-    if engine is not None:
-        return greedy_enumerate(optimizer, sizes, list(pool.values()),
-                                base, budget_bytes,
-                                variant=options.enumeration, engine=engine)
-    return greedy_enumerate_scalar(optimizer, sizes, list(pool.values()),
-                                   base, budget_bytes,
-                                   variant=options.enumeration)
+    return greedy_enumerate(optimizer, sizes, list(pool.values()),
+                            base, budget_bytes,
+                            variant=options.enumeration, engine=engine)
 
 
 @dataclasses.dataclass
@@ -255,7 +246,6 @@ class DesignAdvisor:
         # persistent AdvisorSession planner records
         planner = EstimationPlanner(self.schema.tables,
                                     backend=self.opt.planner_backend,
-                                    use_engine=self.opt.use_batched_planner,
                                     record=False)
         if self.opt.use_deduction:
             plan = planner.plan(targets, self.opt.e, self.opt.q)
@@ -264,12 +254,9 @@ class DesignAdvisor:
             # scanning the f grid for the cheapest fraction that satisfies
             # the (e, q) constraint without deductions.
             plan = planner.plan_all_sampled(targets, self.opt.e, self.opt.q)
-        if self.opt.use_batched_estimation:
-            engine = EstimationEngine(self.schema.tables, self.samples,
-                                      backend=self.opt.estimation_backend)
-            ests = planner.execute(plan, self.samples, engine=engine)
-        else:
-            ests = planner.execute_scalar(plan, self.samples)
+        engine = EstimationEngine(self.schema.tables, self.samples,
+                                  backend=self.opt.estimation_backend)
+        ests = planner.execute(plan, self.samples, engine=engine)
         # execute() also resolves intermediate plan nodes; only register
         # sizes for defs that were actually requested as targets.
         for k, est in ests.items():
@@ -283,18 +270,16 @@ class DesignAdvisor:
     # its incremental caches.  This one-shot composition is the frozen
     # parity reference for the session.
     # ------------------------------------------------------------------
-    def build_engine(self) -> Optional[CostEngine]:
-        """Stage: the batched what-if engine over the current sizes (None
-        on the scalar path).  Built after size estimation so every
-        compressed candidate is scored with its estimated size."""
-        if not self.opt.use_engine:
-            return None
+    def build_engine(self) -> CostEngine:
+        """Stage: the batched what-if engine over the current sizes.
+        Built after size estimation so every compressed candidate is
+        scored with its estimated size."""
         return CostEngine(self.workload, self.sizes,
                           backend=self.opt.engine_backend)
 
     def select_pool(self, per_query_exp: Dict[str, List[IndexDef]],
                     merged_all: Sequence[IndexDef], base: Configuration,
-                    engine: Optional[CostEngine]
+                    engine: CostEngine
                     ) -> Tuple[Dict[Tuple, IndexDef], int]:
         """Stage: per-query candidate costing + §6.1 selection; merged
         candidates enter the pool directly (Figure 1: Merging sits
@@ -312,7 +297,7 @@ class DesignAdvisor:
 
     def enumerate_pool(self, pool: Dict[Tuple, IndexDef],
                        base: Configuration, budget_bytes: float,
-                       engine: Optional[CostEngine]) -> EnumerationResult:
+                       engine: CostEngine) -> EnumerationResult:
         """Stage: §6.2 greedy enumeration over the selected pool."""
         return enumerate_pool(self.optimizer, self.sizes, self.opt, pool,
                               base, budget_bytes, engine)
@@ -327,8 +312,7 @@ class DesignAdvisor:
 
         with tracing.span("advisor.cost"):
             engine = self.build_engine()
-            base_cost = (engine.config_cost(base) if engine is not None
-                         else self.optimizer.workload_cost(base))
+            base_cost = engine.config_cost(base)
             pool, n_cand = self.select_pool(per_query_exp, merged_all, base,
                                             engine)
         res = self.enumerate_pool(pool, base, budget_bytes, engine)
@@ -382,48 +366,41 @@ def staged_recommend(workload: Workload, budget_bytes: float,
     Honors the caller's `AdvisorOptions`: stage 1 runs DTA (no
     compression) but inherits the caller's estimation settings and
     backends, stage 2 plans compressed sizes against the caller's (e, q)
-    rather than a hard-coded (0.5, 0.9), and the stage-2/3 recompression
-    loop is costed through the batched `CostEngine.config_cost` (the
-    scalar `workload_cost` when `use_engine` is off)."""
+    rather than a hard-coded (0.5, 0.9) and on the caller's estimation
+    backend, and the stage-2/3 recompression loop is costed through the
+    batched `CostEngine.config_cost` on the caller's engine backend."""
     opt = options or AdvisorOptions()
     if methods is None:
         methods = opt.methods
     stage1 = dataclasses.replace(
         AdvisorOptions.dta(), e=opt.e, q=opt.q,
         sample_seed=opt.sample_seed, include_clustered=opt.include_clustered,
-        use_engine=opt.use_engine, engine_backend=opt.engine_backend,
-        use_batched_estimation=opt.use_batched_estimation,
+        engine_backend=opt.engine_backend,
         estimation_backend=opt.estimation_backend,
-        use_batched_planner=opt.use_batched_planner,
         planner_backend=opt.planner_backend)
     adv = DesignAdvisor(workload, stage1)
     rec = adv.recommend(budget_bytes)
     # stage 2: compress every selected secondary index with the best method
-    sizes, optimizer = adv.sizes, adv.optimizer
+    sizes = adv.sizes
     # register sizes for compressed variants of the chosen indexes
     chosen = [i for i in rec.config.indexes if not i.clustered]
     variants = cand.expand_with_compression(chosen, methods)
     planner = EstimationPlanner(adv.schema.tables,
-                                backend=opt.planner_backend,
-                                use_engine=opt.use_batched_planner,
-                                record=False)
+                                backend=opt.planner_backend, record=False)
     targets = [NodeKey(i.table, i.cols, i.compression) for i in variants
                if i.compression is not None]
     if targets:
         plan = planner.plan(targets, opt.e, opt.q)
-        ests = (planner.execute(plan, adv.samples)
-                if opt.use_batched_estimation
-                else planner.execute_scalar(plan, adv.samples))
+        engine = EstimationEngine(adv.schema.tables, adv.samples,
+                                  backend=opt.estimation_backend)
+        ests = planner.execute(plan, adv.samples, engine=engine)
         for k, est in ests.items():
             sizes.register(IndexDef(k.table, k.cols, k.method), est.est_bytes)
     # the recompression loop's cost oracle: the batched engine, built
     # AFTER the compressed sizes are registered so variants score with
     # their estimated sizes
-    if opt.use_engine:
-        cost_fn = CostEngine(workload, sizes,
-                             backend=opt.engine_backend).config_cost
-    else:
-        cost_fn = optimizer.workload_cost
+    cost_fn = CostEngine(workload, sizes,
+                         backend=opt.engine_backend).config_cost
     config = rec.config
     for idx in chosen:
         best = (cost_fn(config), config)

@@ -23,9 +23,7 @@ Backend architecture (see repro.core.backend): `batched_bytes(...,
 backend="jax")` dispatches to the Pallas segment-reduce kernels in
 repro.kernels.codec_bytes, which are BIT-IDENTICAL to the NumPy batch
 kernels (int32-safe uint32-plane math — the old int64/x64 gate is gone;
-parity asserted in tests/test_pallas_parity.py).  When jax is unavailable
-the dispatcher runs the NumPy kernels; the unified-backend engines
-surface that fallback via warnings + stats counters (repro.core.backend).
+parity asserted in tests/test_pallas_parity.py).
 """
 from __future__ import annotations
 
@@ -34,12 +32,6 @@ from typing import Callable, Dict, Sequence
 import numpy as np
 
 from .relation import ROW_OVERHEAD, rows_per_page
-
-try:  # optional accelerator backend (repro.kernels idiom: gate, don't require)
-    import jax  # noqa: F401
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - jax is baked into the image
-    HAVE_JAX = False
 
 ORD_IND = "ORD-IND"
 ORD_DEP = "ORD-DEP"
@@ -245,11 +237,6 @@ def rle_bytes_batch(cols: np.ndarray, widths: np.ndarray,
 # module itself, so the dispatcher is exact for every input either way.
 # ---------------------------------------------------------------------------
 
-def jax_batch_ready() -> bool:
-    """True when the accelerated batch kernels can run (exactly)."""
-    return HAVE_JAX
-
-
 BATCH_KERNELS: Dict[str, Callable[[np.ndarray, np.ndarray, int], np.ndarray]] \
     = {
     "NS": ns_bytes_batch,
@@ -263,7 +250,7 @@ BATCH_KERNELS: Dict[str, Callable[[np.ndarray, np.ndarray, int], np.ndarray]] \
 def batched_bytes(method: str, cols: np.ndarray, widths: np.ndarray,
                   rpp: int, backend: str = "numpy") -> np.ndarray:
     """Per-row payload bytes of `method` over an (ntargets, nrows) stack."""
-    if backend == "jax" and jax_batch_ready():
+    if backend == "jax":
         from ..kernels import codec_bytes as _ck
         return _ck.batched_codec_bytes(method, cols, widths, rpp)
     return BATCH_KERNELS[method](cols, widths, rpp)

@@ -30,14 +30,12 @@ Registering an index computes its whole per-statement column in one
 vectorized pass; columns live in capacity-doubling arrays so registration is
 amortized O(statements) per index with no re-stacking.
 
-Backends: plain NumPy (default, float64, bit-compatible with the scalar
-reference) or the unified "jax" backend resolved through `core.backend`
-(one knob for the whole advisor: AdvisorOptions(backend=...)).  Under jax
-the greedy-step scoring kernels — add-secondary, replace-clustered, and
-per-query candidate costing — run as jax.jit array kernels (same idioms
-as repro.kernels.ops).  An unavailable jax never downgrades silently:
-`core.backend.resolve` warns once per site and the engine counts the
-event in ``stats()["backend_fallbacks"]``.
+Backends (`core.backend`): plain NumPy (default, float64, bit-compatible
+with the scalar reference) or "jax", under which the greedy-step scoring
+kernels — add-secondary, replace-clustered, and per-query candidate
+costing — run as float32 jax.jit array kernels (same idioms as
+repro.kernels.ops).  The float32 kernels do not always take the float64
+greedy's decisions, so the chip benchmark costs on NumPy.
 """
 from __future__ import annotations
 
@@ -46,20 +44,14 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
 from . import cost_model as cm
 from .backend import resolve as _resolve_backend
 from .relation import IndexDef, Predicate, Table
 from .whatif import Configuration, SizeProvider, _partial_applicable
 from .workload import BulkInsert, Query, Workload
-
-try:  # optional accelerator backend (repro.kernels idiom: gate, don't require)
-    import jax
-    import jax.numpy as jnp
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - jax is baked into the image
-    jax = None
-    jnp = None
-    HAVE_JAX = False
 
 _INF = float("inf")
 
@@ -491,71 +483,74 @@ class _TableBlock:
 
 
 # ---------------------------------------------------------------------------
-# Optional jax.jit scoring kernel (repro.kernels.ops idiom)
+# jax.jit scoring kernels (repro.kernels.ops idiom)
 # ---------------------------------------------------------------------------
 
-if HAVE_JAX:
-    @jax.jit
-    def _jax_score_secondary(cur_q, cov, seek, ridr, size_c, beta_c,
-                             ncols_used, q_w):
-        npages = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES
-        rid = (cm.T_IO_RAND * jnp.minimum(ridr, npages)
-               + cm.CPU_ROW * ridr
-               + beta_c * ridr * ncols_used[:, None])
-        path = jnp.minimum(cov, seek + rid)
-        new_q = jnp.minimum(cur_q[:, None], path)
-        return q_w @ new_q
 
-    @jax.jit
-    def _jax_score_replace(scanc_c, cov, seek, ridr, size_c, beta_c,
-                           ncols_used, q_w):
-        """Clustered-replacement scoring: every secondary path under every
-        candidate clustered layout.  scanc_c (nq, m) candidate scan costs;
-        cov/seek/ridr (nq, ns) the kept-secondary rows; size_c/beta_c (m,)
-        the candidate layouts' RID coupling."""
-        npages = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES           # (m,)
-        rid = (cm.T_IO_RAND * jnp.minimum(ridr[:, :, None], npages)
-               + cm.CPU_ROW * ridr[:, :, None]
-               + beta_c * ridr[:, :, None] * ncols_used[:, None, None])
-        path = jnp.minimum(cov[:, :, None], seek[:, :, None] + rid)
-        new_q = jnp.minimum(scanc_c, jnp.min(path, axis=1))
-        return q_w @ new_q
+@jax.jit
+def _jax_score_secondary(cur_q, cov, seek, ridr, size_c, beta_c,
+                         ncols_used, q_w):
+    npages = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES
+    rid = (cm.T_IO_RAND * jnp.minimum(ridr, npages)
+           + cm.CPU_ROW * ridr
+           + beta_c * ridr * ncols_used[:, None])
+    path = jnp.minimum(cov, seek + rid)
+    new_q = jnp.minimum(cur_q[:, None], path)
+    return q_w @ new_q
 
-    @jax.jit
-    def _jax_cand_costs(scan_l, cov_s, seek_s, ridr_s, size_l, beta_l,
-                        cov_k, seek_k, ridr_k, size_c, beta_c, ncq,
-                        is_sec):
-        """Per-query candidate costing (one query row, m candidates).
-        Each candidate k is scored under its own layout L_k (the current
-        clustered layout for secondary candidates, the candidate itself
-        for clustered ones): min(scan under L_k, best base-secondary path
-        under L_k, own path under the current layout when secondary)."""
-        npag_l = jnp.maximum(size_l, 0.0) / cm.PAGE_BYTES           # (m,)
-        rid_sl = (cm.T_IO_RAND * jnp.minimum(ridr_s[:, None], npag_l)
-                  + cm.CPU_ROW * ridr_s[:, None]
-                  + beta_l * ridr_s[:, None] * ncq)                 # (ns, m)
-        base_path = jnp.min(
-            jnp.minimum(cov_s[:, None], seek_s[:, None] + rid_sl),
-            axis=0, initial=jnp.inf)                                # (m,)
-        npag_c = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES
-        rid_k = (cm.T_IO_RAND * jnp.minimum(ridr_k, npag_c)
-                 + cm.CPU_ROW * ridr_k + beta_c * ridr_k * ncq)     # (m,)
-        own = jnp.where(is_sec, jnp.minimum(cov_k, seek_k + rid_k),
-                        jnp.inf)
-        return jnp.minimum(jnp.minimum(scan_l, base_path), own)
 
-    @jax.jit
-    def _jax_cand_costs_stacked(scan_l, cov, seek, ridr, size_c, beta_c,
-                                ncq, is_sec):
-        """Cross-job stacked twin of `_jax_cand_costs` for secondary-free
-        bases (the fleet COST-phase prefetch): the same per-element
-        float32 op sequence, so a job scored inside a fleet batch equals
-        the per-job kernel's output bitwise."""
-        npag = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES             # (J,1)
-        rid = (cm.T_IO_RAND * jnp.minimum(ridr, npag)
-               + cm.CPU_ROW * ridr + beta_c * ridr * ncq)           # (J,m)
-        own = jnp.where(is_sec, jnp.minimum(cov, seek + rid), jnp.inf)
-        return jnp.minimum(scan_l, own)
+@jax.jit
+def _jax_score_replace(scanc_c, cov, seek, ridr, size_c, beta_c,
+                       ncols_used, q_w):
+    """Clustered-replacement scoring: every secondary path under every
+    candidate clustered layout.  scanc_c (nq, m) candidate scan costs;
+    cov/seek/ridr (nq, ns) the kept-secondary rows; size_c/beta_c (m,)
+    the candidate layouts' RID coupling."""
+    npages = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES           # (m,)
+    rid = (cm.T_IO_RAND * jnp.minimum(ridr[:, :, None], npages)
+           + cm.CPU_ROW * ridr[:, :, None]
+           + beta_c * ridr[:, :, None] * ncols_used[:, None, None])
+    path = jnp.minimum(cov[:, :, None], seek[:, :, None] + rid)
+    new_q = jnp.minimum(scanc_c, jnp.min(path, axis=1))
+    return q_w @ new_q
+
+
+@jax.jit
+def _jax_cand_costs(scan_l, cov_s, seek_s, ridr_s, size_l, beta_l,
+                    cov_k, seek_k, ridr_k, size_c, beta_c, ncq,
+                    is_sec):
+    """Per-query candidate costing (one query row, m candidates).
+    Each candidate k is scored under its own layout L_k (the current
+    clustered layout for secondary candidates, the candidate itself
+    for clustered ones): min(scan under L_k, best base-secondary path
+    under L_k, own path under the current layout when secondary)."""
+    npag_l = jnp.maximum(size_l, 0.0) / cm.PAGE_BYTES           # (m,)
+    rid_sl = (cm.T_IO_RAND * jnp.minimum(ridr_s[:, None], npag_l)
+              + cm.CPU_ROW * ridr_s[:, None]
+              + beta_l * ridr_s[:, None] * ncq)                 # (ns, m)
+    base_path = jnp.min(
+        jnp.minimum(cov_s[:, None], seek_s[:, None] + rid_sl),
+        axis=0, initial=jnp.inf)                                # (m,)
+    npag_c = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES
+    rid_k = (cm.T_IO_RAND * jnp.minimum(ridr_k, npag_c)
+             + cm.CPU_ROW * ridr_k + beta_c * ridr_k * ncq)     # (m,)
+    own = jnp.where(is_sec, jnp.minimum(cov_k, seek_k + rid_k),
+                    jnp.inf)
+    return jnp.minimum(jnp.minimum(scan_l, base_path), own)
+
+
+@jax.jit
+def _jax_cand_costs_stacked(scan_l, cov, seek, ridr, size_c, beta_c,
+                            ncq, is_sec):
+    """Cross-job stacked twin of `_jax_cand_costs` for secondary-free
+    bases (the fleet COST-phase prefetch): the same per-element
+    float32 op sequence, so a job scored inside a fleet batch equals
+    the per-job kernel's output bitwise."""
+    npag = jnp.maximum(size_c, 0.0) / cm.PAGE_BYTES             # (J,1)
+    rid = (cm.T_IO_RAND * jnp.minimum(ridr, npag)
+           + cm.CPU_ROW * ridr + beta_c * ridr * ncq)           # (J,m)
+    own = jnp.where(is_sec, jnp.minimum(cov, seek + rid), jnp.inf)
+    return jnp.minimum(scan_l, own)
 
 
 class CostEngine:
@@ -568,9 +563,7 @@ class CostEngine:
 
     def __init__(self, workload: Workload, sizes: SizeProvider,
                  backend: str = "numpy"):
-        self.backend, fell_back = _resolve_backend(backend,
-                                                   site="cost_engine")
-        self.backend_fallbacks = int(fell_back)
+        self.backend = _resolve_backend(backend)
         self.workload = workload
         self.sizes = sizes
         self.blocks: Dict[str, _TableBlock] = {}
@@ -591,8 +584,7 @@ class CostEngine:
                 "batch_scores": self.batch_scores,
                 "rows_added": self.rows_added,
                 "rows_removed": self.rows_removed,
-                "cols_refreshed": self.cols_refreshed,
-                "backend_fallbacks": self.backend_fallbacks}
+                "cols_refreshed": self.cols_refreshed}
 
     # -- registration ----------------------------------------------------
     def register(self, idxs: Iterable[IndexDef]) -> np.ndarray:
@@ -896,7 +888,7 @@ def batched_candidate_costs(jobs: Sequence[Dict[str, object]],
     size_c = np.array([j["size_c"] for j in jobs])[:, None]
     beta_c = np.array([j["beta_c"] for j in jobs])[:, None]
     ncq = np.array([j["ncq"] for j in jobs])[:, None]
-    if backend == "jax" and HAVE_JAX:
+    if backend == "jax":
         return np.asarray(_jax_cand_costs_stacked(
             scan_l, cov, seek, ridr, size_c, beta_c, ncq, is_sec),
             dtype=np.float64)

@@ -14,8 +14,9 @@ being added alongside it.
 
 Two execution paths:
 
-* the batched path (default) drives a repro.core.cost_engine.CostEngine and
-  scores the whole pool per greedy step with a few vectorized ops, using
+* the batched path (the advisor's) drives a repro.core.cost_engine.
+  CostEngine and scores the whole pool per greedy step with a few
+  vectorized ops, using
   incremental delta evaluation — a candidate on table T only re-evaluates
   statements on T;
 * `greedy_enumerate_scalar` is the original statement-at-a-time
@@ -68,30 +69,22 @@ def _already_present(config: Configuration, idx: IndexDef) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Batched greedy (the default path)
+# Batched greedy (the advisor's path)
 # ---------------------------------------------------------------------------
 
 def greedy_enumerate(optimizer: WhatIfOptimizer, sizes: SizeProvider,
                      pool: Sequence[IndexDef], base: Configuration,
-                     budget_bytes: float, variant: str = "backtrack",
-                     max_indexes: int = 64,
-                     engine: Optional[CostEngine] = None,
-                     score_chunk_cells: int = 1 << 22,
-                     backend: str = "numpy") -> EnumerationResult:
+                     budget_bytes: float, engine: CostEngine,
+                     variant: str = "backtrack", max_indexes: int = 64,
+                     score_chunk_cells: int = 1 << 22) -> EnumerationResult:
     """Engine-backed hierarchical greedy: candidates are partitioned by
     table, a step re-scores only the partitions its chosen index touched
     (the `stale` set), and each partition's vectorized scoring runs in
     candidate chunks of at most `score_chunk_cells` matrix cells — so the
     peak scratch allocation stays bounded on large workloads.  Chunking is
     value-neutral: every candidate column is scored independently, so the
-    results are bit-identical to one monolithic scoring call.
-
-    `backend` selects the accelerator for a fallback-constructed engine
-    (the unified knob, resolved via `core.backend`); a caller-supplied
-    `engine` keeps its own backend."""
+    results are bit-identical to one monolithic scoring call."""
     assert variant in ("pure", "density", "backtrack")
-    if engine is None:
-        engine = CostEngine(optimizer.workload, sizes, backend=backend)
     pool = list(pool)
     engine.register(base.indexes)
 

@@ -34,17 +34,17 @@ Concretely, per (table, f) group the engine:
    the keys are argsorted stably in one call (`_prefix_permutations`);
 3. stacks the permuted columns into (ntargets, nrows) matrices grouped by
    (method, rows-per-page) and sizes them with the `*_bytes_batch` kernels
-   of `repro.core.compression` (NumPy, or — under the unified
-   `backend="jax"` of repro.core.backend — the bit-identical Pallas
-   segment-reduce kernels in repro.kernels.codec_bytes);
+   of `repro.core.compression` (NumPy, or — with `backend="jax"` — the
+   bit-identical Pallas segment-reduce kernels in
+   repro.kernels.codec_bytes);
 4. assembles per-target compressed bytes, applies the same bias
    correction (`errors.samplecf_bias`) and full-table scaling as
    `sample_cf`, and returns `SizeEstimate`s that match the scalar path
    float-for-float.
 
-Exactness (asserted in tests/test_estimation_engine.py and in
-benchmarks/estimation_scaling.py): per-column integer byte counts equal the
-scalar kernels', so `cf`, `est_bytes` and `cost_pages` are byte-identical.
+Exactness (asserted in tests/test_estimation_engine.py on both backends):
+per-column integer byte counts equal the scalar kernels', so `cf`,
+`est_bytes` and `cost_pages` are byte-identical.
 """
 from __future__ import annotations
 
@@ -59,11 +59,6 @@ from .samplecf import SampleManager, SizeEstimate
 
 # (cols, method) — method None means "uncompressed" (CF = 1.0)
 TargetSpec = Tuple[Tuple[str, ...], Optional[str]]
-
-
-def _resolve_backend(backend: str) -> str:
-    # kept for backwards compatibility; warns + downgrades via core.backend
-    return _resolve(backend, site="estimation_engine")[0]
 
 
 _permute_counters = {"prefixes": 0, "densify": 0, "cut_short": 0,
@@ -349,14 +344,13 @@ class EstimationEngine:
         self.tables = dict(tables)
         self.manager = manager if manager is not None else \
             SampleManager(self.tables, seed=seed)
-        self.backend, fell_back = _resolve(backend, site="estimation_engine")
+        self.backend = _resolve(backend)
         # optional faults.FaultInjector; site "estimation" fires a
         # transient FaultError before any sampling work happens, so a
         # faulted batch is cleanly retryable
         self.faults = faults
         self.batch_calls = 0        # per-(table, f) group batches run
         self.targets_estimated = 0  # total targets sized through the engine
-        self.backend_fallbacks = int(fell_back)  # jax requested, numpy ran
         # codec stacks the jax kernels sent to NumPy for leaving their
         # int32 exactness envelope
         self.envelope_reroutes = 0
@@ -366,7 +360,6 @@ class EstimationEngine:
     def stats(self) -> Dict[str, int]:
         return {"batch_calls": self.batch_calls,
                 "targets_estimated": self.targets_estimated,
-                "backend_fallbacks": self.backend_fallbacks,
                 "envelope_reroutes": self.envelope_reroutes,
                 **{f"permute_{k}": v for k, v in self.permute.items()}}
 

@@ -197,13 +197,13 @@ def _deduction_rv(key: NodeKey, d: Deduction,
 class EstimationPlanner:
     """Builds the graph and runs the greedy (or optimal) state assignment.
 
-    The greedy runs on the batched `planner_engine.PlannerEngine` by default
-    (one pass over a shared deduction graph scores all sampling fractions);
-    `greedy_scalar` is the original per-(target, candidate, f) reference
-    implementation, kept for plan-identical parity checks.  `use_engine`
-    selects the path; `backend` picks the engine's scoring backend
-    ("numpy" — the parity reference — or the optional "jax" mirror of
-    `CostEngine(backend="jax")`).
+    The greedy runs on the batched `planner_engine.PlannerEngine` (one
+    pass over a shared deduction graph scores all sampling fractions);
+    `backend` picks the engine's scoring backend ("numpy" — the parity
+    reference — or the "jax" Pallas kernels).  `greedy_scalar` is the
+    original per-(target, candidate, f) reference implementation, the
+    oracle of the plan-identical parity tests: `plan_scalar`, or a planner
+    built with `use_engine=False`, plans through it.
     """
 
     def __init__(self, tables: Dict[str, Table],
@@ -546,13 +546,12 @@ class EstimationPlanner:
 
     def execute_cached(self, plan: Plan, manager: SampleManager,
                        cache: Dict[Tuple[NodeKey, float], SizeEstimate],
-                       engine: Optional[EstimationEngine] = None,
-                       scalar: bool = False) -> Dict[NodeKey, SizeEstimate]:
+                       engine: Optional[EstimationEngine] = None
+                       ) -> Dict[NodeKey, SizeEstimate]:
         """`execute` with SAMPLED estimates cached by (NodeKey, f) — the
         online-session path.  A SAMPLED node's estimate is a pure function
         of (node, f) given the manager's order-independent samples, so
-        only cache misses are estimated (batched by default, or via the
-        scalar `sample_cf` reference with `scalar=True`); deductions are
+        only cache misses are estimated (in one batch); deductions are
         re-resolved from the plan each call.  Returns estimates identical
         to a fresh `execute`/`execute_scalar` on the same plan.
 
@@ -572,19 +571,12 @@ class EstimationPlanner:
             else:
                 local[k] = est
         if missing:
-            if scalar:
-                for k in missing:
-                    local[k] = cache[(k, plan.f)] = sample_cf(
-                        manager, IndexDef(k.table, k.cols, k.method),
-                        plan.f)
-            else:
-                if engine is None:
-                    engine = EstimationEngine(self.tables, manager)
-                assert engine.manager is manager, \
-                    "engine.manager must be the manager passed in"
-                for k, est in engine.estimate_batch(missing,
-                                                    plan.f).items():
-                    local[k] = cache[(k, plan.f)] = est
+            if engine is None:
+                engine = EstimationEngine(self.tables, manager)
+            assert engine.manager is manager, \
+                "engine.manager must be the manager passed in"
+            for k, est in engine.estimate_batch(missing, plan.f).items():
+                local[k] = cache[(k, plan.f)] = est
         return self._resolve_plan(plan, local.__getitem__)
 
     @tracing.traced("estimate.resolve")

@@ -38,11 +38,9 @@ IEEE operations in the same order as the scalar reference (see
 `errors.compose_batch` / `errors.prob_within_batch`), so the engine is
 **plan-identical** to `greedy_scalar` — same per-node states, same chosen
 deductions, same `total_cost`, for every f — asserted in
-tests/test_core_estimation.py, tests/test_estimation_engine.py and in
-benchmarks/estimation_scaling.py.
+tests/test_core_estimation.py and tests/test_estimation_engine.py.
 
-Backend architecture (see repro.core.backend): under the unified
-`backend="jax"` the candidate-scoring step runs through the Pallas
+Backend architecture (see repro.core.backend): under `backend="jax"` the candidate-scoring step runs through the Pallas
 kernels in repro.kernels.planner_score — `fused_score` fuses the Goodman
 fold, the deduction-error continuation and the masked accuracy
 probability of a whole (candidate x f) record into one float32 kernel,
@@ -83,9 +81,8 @@ def _kind_code(method: str) -> int:
 def assert_plan_identical(ref: Plan, got: Plan, label: str = "") -> None:
     """The engine's parity contract vs `EstimationPlanner.greedy_scalar`:
     same nodes, states, chosen deductions, error RVs, exact sizes,
-    total_cost and feasibility.  Shared by the parity tests and
-    benchmarks/estimation_scaling.py so the asserted contract cannot
-    drift between suites."""
+    total_cost and feasibility.  Shared by the parity tests so the
+    asserted contract cannot drift between suites."""
     tag = f"{label}: " if label else ""
     assert got.f == ref.f and got.targets == ref.targets, \
         tag + "plan identity (f / targets) diverged"
@@ -195,9 +192,7 @@ class PlannerEngine:
                  scost_memo: Optional[Dict] = None, record: bool = True,
                  max_nodes: Optional[int] = None,
                  max_replay: Optional[int] = None, faults=None):
-        self.backend, fell_back = _resolve_backend(backend,
-                                                   site="planner_engine")
-        self.backend_fallbacks = int(fell_back)  # jax requested, numpy ran
+        self.backend = _resolve_backend(backend)
         # record per-target decisions for cross-run replay (the online-
         # session regime).  One-shot throwaway engines pass record=False
         # and skip the bookkeeping entirely.

@@ -243,17 +243,13 @@ class AdvisorSession:
         self.optimizer = WhatIfOptimizer(self.workload, self.sizes)
         self.planner = EstimationPlanner(
             self.schema.tables, backend=self.opt.planner_backend,
-            use_engine=self.opt.use_batched_planner,
             max_nodes=self.opt.max_planner_nodes,
             max_replay=self.opt.max_replay_entries, faults=faults)
-        self.engine: Optional[CostEngine] = (
-            CostEngine(self.workload, self.sizes,
-                       backend=self.opt.engine_backend)
-            if self.opt.use_engine else None)
-        self.est_engine: Optional[EstimationEngine] = (
-            EstimationEngine(self.schema.tables, self.samples,
-                             backend=self.opt.estimation_backend)
-            if self.opt.use_batched_estimation else None)
+        self.engine = CostEngine(self.workload, self.sizes,
+                                 backend=self.opt.engine_backend)
+        self.est_engine = EstimationEngine(
+            self.schema.tables, self.samples,
+            backend=self.opt.estimation_backend)
         # incremental caches
         self._queries: Dict[str, _QueryEntry] = {}
         self._selections: Dict[str, _Selection] = {}
@@ -370,9 +366,8 @@ class AdvisorSession:
             self.workload = new_wl
             self._pending.append(delta)
             return self
-        if self.engine is not None:
-            self.engine.apply_delta(delta)
-            self.engine.workload = new_wl
+        self.engine.apply_delta(delta)
+        self.engine.workload = new_wl
         for name in delta.removed:
             self._retired.add(name)
             self._queries.pop(name, None)
@@ -510,9 +505,8 @@ class AdvisorSession:
         expanded-candidates) jobs can be gathered from live engine
         columns by `CostEngine.cost_job_arrays`.  The selection-staleness
         test is the same one `recommend()` applies, against the same
-        `changed` set.  Returns [] in compressed (outer) mode and when
-        the session has no batched engine."""
-        if self._compressed_mode or self.engine is None:
+        `changed` set.  Returns [] in compressed (outer) mode."""
+        if self._compressed_mode:
             return []
         self.peek_estimation_plan()
         ver, universe, tkey_to_defs, plan = self._peeked
@@ -568,8 +562,7 @@ class AdvisorSession:
                      if n.state is State.SAMPLED
                      and (k, plan.f) not in self._sampled_est)
         ests = self.planner.execute_cached(
-            plan, self.samples, self._sampled_est, engine=self.est_engine,
-            scalar=not self.opt.use_batched_estimation)
+            plan, self.samples, self._sampled_est, engine=self.est_engine)
         self.samplecf_cache_misses += misses
         self.samplecf_cache_hits += plan.n_sampled() - misses
         for k, est in ests.items():
@@ -696,15 +689,13 @@ class AdvisorSession:
             # retryable and the retry recommends bit-identically
             self.faults.check("costing")
         engine = self.engine
-        if engine is not None:
-            engine.sync_sizes()
+        engine.sync_sizes()
         if changed:
             # the scalar optimizer memoizes statement costs by (statement,
             # config); re-registered sizes invalidate those entries (the
             # batched greedy asks it where rounding decides a step)
             self.optimizer._cache.clear()
-        base_cost = (engine.config_cost(base) if engine is not None
-                     else self.optimizer.workload_cost(base))
+        base_cost = engine.config_cost(base)
 
         pre, self._cost_results = self._cost_results, None
         pre_costs = (pre[1] if pre is not None
@@ -772,16 +763,11 @@ class AdvisorSession:
         if isinstance(self._sampled_est, EstimateCache):
             out.update(samplecf_cache_evictions=self._sampled_est.evictions,
                        samplecf_cache_maxsize=self._sampled_est.maxsize)
-        peng = self.planner._engine
-        engines = [e for e in (self.engine, self.est_engine, peng)
-                   if e is not None]
-        out["backend_fallbacks"] = sum(e.backend_fallbacks for e in engines)
-        if self.est_engine is not None:
-            out["envelope_reroutes"] = self.est_engine.envelope_reroutes
-        if self.engine is not None:
-            out.update(engine_rows_added=self.engine.rows_added,
-                       engine_rows_removed=self.engine.rows_removed,
-                       engine_cols_refreshed=self.engine.cols_refreshed)
+        out.update(envelope_reroutes=self.est_engine.envelope_reroutes,
+                   engine_rows_added=self.engine.rows_added,
+                   engine_rows_removed=self.engine.rows_removed,
+                   engine_cols_refreshed=self.engine.cols_refreshed)
+        peng = self.planner._engine   # built lazily by the first plan
         if peng is not None:
             out.update(graph_builds=peng.graph_builds,
                        rec_builds=peng.rec_builds,

@@ -234,7 +234,7 @@ class WhatIfOptimizer:
         `backend=None` (the default, and what internal callers such as
         `workload_cost_batch` pass) reuses whatever engine exists, building
         a numpy one if none does.  An explicit backend that differs from
-        the current engine's resolved backend REBUILDS the engine from the
+        the current engine's backend REBUILDS the engine from the
         provider's current sizes — switching is a fresh build, never an
         error (registered columns and statement deltas do not carry over).
         """
@@ -242,11 +242,9 @@ class WhatIfOptimizer:
         if self._engine is None:
             self._engine = CostEngine(self.workload, self.sizes,
                                       backend=backend or "numpy")
-        elif backend is not None:
-            from .backend import resolve as _resolve
-            if self._engine.backend != _resolve(backend)[0]:
-                self._engine = CostEngine(self.workload, self.sizes,
-                                          backend=backend)
+        elif backend is not None and self._engine.backend != backend:
+            self._engine = CostEngine(self.workload, self.sizes,
+                                      backend=backend)
         return self._engine
 
     def workload_cost_batch(self, configs: Iterable[Configuration]):

@@ -1,18 +1,15 @@
-"""Unified accelerator backend: one knob, loud fallbacks, exact contracts.
+"""Backend plumbing: one name for every engine, and exact contracts.
 
-Covers the backend plumbing the per-kernel suite (test_pallas_parity)
-does not: AdvisorOptions(backend=...) overriding every per-module knob,
-`core.backend.resolve`'s warn-once + counted fallback, WhatIfOptimizer's
-engine REBUILD on backend switch (formerly an AssertionError), the
+Covers what the per-kernel suite (test_pallas_parity) does not:
+AdvisorOptions(backend=...) overriding every per-module field, unknown
+names refused, WhatIfOptimizer's engine REBUILD on backend switch, the
 jax engine kernels against their numpy twins, session and fleet parity
 under backend="jax", the fleet COST-phase stacked costing (bitwise equal
 to per-job scoring on both backends), and the batched delta append.
 
-Hypothesis-free so the module always runs; jax-dependent tests skip
-where jax is genuinely absent.
+Hypothesis-free so the module always runs.
 """
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
@@ -24,12 +21,9 @@ from repro.core import (AdvisorOptions, CostEngine, DesignAdvisor,
 from repro.core import backend as bk
 from repro.core import candidates as cand
 from repro.core.cost_engine import batched_candidate_costs
-from repro.core.estimation_engine import EstimationEngine
 from repro.core.session import AdvisorSession
 from repro.core.whatif import WhatIfOptimizer
 from repro.serve.advisor_service import AdvisorFleetService, FleetConfig
-
-needs_jax = pytest.mark.skipif(not bk.HAVE_JAX, reason="needs jax")
 
 BUDGET = 2_000_000
 
@@ -77,7 +71,6 @@ class TestUnifiedKnob:
         with pytest.raises(ValueError, match="unknown backend"):
             bk.resolve("tpu")
 
-    @needs_jax
     def test_advisor_threads_backend_everywhere(self, workload):
         adv = DesignAdvisor(workload, AdvisorOptions(backend="jax"))
         rec = adv.recommend(BUDGET)
@@ -87,42 +80,7 @@ class TestUnifiedKnob:
         assert adv.opt.estimation_backend == "jax"
 
 
-class TestFallbackIsLoud:
-    def test_warns_once_per_site_and_counts(self, workload, monkeypatch):
-        monkeypatch.setattr(bk, "HAVE_JAX", False)
-        monkeypatch.setattr(bk, "_warned_sites", set())
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            eng = CostEngine(workload, DesignAdvisor(workload).sizes,
-                             backend="jax")
-            eng2 = CostEngine(workload, DesignAdvisor(workload).sizes,
-                              backend="jax")
-        assert eng.backend == "numpy"
-        assert eng.stats()["backend_fallbacks"] == 1
-        assert eng2.stats()["backend_fallbacks"] == 1
-        fallback = [x for x in w
-                    if issubclass(x.category, bk.BackendFallbackWarning)]
-        assert len(fallback) == 1  # once per site, not per engine
-        assert "cost_engine" in str(fallback[0].message)
-
-    def test_estimation_engine_fallback_counts(self, schema, monkeypatch):
-        monkeypatch.setattr(bk, "HAVE_JAX", False)
-        monkeypatch.setattr(bk, "_warned_sites", set())
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            eng = EstimationEngine(schema.tables, backend="jax")
-        assert eng.backend == "numpy"
-        assert eng.stats()["backend_fallbacks"] == 1
-        assert any(issubclass(x.category, bk.BackendFallbackWarning)
-                   for x in w)
-
-    def test_numpy_never_falls_back(self, workload):
-        eng = CostEngine(workload, DesignAdvisor(workload).sizes)
-        assert eng.stats()["backend_fallbacks"] == 0
-
-
 class TestWhatIfEngineRebuild:
-    @needs_jax
     def test_backend_switch_rebuilds_instead_of_raising(self, workload):
         adv = DesignAdvisor(workload)
         w = WhatIfOptimizer(workload, adv.sizes)
@@ -139,7 +97,6 @@ class TestWhatIfEngineRebuild:
         assert np.isfinite(e4.config_cost(base))
 
 
-@needs_jax
 class TestJaxEngineKernels:
     """jax engine kernels vs the numpy float64 twins (float32 tolerance;
     the numpy backend remains the bit-parity reference)."""
@@ -204,7 +161,6 @@ class TestStackedCostBatch:
         for i, want in enumerate(per_job):
             np.testing.assert_array_equal(costs[i, :len(want)], want)
 
-    @needs_jax
     def test_jax_stack_bitwise_equals_per_job(self, workload, schema):
         jobs, per_job = self._jobs(workload, schema, "jax")
         costs = batched_candidate_costs(jobs, backend="jax")
@@ -222,7 +178,6 @@ class TestStackedCostBatch:
             eng.cost_job_arrays(q, base.add(sec), raw)
 
 
-@needs_jax
 class TestSessionJaxParity:
     def test_session_equals_fresh_advisor_jax(self, schema):
         opt = dataclasses.replace(AdvisorOptions.dtac(), backend="jax")
@@ -274,8 +229,7 @@ class TestSessionJaxParity:
 
 
 class TestFleetCostPrefetchParity:
-    @pytest.mark.parametrize("backend", [
-        "numpy", pytest.param("jax", marks=needs_jax)])
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
     def test_fleet_parity_with_cost_prefetch(self, schema, backend):
         opt = dataclasses.replace(AdvisorOptions.dtac(), backend=backend)
         fleet = AdvisorFleetService(FleetConfig(slots=3))

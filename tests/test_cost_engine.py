@@ -11,10 +11,11 @@ from repro.core import (AdvisorOptions, CostEngine, DesignAdvisor,
                         base_configuration, make_scaled_workload,
                         make_tpch_like, make_tpch_workload)
 from repro.core import candidates as cand
-from repro.core.advisor import staged_recommend
-from repro.core.cost_engine import HAVE_JAX
+from repro.core.advisor import (pool_with_merged, select_candidates,
+                                staged_recommend)
 from repro.core.enumeration import greedy_enumerate, greedy_enumerate_scalar
 from repro.core.whatif import Configuration
+from scalar_reference import scalar_recommend
 
 
 @pytest.fixture(scope="module")
@@ -94,7 +95,7 @@ class TestEnumerationParity:
     @pytest.mark.parametrize("frac", [0.0, 0.15, 0.4, 1.0])
     def test_greedy_matches_scalar(self, workload, schema, base_size,
                                    variant, frac):
-        adv = DesignAdvisor(workload, AdvisorOptions(use_engine=False))
+        adv = DesignAdvisor(workload)
         pq, merged_all, all_cands = adv._candidate_universe()
         adv.estimate_sizes(all_cands)
         base = base_configuration(schema)
@@ -123,8 +124,7 @@ class TestEnumerationParity:
         budget = frac * base_size
         rec_b = DesignAdvisor(workload, AdvisorOptions.dtac()).recommend(
             budget)
-        rec_s = DesignAdvisor(workload, AdvisorOptions(
-            use_engine=False)).recommend(budget)
+        rec_s = scalar_recommend(workload, budget)
         assert rec_b.config == rec_s.config
         assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
         assert _rel_err(rec_b.base_cost, rec_s.base_cost) < 1e-6
@@ -140,8 +140,7 @@ class TestEnumerationParity:
                         for i in base_configuration(schema).indexes)
         rec_b = DesignAdvisor(wl, AdvisorOptions.dtac()).recommend(
             0.25 * base_size)
-        rec_s = DesignAdvisor(wl, AdvisorOptions(use_engine=False)).recommend(
-            0.25 * base_size)
+        rec_s = scalar_recommend(wl, 0.25 * base_size)
         assert rec_b.config == rec_s.config
         assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
 
@@ -157,8 +156,7 @@ class TestEnumerationParity:
                         for i in base_configuration(schema).indexes)
         rec_b = DesignAdvisor(wl, AdvisorOptions.dtac()).recommend(
             0.25 * base_size)
-        rec_s = DesignAdvisor(wl, AdvisorOptions(use_engine=False)).recommend(
-            0.25 * base_size)
+        rec_s = scalar_recommend(wl, 0.25 * base_size)
         assert rec_b.config == rec_s.config
         assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
 
@@ -166,14 +164,65 @@ class TestEnumerationParity:
         wl = make_tpch_workload(schema, insert_weight=50.0)
         rec_b = DesignAdvisor(wl, AdvisorOptions.dtac()).recommend(
             0.5 * base_size)
-        rec_s = DesignAdvisor(wl, AdvisorOptions(use_engine=False)).recommend(
-            0.5 * base_size)
+        rec_s = scalar_recommend(wl, 0.5 * base_size)
         assert rec_b.config == rec_s.config
         assert _rel_err(rec_b.cost, rec_s.cost) < 1e-6
 
 
+class TestSmokeParity:
+    """Scalar vs batched advising at a 40-statement workload (scale 0.1,
+    seed 2, budget 0.3 of the base design), with the cost engine of the
+    hot path on each backend."""
+
+    @staticmethod
+    def _pool(adv, per_query_exp, merged_all, base, engine=None):
+        pool = {}
+        for q in adv.workload.queries():
+            costed = cand.cost_candidates(q, per_query_exp[q.name], base,
+                                          adv.optimizer, adv.sizes,
+                                          engine=engine)
+            for c in select_candidates(costed, adv.opt):
+                pool.setdefault(c.index.key, c.index)
+        return list(pool_with_merged(pool, merged_all).values())
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_scalar_vs_batched(self, backend):
+        schema = make_tpch_like(scale=0.1, z=0, seed=2)
+        wl = make_scaled_workload(schema, n_statements=40, seed=2)
+        base = base_configuration(schema)
+        budget = 0.3 * sum(DesignAdvisor(wl).sizes.size(i)
+                           for i in base.indexes)
+
+        adv = DesignAdvisor(wl, AdvisorOptions.dtac())
+        per_query_exp, merged_all, all_cands = adv._candidate_universe()
+        adv.estimate_sizes(all_cands)
+        pool_s = self._pool(adv, per_query_exp, merged_all, base)
+        res_s = greedy_enumerate_scalar(adv.optimizer, adv.sizes, pool_s,
+                                        base, budget)
+        # a fresh advisor: no warm scalar what-if cache
+        adv_b = DesignAdvisor(wl, AdvisorOptions.dtac())
+        adv_b.estimate_sizes(all_cands)
+        engine = CostEngine(wl, adv_b.sizes, backend=backend)
+        pool_b = self._pool(adv_b, per_query_exp, merged_all, base, engine)
+        res_b = greedy_enumerate(adv_b.optimizer, adv_b.sizes, pool_b, base,
+                                 budget, engine=engine)
+        # the jax scoring kernels run in float32
+        tol = 1e-6 if backend == "numpy" else 1e-3
+        assert [p.key for p in pool_b] == [p.key for p in pool_s]
+        assert res_b.config == res_s.config
+        assert _rel_err(res_b.cost, res_s.cost) <= tol
+
+        # end to end on the chip benchmark's paths: estimation and
+        # planner on `backend`, costing on numpy
+        rec_b = DesignAdvisor(wl, AdvisorOptions(
+            estimation_backend=backend,
+            planner_backend=backend)).recommend(budget)
+        rec_s = scalar_recommend(wl, budget)
+        assert rec_b.config == rec_s.config
+        assert _rel_err(rec_b.cost, rec_s.cost) <= 1e-6
+
+
 class TestJaxBackend:
-    @pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
     def test_jax_backend_close_to_numpy(self, workload, base_size):
         budget = 0.3 * base_size
         rec_np = DesignAdvisor(workload, AdvisorOptions.dtac()).recommend(

@@ -7,8 +7,6 @@ Everything here is deterministic (no hypothesis dependency) so the parity
 guarantees run in every environment; the hypothesis property twins live in
 tests/test_core_compression.py and tests/test_core_estimation.py.
 """
-import dataclasses
-
 import numpy as np
 import pytest
 
@@ -72,17 +70,16 @@ class TestBatchKernelParity:
         assert got.tolist() == [0, 0, 0]
 
     def test_jax_dispatcher_falls_back_without_x64(self):
-        # default jax config is x64-off: int64 codec math is unavailable,
-        # so backend="jax" must silently resolve to the numpy kernels
+        # jax's default config is x64-off: backend="jax" runs the int32-
+        # safe Pallas kernels, bit-identical to the numpy kernels
         rng = np.random.default_rng(0)
         cols = rng.integers(0, 1000, (2, 64))
         w = np.array([4, 4])
         a = C.batched_bytes("LDICT", cols, w, 16, backend="numpy")
         b = C.batched_bytes("LDICT", cols, w, 16, backend="jax")
         assert a.tolist() == b.tolist()
-        if not C.jax_batch_ready():
-            assert EstimationEngine({}, SampleManager({}),
-                                    backend="jax").backend == "numpy"
+        assert EstimationEngine({}, SampleManager({}),
+                                backend="jax").backend == "jax"
 
     def test_unknown_backend_rejected(self):
         with pytest.raises(ValueError):
@@ -130,19 +127,20 @@ class TestEnginePlanParity:
                 assert ests[k].cf == ref.cf
 
     def test_estimate_sizes_batched_equals_scalar(self, schema):
+        """The advisor's registered sizes equal per-target `sample_cf`
+        (`execute_scalar`) on the plan it executed."""
         wl = make_scaled_workload(schema, n_statements=40, seed=1)
-        adv_b = DesignAdvisor(wl, AdvisorOptions.dtac())
-        adv_s = DesignAdvisor(wl, dataclasses.replace(
-            AdvisorOptions.dtac(), use_batched_estimation=False))
-        _, _, cands_b = adv_b._candidate_universe()
-        _, _, cands_s = adv_s._candidate_universe()
-        cost_b, plan_b, ns_b, nd_b = adv_b.estimate_sizes(cands_b)
-        cost_s, plan_s, ns_s, nd_s = adv_s.estimate_sizes(cands_s)
-        assert (cost_b, ns_b, nd_b) == (cost_s, ns_s, nd_s)
-        for idx in cands_b:
-            if idx.compression is not None:
-                assert adv_b.sizes.size(idx) == adv_s.sizes.size(idx)
-        assert adv_b.sizes.fallback_hits == 0
+        adv = DesignAdvisor(wl, AdvisorOptions.dtac())
+        _, _, cands = adv._candidate_universe()
+        cost, plan, ns, nd = adv.estimate_sizes(cands)
+        assert (cost, ns, nd) == (plan.total_cost, plan.n_sampled(),
+                                  plan.n_deduced())
+        ref = EstimationPlanner(schema.tables).execute_scalar(
+            plan, SampleManager(schema.tables, seed=adv.opt.sample_seed))
+        for k, defs in DesignAdvisor.estimation_targets(cands).items():
+            for idx in defs:
+                assert adv.sizes.size(idx) == ref[k].est_bytes, idx.label()
+        assert adv.sizes.fallback_hits == 0
 
     def test_mv_index_size_matches_scalar_reference(self, schema):
         samples = SampleManager(schema.tables, seed=0)
@@ -305,28 +303,70 @@ class TestPlannerEngine:
         assert eng.batch_runs == 3
 
     def test_estimate_sizes_planner_toggle_parity(self, schema):
+        """The advisor's plan is plan-identical to the scalar planner's,
+        and its sizes equal that plan's scalar execution."""
         wl = make_scaled_workload(schema, n_statements=40, seed=1)
-        adv_b = DesignAdvisor(wl, AdvisorOptions.dtac())
-        adv_s = DesignAdvisor(wl, dataclasses.replace(
-            AdvisorOptions.dtac(), use_batched_planner=False))
-        _, _, cands_b = adv_b._candidate_universe()
-        _, _, cands_s = adv_s._candidate_universe()
-        cost_b, plan_b, ns_b, nd_b = adv_b.estimate_sizes(cands_b)
-        cost_s, plan_s, ns_s, nd_s = adv_s.estimate_sizes(cands_s)
-        assert (cost_b, ns_b, nd_b) == (cost_s, ns_s, nd_s)
-        assert plan_b.f == plan_s.f
-        for idx in cands_b:
-            if idx.compression is not None:
-                assert adv_b.sizes.size(idx) == adv_s.sizes.size(idx)
+        adv = DesignAdvisor(wl, AdvisorOptions.dtac())
+        _, _, cands = adv._candidate_universe()
+        _, plan, _, _ = adv.estimate_sizes(cands)
+        tkeys = DesignAdvisor.estimation_targets(cands)
+        planner_s = EstimationPlanner(schema.tables, use_engine=False)
+        plan_s = planner_s.plan(list(tkeys), adv.opt.e, adv.opt.q)
+        assert_plan_identical(plan_s, plan)
+        ref = planner_s.execute_scalar(
+            plan_s, SampleManager(schema.tables, seed=adv.opt.sample_seed))
+        for k, defs in tkeys.items():
+            for idx in defs:
+                assert adv.sizes.size(idx) == ref[k].est_bytes, idx.label()
 
     def test_backend_gating(self, schema):
-        # default jax config is x64-off: float64 scoring is unavailable,
-        # so backend="jax" must silently resolve to numpy
-        if not C.jax_batch_ready():
-            assert PlannerEngine(schema.tables,
-                                 backend="jax").backend == "numpy"
+        # both names run as asked; any other name is refused
+        assert PlannerEngine(schema.tables, backend="jax").backend == "jax"
         with pytest.raises(ValueError):
             PlannerEngine(schema.tables, backend="tpu")
+
+
+class TestSmokeParity:
+    """Plan-identical planning and byte-identical SampleCF at a 40-
+    statement workload (scale 0.1, seed 0), with SampleCF on each
+    backend; the planner runs its numpy parity backend."""
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_engines_match_oracles(self, backend):
+        schema = make_tpch_like(scale=0.1, z=0, seed=0)
+        wl = make_scaled_workload(schema, n_statements=40, seed=0)
+        adv = DesignAdvisor(wl, AdvisorOptions(estimation_backend=backend))
+        e, q = adv.opt.e, adv.opt.q
+        _, _, cands = adv._candidate_universe()
+        tkeys = DesignAdvisor.estimation_targets(cands)
+        targets = list(tkeys)
+        planner = EstimationPlanner(schema.tables)
+        plan = planner.plan(targets, e, q)
+        assert_plan_identical(planner.plan_scalar(targets, e, q), plan,
+                              "plan()")
+        for f, got in zip(F_GRID, planner.engine.greedy_batch(
+                targets, e, q, F_GRID)):
+            assert_plan_identical(planner.greedy_scalar(targets, f, e, q),
+                                  got, f"greedy(f={f})")
+
+        mgr_s = SampleManager(schema.tables, seed=adv.opt.sample_seed)
+        mgr_b = SampleManager(schema.tables, seed=adv.opt.sample_seed)
+        ests_s = planner.execute_scalar(plan, mgr_s)
+        engine = EstimationEngine(schema.tables, mgr_b, backend=backend)
+        ests_b = planner.execute(plan, mgr_b, engine=engine)
+        assert engine.targets_estimated > 0
+        assert set(ests_s) == set(ests_b)
+        for k, ref in ests_s.items():
+            got = ests_b[k]
+            assert (got.est_bytes == ref.est_bytes and got.cf == ref.cf
+                    and got.cost_pages == ref.cost_pages
+                    and got.method == ref.method), k.label()
+
+        adv.estimate_sizes(cands)
+        for k, defs in tkeys.items():
+            for idx in defs:
+                assert adv.sizes.size(idx) == ests_s[k].est_bytes, \
+                    idx.label()
 
 
 class TestGreedyVsOptimal:

@@ -21,7 +21,10 @@ from repro.core import (AdvisorOptions, AdvisorSession, DesignAdvisor,
                         make_scaled_workload, make_tpch_like,
                         make_tpch_workload)
 from repro.core.advisor import staged_recommend
+from repro.core.estimation_engine import EstimationEngine
+from repro.core.whatif import WhatIfOptimizer
 from repro.core.workload import BulkInsert, Query
+from scalar_reference import scalar_recommend
 
 
 @pytest.fixture(scope="module")
@@ -207,11 +210,11 @@ class TestSessionParity:
                          DesignAdvisor(wl, opt).recommend(budget))
 
     def test_scalar_path_session_parity(self, schema, base_size):
-        """use_engine=False exercises the scalar optimizer path (with its
-        memo purge on re-registered sizes)."""
+        """A session after a delta equals the scalar reference (scalar
+        planner, `sample_cf`, what-if optimizer and greedy) on the drifted
+        workload."""
         wl = make_scaled_workload(schema, n_statements=12, seed=4)
-        opt = AdvisorOptions(use_engine=False, use_batched_planner=False,
-                             use_batched_estimation=False)
+        opt = AdvisorOptions()
         sess = AdvisorSession(wl, opt)
         budget = 0.3 * base_size
         sess.recommend(budget)
@@ -223,8 +226,13 @@ class TestSessionParity:
                               reweighted=((wl.statements[0].name, 3.0),))
         wl2 = wl.apply_delta(delta)
         sess.apply(delta)
-        assert_identical(sess.recommend(budget),
-                         DesignAdvisor(wl2, opt).recommend(budget))
+        rec, ref = sess.recommend(budget), scalar_recommend(wl2, budget, opt)
+        # the cost engine sums a statement's terms in another order than
+        # the scalar optimizer: costs agree to rounding, all else exactly
+        assert abs(rec.cost - ref.cost) <= 1e-12 * ref.cost
+        assert abs(rec.base_cost - ref.base_cost) <= 1e-12 * ref.base_cost
+        assert_identical(dataclasses.replace(rec, cost=ref.cost,
+                                             base_cost=ref.base_cost), ref)
 
 
 # ---------------------------------------------------------------------------
@@ -298,19 +306,51 @@ class TestSessionIncrementality:
 # ---------------------------------------------------------------------------
 
 class TestStagedOptions:
-    def test_staged_honors_custom_e_q(self, workload, base_size):
-        opt = AdvisorOptions(e=1.0, q=0.8)
+    def test_staged_honors_custom_e_q(self, workload, base_size,
+                                      monkeypatch):
+        """Custom (e, q), and stage 2's SampleCF runs on the caller's
+        estimation backend."""
+        backends = []
+        batch = EstimationEngine.estimate_batch
+
+        def spy(self, *args, **kwargs):
+            backends.append(self.backend)
+            return batch(self, *args, **kwargs)
+
+        monkeypatch.setattr(EstimationEngine, "estimate_batch", spy)
+        opt = AdvisorOptions(e=1.0, q=0.8, estimation_backend="jax")
         rec = staged_recommend(workload, 0.3 * base_size, options=opt)
         assert rec.cost <= rec.base_cost + 1e-9
+        assert backends and set(backends) == {"jax"}
 
     def test_staged_scalar_engine_close_to_batched(self, workload,
-                                                   base_size):
-        b = 0.3 * base_size
-        rec_b = staged_recommend(workload, b)
-        rec_s = staged_recommend(workload, b,
-                                 options=AdvisorOptions(use_engine=False))
-        assert rec_b.config == rec_s.config
-        assert abs(rec_b.cost - rec_s.cost) <= 1e-6 * max(rec_s.cost, 1.0)
+                                                   base_size, monkeypatch):
+        """The recompression loop run on the scalar what-if optimizer
+        picks the batched loop's configuration, at the same cost."""
+        stage1 = {}
+        recommend = DesignAdvisor.recommend
+
+        def spy(self, budget_bytes):
+            stage1["adv"] = self
+            stage1["rec"] = recommend(self, budget_bytes)
+            return stage1["rec"]
+
+        monkeypatch.setattr(DesignAdvisor, "recommend", spy)
+        rec = staged_recommend(workload, 0.3 * base_size)
+        # stage 2 registered the compressed sizes on stage 1's provider
+        oracle = WhatIfOptimizer(workload, stage1["adv"].sizes)
+        config = stage1["rec"].config
+        for idx in [i for i in config.indexes if not i.clustered]:
+            best = (oracle.workload_cost(config), config)
+            for m in AdvisorOptions().methods:
+                cfg2 = config.replace(idx, idx.with_compression(m))
+                c2 = oracle.workload_cost(cfg2)
+                if c2 < best[0]:
+                    best = (c2, cfg2)
+            config = best[1]
+        assert rec.config == config
+        cost_s = oracle.workload_cost(config)
+        assert abs(rec.cost - cost_s) <= 1e-6 * max(cost_s, 1.0)
 
 
 # ---------------------------------------------------------------------------
