@@ -44,7 +44,8 @@ Backend architecture (see repro.core.backend): under `backend="jax"` the candida
 kernels in repro.kernels.planner_score — `fused_score` fuses the Goodman
 fold, the deduction-error continuation and the masked accuracy
 probability of a whole (candidate x f) record into one float32 kernel,
-and `prob_within` is the matching probability stage used by the memoized
+`fused_score_batch` scores a batch of records in one launch of it (the
+walk gathers them ahead, `_batch`), and `prob_within` is the matching probability stage used by the memoized
 `_prob_cached` path (feasibility, replay verification).  Both kernels
 share one probability op sequence, so a probability recomputed from a
 stored (mean, std) pair — buf values are float32-exact once written — is
@@ -171,6 +172,22 @@ class _RecReplay:
 
 
 @dataclasses.dataclass
+class _Scoring:
+    """A re-decided target's pre-decision inputs to lines 6-9 of the §5.2
+    pseudocode, gathered from the walk's buffer, and its candidates'
+    scores.  The scores are a pure function of these inputs, which are
+    read from the target's own row and its children's rows alone."""
+    ch: tuple                    # per-block child views (`_gather`)
+    mask67: np.ndarray           # (nc, nf) lines 6-7 eligibility
+    pre9: Optional[np.ndarray]   # (nc, nf) lines 8-9 precondition
+    extra: Optional[np.ndarray]  # (nc, nf) summed cost of unknown children
+    rvs: tuple                   # (m_s, s_s, m_x, s_x) child RVs per block
+    cm: Optional[np.ndarray] = None   # (nc, nf) composed RV mean
+    cs: Optional[np.ndarray] = None   # ... std
+    p: Optional[np.ndarray] = None    # ... masked accuracy probability
+
+
+@dataclasses.dataclass
 class _RunState:
     """Resolved per-(node, f) arrays of one `_run` pass, pre-assembly."""
     g: _Graph
@@ -250,6 +267,9 @@ class PlannerEngine:
         self.replay_hits = 0      # per-(target) decisions replayed in _run
         self.replay_verified = 0  # ... replayed after appended-mate checks
         self.replay_misses = 0    # ... recomputed (inputs really changed)
+        self.score_launches = 0   # batches of re-decided targets scored
+        self.scored_records = 0   # ... targets in them
+        self.stale_rescores = 0   # ... scores dropped: inputs written first
         self.universe_evictions = 0  # epoch resets of the node universe
         self.replay_evictions = 0    # replay stores dropped at max_replay
         self.replay_faults = 0       # ... dropped by injected faults
@@ -427,19 +447,15 @@ class PlannerEngine:
             return _ps.prob_within(means, stds, e)
         return err.prob_within_batch(means, stds, e)
 
-    def _jax_score(self, rec: _TargetRec, m_s, s_s, m_x, s_x,
-                   mask67: np.ndarray, pre9, extra, e: float,
-                   q: float) -> tuple:
-        """jax backend: score one record's whole (candidate, child, f)
-        stack with the fused Pallas kernel.  ColSet candidates sit at
-        k=0 with EXACT pads after them — folding exact (1, 0) factors is
-        the float32 multiplicative identity, so the packed stack scores
-        bit-identically to per-block kernel calls (the property
-        `_verify_changed` relies on when it re-scores inserted mates
-        alone).  Returns (cm, cs, p): float32 values in float64 arrays,
-        p masked to mask67|pre9 exactly like the numpy path."""
-        from ..kernels import planner_score as _ps
-        nc, nf = mask67.shape
+    def _stack(self, rec: _TargetRec, sc: _Scoring) -> tuple:
+        """jax backend: one record's (candidate, child, f) stack for the
+        fused Pallas kernel.  ColSet candidates sit at k=0 with EXACT pads
+        after them — folding exact (1, 0) factors is the float32
+        multiplicative identity, so the packed stack scores bit-identically
+        to per-block kernel calls (the property `_verify_changed` relies on
+        when it re-scores inserted mates alone)."""
+        m_s, s_s, m_x, s_x = sc.rvs
+        nc, nf = sc.mask67.shape
         ncs = rec.ncs
         kmax = m_x.shape[1] if m_x is not None else 1
         m = np.ones((nc, kmax, nf))
@@ -460,9 +476,53 @@ class PlannerEngine:
             dm[ncs:] = rec.cx_dm
             vt[ncs:] = rec.cx_vterm
             mq[ncs:] = rec.cx_msq
-        cm, cs, p, _, _ = _ps.fused_score(m, s, dm, vt, mq, mask67, pre9,
-                                          extra, e, q)
-        return cm, cs, p
+        return m, s, dm, vt, mq, sc.mask67, sc.pre9, sc.extra
+
+    def _score(self, recs: Sequence[_TargetRec], batch: Dict[int, _Scoring],
+               e: float, q: float) -> None:
+        """Fill each batch member's (cm, cs, p): p masked to the eligible
+        entries of both phases.  jax: one fused Pallas launch for the
+        whole batch (float32 values in float64 arrays; winner selection
+        stays on the host, and p is float32-exact so argmax over it
+        agrees).  numpy: the float64 parity path, record by record."""
+        self.score_launches += 1
+        self.scored_records += len(batch)
+        if self.backend == "jax":
+            from ..kernels import planner_score as _ps
+            got = _ps.fused_score_batch(
+                [self._stack(recs[j], sc) for j, sc in batch.items()], e, q)
+            for sc, (cm, cs, p) in zip(batch.values(), got):
+                sc.cm, sc.cs, sc.p = cm, cs, p
+            return
+        cs_dm, cs_msq, cs_vt = self._cs_fac
+        for j, sc in batch.items():
+            rec = recs[j]
+            m_s, s_s, m_x, s_x = sc.rvs
+            cmA = vA = e2A = None
+            if m_s is not None:
+                msq_s = m_s * m_s
+                cmA = m_s * cs_dm
+                vA = (s_s * s_s + msq_s) * cs_vt
+                e2A = msq_s * cs_msq
+            cmB = vB = e2B = None
+            if m_x is not None:
+                # Goodman fold over the children axis, continued with the
+                # deduction-error factor — bit-identical to the scalar
+                # compose (children in order, deduction last)
+                cmB, vB, e2B = err.goodman_fold(m_x, s_x, axis=1)
+                cmB = cmB * rec.cx_dm
+                vB = vB * rec.cx_vterm
+                e2B = e2B * rec.cx_msq
+            sc.cm = self._concat(cmA, cmB)
+            v = self._concat(vA, vB)
+            e2 = self._concat(e2A, e2B)
+            sc.cs = np.sqrt(np.maximum(v - e2, 0.0))
+            # one probability pass over both phases' eligible entries
+            mask_p = sc.mask67 if sc.pre9 is None else sc.mask67 | sc.pre9
+            sc.p = np.zeros(mask_p.shape)
+            ii = mask_p.nonzero()
+            if ii[0].size:
+                sc.p[ii] = self._prob_cached(sc.cm[ii], sc.cs[ii], e)
 
     def _prob_cached(self, means: np.ndarray, stds: np.ndarray,
                      e: float) -> np.ndarray:
@@ -593,6 +653,131 @@ class PlannerEngine:
         if b is None:
             return a
         return np.concatenate([a, b], axis=0)
+
+    def _inputs(self, rec: _TargetRec, buf: np.ndarray, act: np.ndarray,
+                ch: Optional[tuple], samp_mean: np.ndarray,
+                samp_std: np.ndarray) -> _Scoring:
+        """Lines 6-9 inputs of a re-decided target with undecided
+        fractions `act`, read from `buf` (its row and its children's rows
+        only).
+
+        One composed-RV evaluation serves BOTH phases: with unknown
+        children substituted by their hypothetical SampleCF error, the
+        trial RV of lines 8-9 equals the actual deduction RV of lines 6-7
+        on fully-known rows (the where() substitutes nothing there)."""
+        if ch is None:
+            ch = self._gather(rec, buf)
+        chs, chx = ch                      # per-block child views
+        # per-block accumulators, concatenated in candidate order (ColSet
+        # first): a single-child fold equals the padded fold (the EXACT
+        # pads multiply by exact 1.0), so the block split is bit-identical
+        # to one padded block
+        known_s = chs[:, 0, :] != _NONE if chs is not None else None
+        if chx is not None:
+            known_x = chx[:, :, 0, :] != _NONE
+            allk_x = known_x.all(axis=1)   # (ncx, nf)
+        else:
+            allk_x = None
+        allk = self._concat(known_s, allk_x)   # (nc, nf)
+        any_unknown = not allk.all()
+        kc = rec.kind
+        m_s = s_s = m_x = s_x = None
+        if chs is not None:
+            m_s = chs[:, 1, :]
+            s_s = chs[:, 2, :]
+            if any_unknown:
+                # children RVs, unknown ones hypothetically sampled (all
+                # children share the target's method, hence one Table 2
+                # error fit per record)
+                m_s = np.where(known_s, m_s, samp_mean[kc])
+                s_s = np.where(known_s, s_s, samp_std[kc])
+        if chx is not None:
+            m_x = chx[:, :, 1, :]
+            s_x = chx[:, :, 2, :]
+            if any_unknown:
+                m_x = np.where(known_x, m_x, samp_mean[kc])
+                s_x = np.where(known_x, s_x, samp_std[kc])
+        mask67 = allk & act
+        pre9 = extra = None
+        if any_unknown:
+            # lines 8-9 precondition: summed sampling cost of the unknown
+            # children.  add.reduce over a non-contiguous axis is a
+            # sequential fold (numpy pairwise blocking needs the reduction
+            # axis contiguous), and the known children's exact 0.0 terms
+            # leave every partial sum unchanged — so this matches the
+            # scalar child-order sum bit-for-bit (asserted in the parity
+            # tests).
+            extraA = None if chs is None else \
+                np.where(known_s, 0.0, chs[:, 3, :])
+            extraB = None if chx is None else np.add.reduce(
+                np.where(known_x, 0.0, chx[:, :, 3, :]), axis=1)
+            extra = self._concat(extraA, extraB)
+            pre9 = ~allk & (extra < buf[rec.tid, 3, :]) & act
+        return _Scoring(ch, mask67, pre9, extra, (m_s, s_s, m_x, s_x))
+
+    def _batch(self, recs: Sequence[_TargetRec], i: int, first: _Scoring,
+               buf: np.ndarray, dirty: np.ndarray, store: Optional[Dict],
+               e: float, q: float, samp_mean: np.ndarray,
+               samp_std: np.ndarray) -> Dict[int, _Scoring]:
+        """Score `recs[i]` together with the targets after it that the
+        walk will re-decide before it writes anything they read.
+
+        A target's scores are a pure function of its row and its
+        children's rows, so they can be computed ahead of the walk, from
+        the buffer as it is now, for every target whose reads no earlier
+        target writes.  Writes known before any decision: a target with an
+        undecided fraction writes its own row (lines 6-11), and a replayed
+        decision writes the nodes it recorded.  The batch stops before the
+        first target that reads one of those, and where the scoring kernel
+        holds no more rows.  Writes known only once a decision is made (a
+        lines 8-9 sample of unknown children) `_run` catches when it
+        applies a score.  Targets the walk replays, or that have nothing
+        left to decide, are not scored."""
+        fits = None
+        if self.backend == "jax":
+            from ..kernels import planner_score as _ps
+            fits = _ps.fits
+        state = buf[:, 0, :]
+        batch = {i: first}
+        rows, k = len(recs[i].cands), recs[i].cx_ids.shape[1]
+        will = np.zeros(buf.shape[0], dtype=bool)   # written before j
+        will[recs[i].tid] = True
+        for j in range(i + 1, len(recs)):
+            r = recs[j]
+            if will[r.tid] or will[r.all_child_ids].any():
+                break
+            rr = store.get(r.key) if store is not None else None
+            ch = None
+            if rr is not None:
+                if rr.rec is not r:
+                    # a changed record: `_verify_changed` decides it alone
+                    will[r.tid] = True
+                    will[rr.written] = True
+                    continue
+                if not dirty[r.tid] and not dirty[r.all_child_ids].any():
+                    will[rr.written] = True       # fast-path replay
+                    continue
+                if np.array_equal(rr.view_tid, buf[r.tid]):
+                    if rr.view_ch is not None:
+                        ch = self._gather(r, buf)
+                    if rr.view_ch is None or \
+                            self._views_equal(rr.view_ch, ch):
+                        will[rr.written] = True   # replay by its view
+                        continue
+            act = state[r.tid] == _NONE
+            if not act.any():
+                continue
+            will[r.tid] = True
+            if not r.cands:
+                continue
+            kj = max(k, r.cx_ids.shape[1])
+            if fits is not None and not fits(rows + len(r.cands), kj):
+                break
+            batch[j] = self._inputs(r, buf, act, ch, samp_mean, samp_std)
+            rows += len(r.cands)
+            k = kj
+        self._score(recs, batch, e, q)
+        return batch
 
     def _verify_changed(self, rec: _TargetRec, rr: _RecReplay,
                         buf: np.ndarray, dirty: np.ndarray, e: float,
@@ -783,9 +968,20 @@ class PlannerEngine:
         the gathered child rows) is bit-identical to the recorded one.
         Only targets actually affected by a workload delta (changed mate
         groups, changed child states, new targets) are re-scored.
+
+        Re-scored targets are scored in batches (`_batch`: on the jax
+        backend, one kernel launch each) and decided in walk order.  A
+        score is applied only if nothing its target reads was written
+        after the batch was gathered, so every decision is made from
+        exactly the inputs it would see one target at a time.
         """
         self.batch_runs += 1
         f_grid = tuple(f_grid)
+        if self.backend == "jax":
+            # every program a batch can launch, before any decision can
+            # pick a shape
+            from ..kernels import planner_score as _ps
+            _ps.warm_once(e, q)
         if self.record:
             if self.faults is not None and \
                     self.faults.fires("planner_replay"):
@@ -842,7 +1038,14 @@ class PlannerEngine:
                 dirty[store[k].written] = True
                 del store[k]
 
-        for rec in g.recs:
+        # re-decided targets are scored in batches gathered ahead of the
+        # walk (`_batch`) and applied here in order.  `held` keeps the
+        # batch's scores not applied yet; `touched` flags every node the
+        # walk wrote since the batch was gathered: a score whose target
+        # reads a touched node is stale
+        held: Dict[int, _Scoring] = {}
+        touched = np.zeros(n + 1, dtype=bool)
+        for i, rec in enumerate(g.recs):
             tid = rec.tid
             rr = store.get(rec.key) if store is not None else None
             fresh = rr is not None and rr.rec is rec
@@ -852,6 +1055,7 @@ class PlannerEngine:
                 # so its input view is bit-identical by induction
                 self.replay_hits += 1
                 self._replay_rec(rr, buf, used, chosen, total)
+                touched[rr.written] = True
                 continue
             tview = buf[tid].copy() if store is not None else None
             ch = None
@@ -864,6 +1068,7 @@ class PlannerEngine:
                     # nothing new becomes dirty
                     self.replay_hits += 1
                     self._replay_rec(rr, buf, used, chosen, total)
+                    touched[rr.written] = True
                     continue
             elif rr is not None and rr.rec is not rec:
                 # candidate record changed (mate-group delta): decision-
@@ -873,6 +1078,7 @@ class PlannerEngine:
                 if ok:
                     self.replay_verified += 1
                     self._replay_rec(rr, buf, used, chosen, total)
+                    touched[rr.written] = True
                     store[rec.key] = dataclasses.replace(
                         rr, rec=rec, view_ch=ch)
                     continue
@@ -886,95 +1092,23 @@ class PlannerEngine:
             kc = rec.kind
             has6 = has9 = false_f
             if nc:
-                if ch is None:
-                    ch = self._gather(rec, buf)
-                chs, chx = ch                      # per-block child views
-                # per-block Goodman accumulators, concatenated in candidate
-                # order (ColSet first): a single-child fold equals the
-                # padded fold (the EXACT pads multiply by exact 1.0), so
-                # the block split is bit-identical to one padded block
-                known_s = chs[:, 0, :] != _NONE if chs is not None else None
-                if chx is not None:
-                    known_x = chx[:, :, 0, :] != _NONE
-                    allk_x = known_x.all(axis=1)   # (ncx, nf)
-                else:
-                    allk_x = None
-                allk = self._concat(known_s, allk_x)   # (nc, nf)
-                any_unknown = not allk.all()
-                cs_dm, cs_msq, cs_vt = self._cs_fac
-                m_s = s_s = m_x = s_x = None
-                if chs is not None:
-                    m_s = chs[:, 1, :]
-                    s_s = chs[:, 2, :]
-                    if any_unknown:
-                        # children RVs, unknown ones hypothetically sampled
-                        # (all children share the target's method, hence
-                        # one Table 2 error fit per record)
-                        m_s = np.where(known_s, m_s, samp_mean[kc])
-                        s_s = np.where(known_s, s_s, samp_std[kc])
-                if chx is not None:
-                    m_x = chx[:, :, 1, :]
-                    s_x = chx[:, :, 2, :]
-                    if any_unknown:
-                        m_x = np.where(known_x, m_x, samp_mean[kc])
-                        s_x = np.where(known_x, s_x, samp_std[kc])
-                if self.backend != "jax":
-                    cmA = vA = e2A = None
-                    if chs is not None:
-                        msq_s = m_s * m_s
-                        cmA = m_s * cs_dm
-                        vA = (s_s * s_s + msq_s) * cs_vt
-                        e2A = msq_s * cs_msq
-                    cmB = vB = e2B = None
-                    if chx is not None:
-                        # Goodman fold over the children axis, continued
-                        # with the deduction-error factor — bit-identical
-                        # to the scalar compose (children in order,
-                        # deduction last)
-                        cmB, vB, e2B = err.goodman_fold(m_x, s_x, axis=1)
-                        cmB = cmB * rec.cx_dm
-                        vB = vB * rec.cx_vterm
-                        e2B = e2B * rec.cx_msq
-                    cm = self._concat(cmA, cmB)
-                    v = self._concat(vA, vB)
-                    e2 = self._concat(e2A, e2B)
-                    cs = np.sqrt(np.maximum(v - e2, 0.0))
-
-                mask67 = allk & act
-                if any_unknown:
-                    # lines 8-9 precondition: summed sampling cost of the
-                    # unknown children.  add.reduce over a non-contiguous
-                    # axis is a sequential fold (numpy pairwise blocking
-                    # needs the reduction axis contiguous), and the known
-                    # children's exact 0.0 terms leave every partial sum
-                    # unchanged — so this matches the scalar child-order
-                    # sum bit-for-bit (asserted in the parity tests).
-                    extraA = None if chs is None else \
-                        np.where(known_s, 0.0, chs[:, 3, :])
-                    extraB = None if chx is None else np.add.reduce(
-                        np.where(known_x, 0.0, chx[:, :, 3, :]), axis=1)
-                    extra = self._concat(extraA, extraB)
-                    my_cost = scost[tid]           # (nf,)
-                    pre9 = ~allk & (extra < my_cost) & act
-                    mask_p = mask67 | pre9
-                else:
-                    pre9 = None
-                    mask_p = mask67
-
-                if self.backend == "jax":
-                    # fused Pallas kernel: compose + masked probability in
-                    # one pass (winner selection stays on the host; p is
-                    # float32-exact so argmax over it agrees)
-                    cm, cs, p = self._jax_score(
-                        rec, m_s, s_s, m_x, s_x, mask67, pre9,
-                        extra if any_unknown else None, e, q)
-                else:
-                    # one probability pass over both phases' eligible
-                    # entries
-                    p = np.zeros((nc, nf))
-                    ii = mask_p.nonzero()
-                    if ii[0].size:
-                        p[ii] = self._prob_cached(cm[ii], cs[ii], e)
+                sc = held.pop(i, None)
+                if (sc is None or touched[tid]
+                        or touched[rec.all_child_ids].any()):
+                    # no score gathered, or one gathered before a write to
+                    # what this target reads (a lines 8-9 sample, or a
+                    # decision the walk could not foresee): drop what is
+                    # held and start a new batch here
+                    self.stale_rescores += len(held) + (sc is not None)
+                    held = self._batch(
+                        g.recs, i,
+                        self._inputs(rec, buf, act, ch, samp_mean, samp_std),
+                        buf, dirty, store, e, q, samp_mean, samp_std)
+                    touched[:] = False
+                    sc = held.pop(i)
+                ch = sc.ch
+                cm, cs, p = sc.cm, sc.cs, sc.p
+                mask67, pre9, extra = sc.mask67, sc.pre9, sc.extra
                 sat = p >= q
 
                 # ---- lines 6-7: an enabled deduction satisfying (e, q) --
@@ -1006,6 +1140,7 @@ class PlannerEngine:
                                 buf[cid, :3, fi] = (_SAMPLED,
                                                     samp_mean[kc, fi],
                                                     samp_std[kc, fi])
+                                touched[cid] = True
                                 c = float(scost[cid, fi])
                                 total[fi] += c
                                 r_child.append((int(cid), fi,
@@ -1029,6 +1164,8 @@ class PlannerEngine:
                     c = float(scost[tid, fi])
                     total[fi] += c
                     r_tot.append((fi, c))
+            if act.any():
+                touched[tid] = True
 
             # ---- record the decision + propagate dirtiness --------------
             if store is None:
@@ -1061,6 +1198,7 @@ class PlannerEngine:
                 dirty[written] = True
             store[rec.key] = rr2
 
+        self.stale_rescores += len(held)
         return _RunState(g=g, targets=tuple(targets), f_grid=f_grid,
                          state=state, mean=buf[:, 1, :], std=buf[:, 2, :],
                          used=used, chosen=chosen, total=total)

@@ -775,6 +775,9 @@ class AdvisorSession:
                        replay_hits=peng.replay_hits,
                        replay_verified=peng.replay_verified,
                        replay_misses=peng.replay_misses,
+                       score_launches=peng.score_launches,
+                       scored_records=peng.scored_records,
+                       stale_rescores=peng.stale_rescores,
                        universe_nodes=len(peng._node_keys),
                        universe_peak_nodes=peng.peak_nodes,
                        universe_evictions=peng.universe_evictions,

@@ -12,7 +12,11 @@ Two kernels behind `PlannerEngine(backend="jax")`:
   child, f) RV stack, continued with the deduction-error factor, the
   composed std, the masked accuracy probability, and the lines-6-9 winner
   selection (first-argmax of p over eligible candidates, first-argmin of
-  the extra sampling cost) per fraction.
+  the extra sampling cost) per fraction.  `fused_score_batch` puts the
+  candidate rows of several targets through the same kernel in one
+  launch; rows are independent, so each target's values are bitwise
+  those of its own launch.  `programs` is the fixed set of padded
+  shapes, `warm_up` runs it.
 
 Consistency contract (this is what keeps replay and session-vs-fresh
 plan equality exact under the jax backend): both kernels evaluate the
@@ -36,6 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -233,12 +238,88 @@ def _fused_call(m, s, dm, vt, mq, m67, p9, ex, *, k: int, e: float,
     )(m, s, dm, vt, mq, m67, p9, ex)
 
 
-def _pad_axis(a: np.ndarray, axis: int, size: int, fill) -> np.ndarray:
-    if a.shape[axis] == size:
-        return a
-    shape = list(a.shape)
-    shape[axis] = size - a.shape[axis]
-    return np.concatenate([a, np.full(shape, fill, dtype=a.dtype)], axis=axis)
+# the launches `warm_up` runs, and the walk's cap on a batch: candidate
+# rows, children per candidate, and padded rows x padded children.  A
+# (rows, children x 128 lanes) float32 stack of 4096 rows x children is
+# what one v5e kernel's scoped VMEM holds with m and s double-buffered
+# (128 x 64 runs out).  TPC-H SF1 records reach 19 candidates and 30
+# children, and a batch of them 70 rows; a larger record is a launch of
+# its own and lowers its program when it first comes.
+MAX_CANDIDATES = 128
+MAX_CHILDREN = 64
+MAX_CELLS = 4096
+
+
+def _buckets(rows: int, k: int) -> Tuple[int, int]:
+    """Padded (candidate, child) axes: powers of two keep the programs a
+    small fixed set; EXACT (mean=1, std=0) child pads are the fold's
+    exact identity, and pad candidates are never eligible."""
+    return max(8, 1 << (rows - 1).bit_length()), 1 << (k - 1).bit_length()
+
+
+def fits(rows: int, k: int) -> bool:
+    """Whether a launch of `rows` candidates of up to `k` children is one
+    of the programs `warm_up` runs."""
+    nc_pad, k_pad = _buckets(rows, k)
+    return (nc_pad <= MAX_CANDIDATES and k_pad <= MAX_CHILDREN
+            and nc_pad * k_pad <= MAX_CELLS)
+
+
+def programs() -> List[Tuple[int, int]]:
+    """The padded (candidates, children) of every program `warm_up` runs:
+    each power-of-two pair that `fits`."""
+    out = []
+    nc = 8
+    while nc <= MAX_CANDIDATES:
+        k = 1
+        while fits(nc, k):
+            out.append((nc, k))
+            k *= 2
+        nc *= 2
+    return out
+
+
+Record = Tuple[np.ndarray, ...]   # (m, s, dm, vt, mq, mask67, pre9, extra)
+
+
+def _launch(records: Sequence[Record], e: float, q: float) -> tuple:
+    """Pack records' candidate rows one after another into one padded
+    stack, EXACT-padded to the widest record's children, and launch the
+    fused kernel once.  Returns the device outputs and the real rows."""
+    nf = records[0][5].shape[1]
+    rows = sum(r[0].shape[0] for r in records)
+    nc_pad, k_pad = _buckets(rows, max(r[0].shape[1] for r in records))
+    nf_pad = -(-nf // _LANES) * _LANES
+    mp = np.ones((nc_pad, k_pad, nf_pad), dtype=np.float32)
+    sp = np.zeros((nc_pad, k_pad, nf_pad), dtype=np.float32)
+    dmp, vtp, mqp = (np.ones((nc_pad, 1), dtype=np.float32)
+                     for _ in range(3))
+    m67p, p9p = (np.zeros((nc_pad, nf_pad), dtype=np.int32)
+                 for _ in range(2))
+    exp_ = np.zeros((nc_pad, nf_pad), dtype=np.float32)
+    r0 = 0
+    for m, s, dm, vt, mq, mask67, pre9, extra in records:
+        nc, k, _ = m.shape
+        r1 = r0 + nc
+        mp[r0:r1, :k, :nf] = m
+        sp[r0:r1, :k, :nf] = s
+        dmp[r0:r1] = dm
+        vtp[r0:r1] = vt
+        mqp[r0:r1] = mq
+        m67p[r0:r1, :nf] = mask67
+        if pre9 is not None:
+            p9p[r0:r1, :nf] = pre9
+        if extra is not None:
+            exp_[r0:r1, :nf] = extra
+        r0 = r1
+    args = (mp.reshape(nc_pad, k_pad * nf_pad),
+            sp.reshape(nc_pad, k_pad * nf_pad), dmp, vtp, mqp, m67p, p9p,
+            exp_)
+    outs = _fused_call(*jax.device_put(args), k=k_pad, e=float(e),
+                       q=float(q), interpret=pallas_interpret())
+    _counters["fused_calls"] += 1
+    _counters["h2d_bytes"] += sum(a.nbytes for a in args)
+    return outs, rows
 
 
 @tracing.traced("kernel.planner")
@@ -255,38 +336,11 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
     float32 values in float64 arrays — and the per-f winner indices
     (int64; meaningless where the respective mask column is empty).
     """
-    nc, k, nf = m.shape
-    # powers of two on the candidate and child axes keep the programs a
-    # small fixed set; EXACT (mean=1, std=0) child pads are the fold's
-    # exact identity, and pad candidates are never eligible
-    nc_pad = max(8, 1 << (nc - 1).bit_length())
-    k_pad = 1 << (k - 1).bit_length()
-    nf_pad = -(-nf // _LANES) * _LANES
-    m = _pad_axis(np.asarray(m, dtype=np.float32), 1, k_pad, 1.0)
-    s = _pad_axis(np.asarray(s, dtype=np.float32), 1, k_pad, 0.0)
-    z = np.zeros((nc, nf)) if pre9 is None else pre9
-    x = np.zeros((nc, nf)) if extra is None else extra
-
-    def prep(a, fill, dtype):
-        a = _pad_axis(np.asarray(a, dtype=dtype), 0, nc_pad, fill)
-        return _pad_axis(a, a.ndim - 1, nf_pad, fill)
-
-    mp = prep(m, 1.0, np.float32).reshape(nc_pad, k_pad * nf_pad)
-    sp = prep(s, 0.0, np.float32).reshape(nc_pad, k_pad * nf_pad)
-    dmp = _pad_axis(np.asarray(dm, dtype=np.float32), 0, nc_pad, 1.0)
-    vtp = _pad_axis(np.asarray(vt, dtype=np.float32), 0, nc_pad, 1.0)
-    mqp = _pad_axis(np.asarray(mq, dtype=np.float32), 0, nc_pad, 1.0)
-    m67p = prep(mask67, 0, np.int32)
-    p9p = prep(z, 0, np.int32)
-    exp_ = prep(x, 0.0, np.float32)
-
-    args = (mp, sp, dmp, vtp, mqp, m67p, p9p, exp_)
-    outs = _fused_call(*(jnp.asarray(a) for a in args), k=k_pad, e=float(e),
-                       q=float(q), interpret=pallas_interpret())
-    cm, cs, p, w6, w9 = outs
-    _counters["fused_calls"] += 1
-    _counters["h2d_bytes"] += sum(a.nbytes for a in args)
+    nc, _, nf = m.shape
+    outs, _ = _launch([(m, s, dm, vt, mq, mask67, pre9, extra)], e, q)
+    outs = jax.device_get(outs)
     _counters["d2h_bytes"] += sum(o.nbytes for o in outs)
+    cm, cs, p, w6, w9 = outs
     return (np.asarray(cm, dtype=np.float64)[:nc, :nf],
             np.asarray(cs, dtype=np.float64)[:nc, :nf],
             np.asarray(p, dtype=np.float64)[:nc, :nf],
@@ -294,26 +348,56 @@ def fused_score(m: np.ndarray, s: np.ndarray, dm: np.ndarray,
             np.asarray(w9, dtype=np.int64)[0, :nf])
 
 
-# the largest record `warm_up` runs: candidates and children per candidate
-# (TPC-H SF1 records reach 19 candidates and 30 children; a larger record
-# lowers its program when it first comes)
-MAX_CANDIDATES = 32
-MAX_CHILDREN = 64
+@tracing.traced("kernel.planner")
+def fused_score_batch(records: Sequence[Record], e: float,
+                      q: float) -> List[Tuple[np.ndarray, ...]]:
+    """`fused_score` of several records in one launch and one read-back.
+
+    Each record is `fused_score`'s (m, s, dm, vt, mq, mask67, pre9,
+    extra).  Every candidate row of the kernel is computed from its own
+    inputs alone, and EXACT child pads are the fold's identity, so each
+    record's (cm, cs, p) is bitwise what `fused_score` returns for it.
+    The in-kernel winners span the whole launch and are not read back:
+    the caller selects each record's winners from its own rows."""
+    nf = records[0][5].shape[1]
+    outs, rows = _launch(records, e, q)
+    cm, cs, p = (np.asarray(o, dtype=np.float64)[:rows, :nf]
+                 for o in jax.device_get(outs[:3]))
+    _counters["d2h_bytes"] += sum(o.nbytes for o in outs[:3])
+    res = []
+    r0 = 0
+    for rec in records:
+        r1 = r0 + rec[0].shape[0]
+        res.append((cm[r0:r1], cs[r0:r1], p[r0:r1]))
+        r0 = r1
+    return res
+
+
+# accuracy targets whose programs this process has run: compiled
+# programs live in jax's process-wide cache, so this set does too
+_warmed: set = set()
 
 
 def warm_up(e: float, q: float) -> int:
-    """Run once each `fused_score` program of up to MAX_CANDIDATES
-    candidates and MAX_CHILDREN children at accuracy (e, q): every
-    power-of-two pair of those axes.  Returns the launches."""
-    launches = 0
-    nc = 8
-    while nc <= MAX_CANDIDATES:
-        k = 1
-        while k <= MAX_CHILDREN:
-            ones, zeros = np.ones((nc, 1)), np.zeros((nc, 1), dtype=bool)
-            fused_score(np.ones((nc, k, 1)), np.zeros((nc, k, 1)), ones,
-                        ones, ones, zeros, None, None, e, q)
-            launches += 1
-            k *= 2
-        nc *= 2
-    return launches
+    """Run once each `fused_score` program of `programs()` at accuracy
+    (e, q).  Returns the launches."""
+    shapes = programs()
+    for nc, k in shapes:
+        ones, zeros = np.ones((nc, 1)), np.zeros((nc, 1), dtype=bool)
+        fused_score(np.ones((nc, k, 1)), np.zeros((nc, k, 1)), ones, ones,
+                    ones, zeros, None, None, e, q)
+    _warmed.add((float(e), float(q)))
+    return len(shapes)
+
+
+def warm_once(e: float, q: float) -> int:
+    """`warm_up(e, q)` the first time a compiling process asks for it,
+    so that no scoring launch whose shape depends on the walk's decisions
+    lowers a program later.  Interpret mode (the CPU) compiles nothing
+    worth keeping out of a timed window and skips it.  Returns the
+    launches."""
+    key = (float(e), float(q))
+    if key in _warmed or pallas_interpret():
+        return 0
+    _warmed.add(key)
+    return warm_up(e, q)

@@ -1023,6 +1023,13 @@ class AdvisorFleetService:
             if isinstance(g.cache, EstimateCache))
         out["sampling_calls"] = sum(
             g.samples.sampling_calls for g in self.groups.values())
+        # the tenants' planners: scoring batches, the targets scored in
+        # them, and the scores dropped because an earlier decision wrote
+        # what their targets read
+        sessions = [t.session.stats for t in self.tenants.values()
+                    if t.session is not None]
+        for k in ("score_launches", "scored_records", "stale_rescores"):
+            out[k] = sum(st.get(k, 0) for st in sessions)
         return out
 
     def tenant_stats(self, tenant_id: str) -> Dict[str, float]:

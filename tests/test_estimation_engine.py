@@ -23,6 +23,7 @@ from repro.core.estimation_graph import F_GRID, FORCE_ALL_Q, sampling_cost
 from repro.core.planner_engine import assert_plan_identical
 from repro.core.relation import ColumnDef, Table, rows_per_page
 from repro.core.samplecf import full_index_sizes
+from repro.core.workload import make_tpch_workload
 from repro.core.synopses import MVDef, SynopsisManager
 
 
@@ -324,6 +325,118 @@ class TestPlannerEngine:
         assert PlannerEngine(schema.tables, backend="jax").backend == "jax"
         with pytest.raises(ValueError):
             PlannerEngine(schema.tables, backend="tpu")
+
+
+class TestBatchedScoring:
+    """The walk scores the targets it re-decides in batches gathered ahead
+    of it (one kernel launch each on jax) and decides them in order; a
+    score gathered before a write to what its target reads is dropped and
+    the target scored again, so the plans are those of scoring one target
+    at a time."""
+
+    # the two 3-column targets share a batch; at f = 0.075 the first
+    # samples (l_shipdate) by lines 8-9, which the second reads
+    STALE = ([("l_discount", "l_extendedprice"),
+              ("l_discount", "l_extendedprice", "l_shipdate"),
+              ("l_quantity", "l_extendedprice", "l_shipdate")], 0.2, 0.9)
+
+    @staticmethod
+    def d2_targets(schema, insert_weight=0.1):
+        """The App. D.2 workload's estimation targets."""
+        wl = make_tpch_workload(schema, insert_weight=insert_weight)
+        adv = DesignAdvisor(wl, AdvisorOptions.dtac())
+        _, _, cands = adv._candidate_universe()
+        return list(DesignAdvisor.estimation_targets(cands))
+
+    @staticmethod
+    def one_per_launch(monkeypatch):
+        """Batches of one target: the walk as it scored before batching."""
+        from repro.kernels import planner_score as ps
+        monkeypatch.setattr(ps, "fits", lambda rows, k: False)
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_child_sample_makes_a_later_score_stale(self, schema, backend,
+                                                    monkeypatch):
+        cols, e, q = self.STALE
+        targets = [NodeKey("lineitem", c, "NS") for c in cols]
+        eng = PlannerEngine(schema.tables, backend=backend)
+        plans = eng.greedy_batch(targets, e, q, F_GRID)
+        assert eng.stale_rescores == 1
+        assert (eng.score_launches, eng.scored_records) == (3, 4)
+        sampled_child = {f for p, f in zip(plans, F_GRID)
+                         for k, nd in p.nodes.items()
+                         if k not in targets and nd.state is State.SAMPLED}
+        assert sampled_child == {0.075}
+        if backend == "numpy":
+            planner = EstimationPlanner(schema.tables)
+            for f, got in zip(F_GRID, plans):
+                assert_plan_identical(
+                    planner.greedy_scalar(targets, f, e, q), got, f"f={f}")
+        else:
+            self.one_per_launch(monkeypatch)
+            alone = PlannerEngine(schema.tables, backend=backend)
+            for f, a, b in zip(F_GRID, alone.greedy_batch(targets, e, q,
+                                                          F_GRID), plans):
+                assert_plan_identical(a, b, f"f={f}")
+            assert alone.score_launches == alone.scored_records == 3
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_d2_batches_decide_as_one_at_a_time(self, schema, backend,
+                                                monkeypatch):
+        """On the App. D.2 workload's targets a launch holds several
+        targets, and the plans are bitwise those of one target a launch
+        (numpy: of the scalar greedy)."""
+        targets = self.d2_targets(schema)
+        eng = PlannerEngine(schema.tables, backend=backend)
+        plans = eng.greedy_batch(targets, 0.5, 0.9, F_GRID)
+        assert 0 < eng.score_launches < eng.scored_records
+        assert eng.scored_records >= 4 * eng.score_launches
+        if backend == "numpy":
+            planner = EstimationPlanner(schema.tables)
+            for f, got in zip(F_GRID, plans):
+                assert_plan_identical(
+                    planner.greedy_scalar(targets, f, 0.5, 0.9), got,
+                    f"f={f}")
+        else:
+            self.one_per_launch(monkeypatch)
+            alone = PlannerEngine(schema.tables, backend=backend)
+            for f, a, b in zip(F_GRID, alone.greedy_batch(
+                    targets, 0.5, 0.9, F_GRID), plans):
+                assert_plan_identical(a, b, f"f={f}")
+            assert alone.score_launches == alone.scored_records
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_session_after_delta_equals_fresh(self, schema, backend):
+        """A recording engine replays what a delta left alone and batches
+        what it re-decides among the replays; its plans after the delta
+        equal a fresh engine's."""
+        before = self.d2_targets(schema, 0.1)
+        after = TestPlannerEngine().advisor_targets(schema, seed=1)
+        eng = PlannerEngine(schema.tables, backend=backend)
+        eng.greedy_batch(before, 0.5, 0.9, F_GRID)
+        launches = eng.score_launches
+        got = eng.greedy_batch(after, 0.5, 0.9, F_GRID)
+        fresh = PlannerEngine(schema.tables, backend=backend)
+        for f, a, b in zip(F_GRID, fresh.greedy_batch(after, 0.5, 0.9,
+                                                      F_GRID), got):
+            assert_plan_identical(a, b, f"f={f}")
+        assert eng.replay_hits + eng.replay_verified > 0
+        assert eng.score_launches > launches
+
+    def test_jax_walk_warms_its_programs(self, schema, monkeypatch):
+        """The jax walk asks for every program a batch can launch before
+        its first decision, once per accuracy target (a no-op where the
+        kernels are interpreted)."""
+        from repro.kernels import planner_score as ps
+        asked = []
+        monkeypatch.setattr(ps, "warm_once",
+                            lambda e, q: asked.append((e, q)) or 0)
+        targets = make_targets("NS", 4)
+        PlannerEngine(schema.tables, backend="jax").greedy_batch(
+            targets, 0.5, 0.9, F_GRID)
+        PlannerEngine(schema.tables).greedy_batch(targets, 0.5, 0.9,
+                                                  F_GRID)
+        assert asked == [(0.5, 0.9)]
 
 
 class TestSmokeParity:

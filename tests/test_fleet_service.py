@@ -563,6 +563,18 @@ class TestShareGroupBackend:
                 assert identical(tk.result(), fresh), (rnd, tid)
         assert fleet.stats["recommends"] == 6
 
+    def test_planner_batches_in_stats(self, schema):
+        """The tenants' planners' scoring batches show in the fleet's
+        `stats` as the sums of the tenants' own."""
+        fleet, wls = make_fleet(schema, 2)
+        for tid in wls:
+            fleet.submit_recommend(tid, BUDGET)
+        fleet.run_until_drained()
+        st = fleet.stats
+        for k in ("score_launches", "scored_records", "stale_rescores"):
+            assert st[k] == sum(fleet.tenant_stats(t)[k] for t in wls)
+        assert 0 < st["score_launches"] < st["scored_records"]
+
     def test_warm_up_leaves_nothing_to_lower(self, schema):
         """`warm_up` before any request, the tenants' first recommends,
         and `warm_up` again (for any fraction those sampled at besides the

@@ -504,16 +504,97 @@ class TestFusedScore:
                 np.testing.assert_array_equal(x, y)
         assert ps._fused_call._cache_size() - before == 1
 
+    @pytest.mark.parametrize("shapes", [
+        ((3, 1), (5, 4), (11, 2)),       # 19 rows, children 1..4
+        ((1, 7), (9, 3)),                # K=3 padded to the K=7 record's 8
+        ((8, 2), (8, 2)),                # two whole 8-row buckets
+        ((2, 16), (1, 1), (4, 5), (6, 9), (1, 3)),
+    ])
+    def test_batch_bitwise_per_record(self, shapes):
+        """One launch over several records of different candidate and
+        child counts returns each record's cm/cs/p bitwise as its own
+        `fused_score` launch does: candidate rows are independent, and
+        EXACT child pads up to the widest record are the fold's identity."""
+        nf = 5
+        records = []
+        for i, (nc, k) in enumerate(shapes):
+            m, s, dm, vt, mq = random_rvs(nc, k, nf, seed=10 + i)
+            rng = np.random.default_rng(20 + i)
+            mask67 = rng.random((nc, nf)) < 0.6
+            pre9 = extra = None
+            if i % 2:
+                pre9 = ~mask67 & (rng.random((nc, nf)) < 0.7)
+                extra = rng.uniform(0.1, 2.0, size=(nc, nf))
+            records.append((m, s, dm, vt, mq, mask67, pre9, extra))
+        calls = ps.counters()["fused_calls"]
+        got = ps.fused_score_batch(records, self.E, 0.37)
+        assert ps.counters()["fused_calls"] == calls + 1
+        assert len(got) == len(records)
+        for rec, scores in zip(records, got):
+            want = ps.fused_score(*rec, self.E, 0.37)
+            assert (want[2] > 0).any()
+            for x, y in zip(scores, want[:3]):
+                assert x.shape == y.shape
+                np.testing.assert_array_equal(x, y)
+
     def test_warm_up_runs_every_program_once(self, monkeypatch):
-        monkeypatch.setattr(ps, "MAX_CANDIDATES", 16)
+        monkeypatch.setattr(ps, "MAX_CANDIDATES", 32)
         monkeypatch.setattr(ps, "MAX_CHILDREN", 8)
-        assert ps.warm_up(self.E, 0.41) == 2 * 4
+        monkeypatch.setattr(ps, "MAX_CELLS", 128)
+        # candidate buckets 8 and 16 with children 1..8, 32 with 1..4
+        assert ps.warm_up(self.E, 0.41) == 2 * 4 + 3
         size = ps._fused_call._cache_size()
-        for nc, k in ((3, 1), (8, 2), (11, 3), (16, 8), (13, 7)):
+        for nc, k in ((3, 1), (8, 2), (11, 3), (16, 8), (13, 7), (32, 4),
+                      (17, 3)):
+            assert ps.fits(nc, k)
             m, s, dm, vt, mq = random_rvs(nc, k, 5, seed=k)
             ps.fused_score(m, s, dm, vt, mq, np.ones((nc, 5), dtype=bool),
                            None, None, self.E, 0.41)
+        # batches the walk can gather: any records whose rows fit the cap
+        for shapes in (((3, 1), (5, 4), (11, 2)), ((20, 3), (9, 4)),
+                       ((1, 8), (2, 5), (4, 1))):
+            assert ps.fits(sum(nc for nc, _ in shapes),
+                           max(k for _, k in shapes))
+            recs = []
+            for nc, k in shapes:
+                m, s, dm, vt, mq = random_rvs(nc, k, 5, seed=nc)
+                recs.append((m, s, dm, vt, mq, np.ones((nc, 5), dtype=bool),
+                             None, None))
+            ps.fused_score_batch(recs, self.E, 0.41)
         assert ps._fused_call._cache_size() == size
+        assert not ps.fits(33, 1) and not ps.fits(17, 8)
+        assert not ps.fits(8, 9)
+
+    def test_program_set_holds_the_batch_cap(self):
+        """The warmed set reaches the walk's cap: 128 candidate rows of up
+        to 32 children, or 64 rows of up to 64 (a 128 x 64 stack does not
+        fit a v5e kernel's scoped VMEM), and every launch `fits` admits
+        is one of its programs."""
+        progs = set(ps.programs())
+        assert ps.MAX_CANDIDATES == 128 and ps.MAX_CHILDREN == 64
+        assert {(128, 32), (64, 64), (8, 64)} <= progs
+        assert (128, 64) not in progs
+        assert len(progs) == 7 * 4 + 6
+        for rows in range(1, 140):
+            for k in range(1, 70):
+                if ps.fits(rows, k):
+                    assert ps._buckets(rows, k) in progs
+        assert ps.fits(128, 32) and ps.fits(64, 64)
+        assert not ps.fits(129, 1) and not ps.fits(65, 33)
+
+    def test_warm_once_per_accuracy_target(self, monkeypatch):
+        """`warm_once` runs the set once per (e, q) in a compiling
+        process, and not at all where kernels are interpreted."""
+        ran = []
+        monkeypatch.setattr(ps, "_warmed", set())
+        monkeypatch.setattr(ps, "warm_up",
+                            lambda e, q: ran.append((e, q)) or 34)
+        assert ps.warm_once(0.5, 0.9) == 0
+        monkeypatch.setattr(ps, "pallas_interpret", lambda: False)
+        assert ps.warm_once(0.5, 0.9) == 34
+        assert ps.warm_once(0.5, 0.9) == 0
+        assert ps.warm_once(0.5, 1.1) == 34
+        assert ran == [(0.5, 0.9), (0.5, 1.1)]
 
     def test_winner_indices_match_host_argmax(self):
         nc, k, nf = 14, 2, 6

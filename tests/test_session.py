@@ -271,6 +271,25 @@ class TestSessionIncrementality:
         d_sel_miss = warm["selection_misses"] - cold["selection_misses"]
         assert d_sel_hits > d_sel_miss, (d_sel_hits, d_sel_miss)
 
+    def test_counters_show_scoring_batches(self, workload, drift_pool,
+                                           base_size):
+        """The planner scores the targets it re-decides in batches: a cold
+        round several to a batch, a delta round only what it re-decides."""
+        budget = 0.25 * base_size
+        sess = AdvisorSession(workload, AdvisorOptions.dtac())
+        sess.recommend(budget)
+        cold = dict(sess.stats)
+        assert 0 < cold["score_launches"] < cold["scored_records"]
+        assert 0 <= cold["stale_rescores"] < cold["scored_records"]
+        sess.apply(WorkloadDelta(added=tuple(drift_pool[30:32])))
+        sess.recommend(budget)
+        warm = dict(sess.stats)
+        scored = warm["scored_records"] - cold["scored_records"]
+        misses = warm["replay_misses"] - cold["replay_misses"]
+        # a re-decided target is scored once, or again after a stale score
+        assert scored <= misses + (warm["stale_rescores"]
+                                   - cold["stale_rescores"])
+
     def test_reweight_only_round_reuses_everything(self, workload,
                                                    base_size):
         budget = 0.25 * base_size

@@ -6,8 +6,8 @@ primitive Mosaic has no lowering for, an unaligned shape cast, a block
 that overflows scoped VMEM.  Shapes are the TPC-H SF1 main path's:
 SampleCF samples of 60,000 rows, launches of `CHUNK` of a codec call's
 targets (up to 282 of them), rows per page that are not lane multiples,
-and the planner's largest
-(candidates, children) record.
+the planner's largest (candidates, children) record, and the largest
+launch of the planner's batches of records.
 
 The topology is described inside a module fixture, never at import time,
 and the persistent compilation cache is off around these compiles (a
@@ -114,6 +114,23 @@ def test_fused_score_compiles(one_chip):
                                   q=0.9, interpret=False),
                 stack, stack, col, col, col, ((nc, nf), i32),
                 ((nc, nf), i32), ((nc, nf), f32))
+
+
+@pytest.mark.parametrize("nc,k", [
+    (ps.MAX_CANDIDATES, ps.MAX_CELLS // ps.MAX_CANDIDATES),
+    (ps.MAX_CELLS // ps.MAX_CHILDREN, ps.MAX_CHILDREN)])
+def test_fused_score_batch_cap_compiles(one_chip, nc, k):
+    """The largest launches of the walk's batches: the candidate cap at
+    the children it allows, and the child cap at its candidates."""
+    assert (nc, k) in ps.programs()
+    f32, i32 = jnp.float32, jnp.int32
+    stack = ((nc, k * 128), f32)
+    col = ((nc, 1), f32)
+    compile_for(one_chip,
+                functools.partial(ps._fused_call, k=k, e=0.5, q=0.9,
+                                  interpret=False),
+                stack, stack, col, col, col, ((nc, 128), i32),
+                ((nc, 128), i32), ((nc, 128), f32))
 
 
 @pytest.mark.parametrize("kernel,fn,shapes", [
